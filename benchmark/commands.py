@@ -45,19 +45,27 @@ class CommandMaker:
         debug: bool = False,
         chunk: int | None = None,
         committee: str | None = None,
+        min_bucket: int | None = None,
     ) -> str:
         """The shared crypto sidecar: one process owns the TPU; all local
         nodes ship their large verification batches to it. `committee`
-        points at the node committee file so the sidecar registers the
-        validator keys as device-resident precompute at boot (the
-        committee-tagged batches it serves then ride the
-        zero-decompression kernel)."""
+        points at the node committee file so the sidecar holds the
+        validator tables on the device. `min_bucket` defaults to 4096,
+        which with the default 4096 chunk makes the sidecar dispatch ONE
+        generic width: each width is a whole verify program, minutes of
+        cold compile."""
         v = "-vvv" if debug else "-vv"
         chunk_arg = f" --chunk {chunk}" if chunk is not None else ""
         committee_arg = f" --committee {committee}" if committee else ""
+        bucket_arg = (
+            f" --min-bucket {4096 if min_bucket is None else min_bucket}"
+            if backend == "tpu"
+            else ""
+        )
         return (
             f"{sys.executable} -m hotstuff_tpu.crypto.remote {v} "
-            f"--port {port} --backend {backend}{chunk_arg}{committee_arg}"
+            f"--port {port} --backend {backend}{bucket_arg}{chunk_arg}"
+            f"{committee_arg}"
         )
 
     @staticmethod

@@ -314,16 +314,20 @@ def _parse_node(text: str) -> dict:
     # are cumulative, so only the LAST well-formed snapshot per node
     # matters; a malformed blob (truncated by SIGTERM mid-line) is skipped,
     # never a ParseError — observability must not fail the run.
-    out["metrics"] = None
+    out["metrics"] = _last_metrics(text)
+    return out
+
+
+def _last_metrics(text: str) -> dict | None:
+    """The LAST well-formed `METRICS {json}` snapshot of one log."""
     for blob in reversed(_search_all(r"METRICS (\{.*\})\s*$", text)):
         try:
             snap = json.loads(blob)
         except json.JSONDecodeError:
             continue
         if isinstance(snap, dict):
-            out["metrics"] = snap
-            break
-    return out
+            return snap
+    return None
 
 
 def _map_logs(fn, texts: list[str]) -> list[dict]:
@@ -336,7 +340,19 @@ def _map_logs(fn, texts: list[str]) -> list[dict]:
 
 
 class LogParser:
-    def __init__(self, clients: list[str], nodes: list[str], faults: int = 0) -> None:
+    def __init__(
+        self,
+        clients: list[str],
+        nodes: list[str],
+        faults: int = 0,
+        sidecar: str | None = None,
+    ) -> None:
+        # The crypto sidecar's last METRICS snapshot (`--crypto tpu` runs):
+        # `info.backend` is TpuBackend.report() — the device as JAX named
+        # it, signatures routed to it, chunks dispatched per program. This,
+        # not the nodes' "Verifying ... batch" lines, says what checked
+        # the signatures.
+        self.sidecar_metrics = _last_metrics(sidecar) if sidecar else None
         self.faults = faults
         self.committee_size = len(nodes) + faults
 
@@ -945,6 +961,7 @@ class LogParser:
             f" End-to-end BPS: {round(e_bps):,} B/s\n"
             f" End-to-end latency: {round(e_lat * 1000):,} ms\n"
             f" Batch verification rate: {round(v_rate):,} sigs/s ({v_total:,} total)\n"
+            + self._sidecar_line()
             + (
                 f" Workload shed at saturation: >= {self.workload_shed:,} sigs\n"
                 if self.workload_shed
@@ -965,6 +982,22 @@ class LogParser:
             + "-----------------------------------------\n"
         )
 
+    def _sidecar_line(self) -> str:
+        rep = ((self.sidecar_metrics or {}).get("info") or {}).get("backend")
+        if not isinstance(rep, dict):
+            return ""
+        cached = (self.sidecar_metrics.get("counters") or {}).get(
+            "verifier.dedup_hits", 0
+        )
+        return (
+            f" Sidecar device: {rep.get('platform')} "
+            f"({rep.get('device_kind')} x{rep.get('device_count')}), "
+            f"{rep.get('tpu_sigs', 0):,} sigs on device, "
+            f"{rep.get('cpu_sigs', 0):,} sub-crossover on its host, "
+            f"{cached:,} answered from its verified-signature cache, "
+            f"programs {rep.get('dispatched')}\n"
+        )
+
     @classmethod
     def process(cls, directory: str, faults: int = 0) -> "LogParser":
         clients = []
@@ -975,4 +1008,10 @@ class LogParser:
         for path in sorted(glob(join(directory, "node-*.log"))):
             with open(path) as f:
                 nodes.append(f.read())
-        return cls(clients, nodes, faults)
+        sidecar = None
+        try:
+            with open(join(directory, "sidecar.log")) as f:
+                sidecar = f.read()
+        except OSError:
+            pass
+        return cls(clients, nodes, faults, sidecar=sidecar)

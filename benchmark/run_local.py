@@ -2,6 +2,12 @@
 
     python -m benchmark.run_local --nodes 4 --rate 1000 --size 512 \
         --duration 20 [--faults 0] [--crypto cpu|tpu]
+
+The served run of chip_smoke.py, as a command:
+
+    python -m benchmark.run_local --nodes 4 --rate 4000 --size 512 \
+        --crypto tpu --benchmark-workload --mempool-payload-size 100000 \
+        --min-block-delay 100 --duration 20
 """
 
 from __future__ import annotations
@@ -28,6 +34,13 @@ def main() -> None:
                    "config uses 500 kB, fabfile.py:107-120)")
     p.add_argument("--timeout-delay", type=int, default=None,
                    help="override consensus timeout_delay (ms)")
+    p.add_argument("--min-block-delay", type=int, default=None,
+                   help="override consensus AND mempool min_block_delay (ms). "
+                   "The local default 0 lets a fast host seal payloads of a "
+                   "handful of transactions, so no batch reaches the "
+                   "64-signature crossover and --crypto tpu leaves the chip "
+                   "idle; 100 is the node's own default and upstream's "
+                   "remote setting (what chip_smoke.py runs)")
     p.add_argument("--sidecar-chunk", type=int, default=None,
                    help="TPU sidecar upload-pipeline chunk size (the device "
                    "chunk sweep's verdict); only with --crypto tpu")
@@ -54,6 +67,9 @@ def main() -> None:
         node_params["mempool"]["benchmark_mode"] = True
     if args.mempool_payload_size is not None:
         node_params["mempool"]["max_payload_size"] = args.mempool_payload_size
+    if args.min_block_delay is not None:
+        node_params["consensus"]["min_block_delay"] = args.min_block_delay
+        node_params["mempool"]["min_block_delay"] = args.min_block_delay
     if args.timeout_delay is not None:
         node_params["consensus"]["timeout_delay"] = args.timeout_delay
     parser = LocalBench(bench_params, node_params).run(debug=args.debug)

@@ -15,6 +15,7 @@ import logging
 import os
 import pathlib
 import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -37,7 +38,7 @@ def _build() -> pathlib.Path | None:
     try:
         if not hdr.exists():
             subprocess.run(
-                ["python", str(_NATIVE_DIR / "gen_constants.py")],
+                [sys.executable, str(_NATIVE_DIR / "gen_constants.py")],
                 check=True,
                 capture_output=True,
             )

@@ -32,10 +32,18 @@ import struct
 import threading
 from typing import Sequence
 
+from ..utils import metrics
 from .backend import CpuBackend, CryptoBackend
 from .primitives import PublicKey, Signature
 
 log = logging.getLogger("hotstuff.crypto")
+
+# Mirror RemoteBackend.stats into the node's METRICS line. The fallback
+# counter is the one a served run is judged by: an above-crossover batch a
+# node verified on its OWN CPU because the sidecar did not answer.
+_M_REMOTE_BATCHES = metrics.counter("crypto.remote_batches")
+_M_REMOTE_SIGS = metrics.counter("crypto.remote_sigs")
+_M_REMOTE_FALLBACKS = metrics.counter("crypto.remote_fallback_batches")
 
 
 def _encode_request(
@@ -90,7 +98,10 @@ class RemoteBackend(CryptoBackend):
     Small batches (below `crossover`) verify on the local CPU: a localhost
     round-trip plus device dispatch would only add latency to the
     consensus-critical QC path. Falls back to CPU entirely if the sidecar
-    is unreachable (a crypto sidecar outage must not halt the protocol)."""
+    is unreachable (a crypto sidecar outage must not halt the protocol);
+    every such batch is logged and counted (`fallback_batches`,
+    `crypto.remote_fallback_batches`) so a run cannot pass for a device
+    run while the nodes' CPUs did the work."""
 
     name = "remote"
 
@@ -122,7 +133,13 @@ class RemoteBackend(CryptoBackend):
         # Urgent lane: one reserved socket + slot for small requests.
         self._urgent_sem = threading.BoundedSemaphore(1)
         self._urgent_sock: socket.socket | None = None
-        self.stats = {"remote_batches": 0, "remote_sigs": 0, "cpu_batches": 0, "cpu_sigs": 0}
+        self.stats = {
+            "remote_batches": 0,
+            "remote_sigs": 0,
+            "cpu_batches": 0,
+            "cpu_sigs": 0,
+            "fallback_batches": 0,
+        }
 
     def _dial(self) -> socket.socket:
         s = socket.create_connection(self.addr, timeout=self.timeout)
@@ -203,6 +220,8 @@ class RemoteBackend(CryptoBackend):
                     self._give_back(sock, urgent)
                     self.stats["remote_batches"] += 1
                     self.stats["remote_sigs"] += n
+                    _M_REMOTE_BATCHES.inc()
+                    _M_REMOTE_SIGS.inc(n)
                     return [b != 0 for b in mask]
                 except (OSError, ConnectionError) as e:
                     if sock is not None:
@@ -216,6 +235,8 @@ class RemoteBackend(CryptoBackend):
                         )
         self.stats["cpu_batches"] += 1
         self.stats["cpu_sigs"] += n
+        self.stats["fallback_batches"] += 1
+        _M_REMOTE_FALLBACKS.inc()
         return self._cpu.verify_batch_mask(messages, keys, signatures)
 
 
@@ -281,15 +302,28 @@ async def _handle_connection(reader, writer, service, urgent_below: int):
 
 def warmup_backend(backend: CryptoBackend) -> None:
     """Pre-compile every verifier bucket width BEFORE serving: a cold jit
-    specialisation (~20-40 s on TPU) hitting mid-run would stall the whole
-    committee's verification pipeline. With the persistent compilation cache
-    enabled this is fast on every boot after the first. Delegates to the
-    backend's own warmup (TpuBackend.warmup covers the device-hash AND
-    host-hash variants); backends without one (CpuBackend) need none."""
+    specialisation (minutes for one whole verify program) hitting mid-run
+    would stall the whole committee's verification pipeline. With the
+    persistent compilation cache a later boot skips the compile but still
+    traces, lowers and loads the program (about a minute for the ~270 MB
+    Pallas one on a v5e host). Delegates to the backend's own warmup; backends without one
+    (CpuBackend) need none. A program the device refuses raises here."""
     warm = getattr(backend, "warmup", None)
     if warm is not None:
         secs = warm()
         log.info("backend warmup finished in %.1f s", secs)
+
+
+def _describe(backend: CryptoBackend) -> str:
+    """`name` for host backends; name, platform, device kind and count for
+    one that holds a device (TpuBackend) — the boot line says what the
+    sidecar actually runs on, never just what it was asked for."""
+    if not hasattr(backend, "platform"):
+        return backend.name
+    return (
+        f"{backend.name}: platform={backend.platform} "
+        f"kind={backend.device_kind!r} count={backend.device_count}"
+    )
 
 
 async def serve(
@@ -312,7 +346,12 @@ async def serve(
 
     server = await asyncio.start_server(handler, addr[0], addr[1])
     # NOTE: parsed by the benchmark harness to detect readiness.
-    log.info("Crypto sidecar (%s) successfully booted on %s:%s", backend.name, addr[0], addr[1])
+    log.info(
+        "Crypto sidecar (%s) successfully booted on %s:%s",
+        _describe(backend),
+        addr[0],
+        addr[1],
+    )
     async with server:
         await server.serve_forever()
 
@@ -372,9 +411,6 @@ def main(argv: list[str] | None = None) -> None:
         p.error("--chunk must be positive")
     setup_logging(args.verbose)
     if args.backend == "tpu":
-        from ..ops import enable_persistent_cache
-
-        enable_persistent_cache()
         if args.multihost:
             from ..parallel.mesh import init_multihost
 
@@ -408,15 +444,16 @@ def main(argv: list[str] | None = None) -> None:
         warmup_backend(backend)
         quiet_jax_logs(args.verbose)  # device init may reconfigure logging
     if args.committee is not None:
-        # After the generic warmup (device initialized) and with the same
-        # warmup policy: the committee kernel family compiles at every
-        # dispatch width before the sidecar starts serving.
+        # The table is built on the host and uploaded: no compile. The
+        # committee kernel family is NOT warmed here: the sidecar's wire
+        # protocol carries no committee tag, so no request can reach that
+        # family, and one program of it is minutes of cold compile.
         from ..node.config import Committee as NodeCommittee
 
         backend.register_committee(
-            NodeCommittee.read(args.committee).consensus.sorted_keys(),
-            warmup=not args.no_warmup,
+            NodeCommittee.read(args.committee).consensus.sorted_keys()
         )
+    _install_exit_report(backend)
     asyncio.run(
         serve(
             (args.host, args.port),
@@ -425,6 +462,31 @@ def main(argv: list[str] | None = None) -> None:
             max_delay=args.max_delay,
         )
     )
+
+
+def _install_exit_report(backend: CryptoBackend) -> None:
+    """What `node run` has (node/main.py): the periodic `METRICS {json}`
+    line LogParser scrapes, and one last snapshot on SIGTERM (the harness
+    stops every process with it). The backend's own report — device,
+    dispatched programs, routing stats — rides under `info.backend`."""
+    import atexit
+    import os
+    import signal
+
+    from ..ops.pipeline import close_all
+
+    report = getattr(backend, "report", None)
+    if report is not None:
+        metrics.set_info("backend", report)
+    metrics.start_periodic_emitter_from_env()
+
+    def _on_term(*_a):
+        metrics.emit_snapshot()
+        close_all()
+        os._exit(0)
+
+    signal.signal(signal.SIGTERM, _on_term)
+    atexit.register(metrics.emit_snapshot)
 
 
 if __name__ == "__main__":

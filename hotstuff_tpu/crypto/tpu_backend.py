@@ -68,15 +68,31 @@ class TpuBackend(CryptoBackend):
         committee_crossover: int | None = None,
     ):
         # import lazily so CPU-only processes never touch jax
-        from ..ops import enable_persistent_cache
+        import jax
 
-        enable_persistent_cache()
+        from ..ops import cpu_requested, enable_persistent_cache
+
+        self.cache_dir = enable_persistent_cache()
+        # The device is named ONCE, here: everything downstream (the kernel
+        # flavour, the sidecar's boot line and exit report, chip_smoke.py)
+        # reads these attributes instead of guessing from the class name.
+        self.platform = jax.default_backend()
+        devices = jax.devices()
+        self.device_kind = devices[0].device_kind
+        self.device_count = len(devices)
+        if self.platform != "tpu" and not cpu_requested():
+            raise RuntimeError(
+                f"TpuBackend found no TPU (jax backend {self.platform!r}, "
+                f"{self.device_kind}); it runs on the CPU only when the "
+                "process was told to (JAX_PLATFORMS=cpu, as the tests do)"
+            )
+        # pallas ladder on the chip; the jnp w4 kernel where the process
+        # asked for the CPU (pallas has no CPU lowering). Packed wire
+        # format + upload pipeline either way.
+        kernel = "pallas" if self.platform == "tpu" else "w4"
         if sharded or mesh is not None:
-            import jax
-
             from ..parallel.mesh import ShardedEd25519Verifier
 
-            kernel = "w4" if jax.default_backend() == "cpu" else "pallas"
             self._verifier = ShardedEd25519Verifier(
                 mesh=mesh,
                 min_bucket=min_bucket,
@@ -85,20 +101,22 @@ class TpuBackend(CryptoBackend):
                 chunk=chunk,
             )
         else:
-            import jax
-
             from ..ops.ed25519 import Ed25519TpuVerifier
 
-            # pallas ladder on a real accelerator; the jnp w4 kernel on the
-            # CPU interpreter (pallas has no CPU lowering). Packed wire
-            # format + threaded upload pipeline either way.
-            kernel = "w4" if jax.default_backend() == "cpu" else "pallas"
             self._verifier = Ed25519TpuVerifier(
                 min_bucket=min_bucket,
                 max_bucket=max_bucket,
                 kernel=kernel,
                 chunk=chunk,
             )
+        log.info(
+            "TpuBackend on %s (%s x%d), generic kernel %s, compile cache %s",
+            self.platform,
+            self.device_kind,
+            self.device_count,
+            self.kernel_names["generic"],
+            self.cache_dir,
+        )
         self._cpu = CpuBackend()
         self.crossover = crossover
         # The committee kernel skips per-batch decompression + window-table
@@ -132,6 +150,31 @@ class TpuBackend(CryptoBackend):
         closer = getattr(self._verifier, "close", None)
         if closer is not None:
             closer()
+
+    @property
+    def kernel_names(self) -> dict[str, str]:
+        """The program each family dispatches 32-byte-digest batches to."""
+        v = self._verifier
+        return {
+            "generic": v.program_name(False, True),
+            "committee": v.program_name(True, True),
+        }
+
+    def report(self) -> dict:
+        """What ran where: the device as JAX named it, the routing stats and
+        the chunks dispatched per program. The sidecar publishes this in its
+        METRICS line (`info.backend`), which is how a served run shows that
+        the device — not a node's CPU — checked signatures."""
+        with self._lock:
+            stats = dict(self.stats)
+        return {
+            "platform": self.platform,
+            "device_kind": self.device_kind,
+            "device_count": self.device_count,
+            "kernels": self.kernel_names,
+            "dispatched": dict(self._verifier.dispatched),
+            **stats,
+        }
 
     @property
     def bucket_alignment(self) -> int:
@@ -218,12 +261,6 @@ class TpuBackend(CryptoBackend):
             v.verify_batch_mask_committee(
                 [os.urandom(32)] * n, [0] * n, [os.urandom(64)] * n
             )
-        # host-hash variant (the device-hash failure latch's fallback)
-        v.verify_batch_mask_committee(
-            [os.urandom(33)] * sizes[-1],
-            [0] * sizes[-1],
-            [os.urandom(64)] * sizes[-1],
-        )
         secs = time.perf_counter() - t0
         log.info(
             "committee kernel warmup: %d batch sizes (widths %s) in %.1f s",
@@ -236,17 +273,22 @@ class TpuBackend(CryptoBackend):
     def warmup(self) -> float:
         """Force-compile every device bucket shape the verifier dispatches at
         runtime, BEFORE the node joins consensus. The first dispatch at each
-        bucket width triggers XLA compilation (tens of seconds cold); paying
-        that lazily inside the protocol stalls rounds past timeout_delay and
-        fires the pacemaker (the round-4 saturation runs logged dozens of
-        boot-window timeouts). With the persistent compile cache enabled in
-        __init__, later processes and runs hit the on-disk cache and this
-        costs seconds. Returns wall seconds spent.
+        bucket width triggers XLA compilation (minutes cold for one whole
+        verify program); paying that lazily inside the protocol stalls
+        rounds past timeout_delay and fires the pacemaker (the round-4
+        saturation runs logged dozens of boot-window timeouts). With the persistent compile cache enabled in
+        __init__, later processes and runs hit the on-disk cache and skip
+        the compile (trace, lower and load remain: about a minute per
+        program on a v5e host). Returns wall seconds spent.
 
         Junk inputs are used on purpose: compilation is shape-dependent
         only, and the masks are discarded. 32-byte messages warm the
-        production device-hash path; one 33-byte batch at the largest width
-        warms the host-hash variant the failure latch falls back to.
+        production device-hash program. The host-hash twin (messages of
+        any other length) is NOT compiled here: one whole verify program
+        is minutes of compile and a quarter of a gigabyte of code, the
+        protocol signs 32-byte digests, and nothing reruns a failed
+        device-hash batch through it — a program the chip's compiler
+        refuses raises here, before the process serves anything.
         """
         import os
         import time
@@ -259,11 +301,6 @@ class TpuBackend(CryptoBackend):
             junk_k = [os.urandom(32)] * n
             junk_s = [os.urandom(64)] * n
             v.verify_batch_mask(junk_m, junk_k, junk_s)
-        v.verify_batch_mask(
-            [os.urandom(33)] * sizes[-1],
-            [os.urandom(32)] * sizes[-1],
-            [os.urandom(64)] * sizes[-1],
-        )
         secs = time.perf_counter() - t0
         log.info(
             "generic kernel warmup: %d batch sizes (widths %s) in %.1f s",

@@ -269,14 +269,11 @@ def main(argv: list[str] | None = None) -> None:
 
         # Periodic `METRICS {json}` snapshot line on hotstuff.metrics
         # (scraped by benchmark.logs.LogParser); <= 0 disables.
-        try:
-            interval = float(os.environ.get("HOTSTUFF_METRICS_INTERVAL", "5"))
-        except ValueError:
-            logging.getLogger("hotstuff.metrics").warning(
-                "ignoring malformed HOTSTUFF_METRICS_INTERVAL"
-            )
-            interval = 5.0
-        metrics.start_periodic_emitter(interval)
+        metrics.start_periodic_emitter_from_env()
+        # One last snapshot at exit: counters that matter for a run's
+        # verdict (crypto.remote_fallback_batches among them) must not
+        # stop at the last 5 s tick.
+        flushers.append(metrics.emit_snapshot)
         if args.metrics_out:
 
             def _write_metrics():

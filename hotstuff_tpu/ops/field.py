@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -145,18 +146,26 @@ def sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 # Mosaic (Pallas TPU) cannot lower scatter-add, so kernels switch the
 # convolution to explicit per-row sums at trace time via this flag. The
 # scatter form traces smaller/faster for the plain-XLA path.
-_MOSAIC_SAFE = False
+# Per THREAD: programs are traced concurrently (the batch service dispatches
+# from several threads; chip_smoke.py compiles programs side by side), and a
+# process-global flag leaked both ways — a Pallas body lost the flag when
+# another thread's context exited (Mosaic then refuses `scatter-add`), and a
+# plain-XLA program traced meanwhile silently took the larger per-row form.
+_TRACE = threading.local()
+
+
+def _mosaic_safe_on() -> bool:
+    return getattr(_TRACE, "mosaic_safe", False)
 
 
 @contextlib.contextmanager
 def mosaic_safe():
     """Trace field ops without scatter/dynamic-update (for Pallas bodies)."""
-    global _MOSAIC_SAFE
-    prev, _MOSAIC_SAFE = _MOSAIC_SAFE, True
+    prev, _TRACE.mosaic_safe = _mosaic_safe_on(), True
     try:
         yield
     finally:
-        _MOSAIC_SAFE = prev
+        _TRACE.mosaic_safe = prev
 
 
 def _conv_scatter(a, b, batch):
@@ -230,7 +239,7 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     exceed 2^512, so the convolution gets 66 rows (see _reduce_512).
     """
     batch = jnp.broadcast_shapes(a.shape[1:], b.shape[1:])
-    if _MOSAIC_SAFE:
+    if _mosaic_safe_on():
         conv = _conv_shift if MOSAIC_CONV == "shift" else _conv_rows
     else:
         conv = _conv_scatter
@@ -242,7 +251,7 @@ def sqr(a: jnp.ndarray) -> jnp.ndarray:
     (c_k = a_i^2 [i+i=k] + 2*a_i*a_j [i<j, i+j=k]); same bounds as mul."""
     batch = a.shape[1:]
     a2 = a + a
-    if _MOSAIC_SAFE:
+    if _mosaic_safe_on():
         # shift form: block i contributes [a_i^2, 2*a_i*a_{i+1..}] at
         # offset 2i; zero-padded full-tile adds (see _conv_shift)
         parts = []

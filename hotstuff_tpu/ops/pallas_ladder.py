@@ -119,9 +119,13 @@ def _ladder_kernel(
     t_out[:] = T
 
 
-@functools.partial(jax.jit, static_argnames=())
-def ladder_pallas(s_digits, h_digits, ta_ypx, ta_ymx, ta_z, ta_t2d):
-    """(64,B) digits + per-item tables (16,32,B) -> ladder result Point."""
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ladder_pallas(
+    s_digits, h_digits, ta_ypx, ta_ymx, ta_z, ta_t2d, interpret: bool = False
+):
+    """(64,B) digits + per-item tables (16,32,B) -> ladder result Point.
+    `interpret` runs the kernel body in the Pallas interpreter (the only
+    way it executes without a TPU; the tests pass it, nothing else does)."""
     batch = s_digits.shape[1]
     assert batch % BLOCK == 0, f"batch {batch} must be a multiple of {BLOCK}"
     grid = (batch // BLOCK,)
@@ -147,6 +151,7 @@ def ladder_pallas(s_digits, h_digits, ta_ypx, ta_ymx, ta_z, ta_t2d):
         in_specs=[digit_spec, digit_spec] + [shared_spec] * 3 + [item_spec] * 4,
         out_specs=[out_spec] * 4,
         out_shape=[out_shape] * 4,
+        interpret=interpret,
     )(s_digits, h_digits, *base, ta_ypx, ta_ymx, ta_z, ta_t2d)
     return x, y, z, t
 

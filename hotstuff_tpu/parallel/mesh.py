@@ -26,22 +26,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map: jax>=0.8 (`jax.shard_map`, check_vma) with
-    fallback to the experimental API (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _sm  # pragma: no cover
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
 from ..ops import ed25519 as ed
 from ..utils import metrics
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    """`jax.shard_map` without the varying-manual-axes check (the kernels
+    mix replicated constants with sharded lanes freely)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def default_mesh(n_devices: int | None = None, axis: str = "dp") -> Mesh:
@@ -260,7 +254,7 @@ class ShardedEd25519Verifier(ed.Ed25519TpuVerifier):
             # dispatch is queued async (compute still overlaps later
             # chunks' staging), then ONE end-of-batch allgather
             # materializes all masks — per-transfer latency is paid
-            # once, not per chunk, decisive over tunneled links.
+            # once, not per chunk.
             self.pipeline.set_depth(1)
             self._defer_readback = True
         # per-device shard keeps full lanes (and pallas BLOCK alignment)

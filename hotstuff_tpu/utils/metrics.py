@@ -326,6 +326,7 @@ class Registry:
 
     def __init__(self) -> None:
         self._metrics: dict[str, Counter | Gauge | Histogram] = {}
+        self._info: dict[str, object] = {}
         self._lock = _new_lock()
 
     def _get_or_create(self, name: str, kind, factory):
@@ -366,13 +367,29 @@ class Registry:
                 if include_buckets:
                     summary["buckets"] = m.buckets_dict()
                 hists[m.name] = summary
-        return {
+        out = {
             "v": 1,
             "enabled": _enabled,
             "counters": counters,
             "gauges": gauges,
             "histograms": hists,
         }
+        with self._lock:
+            info = dict(self._info)
+        if info:
+            # Non-numeric facts about the process (which device, which
+            # kernel): callables are evaluated at dump time so the section
+            # is as live as the counters beside it.
+            out["info"] = {
+                k: v() if callable(v) else v for k, v in info.items()
+            }
+        return out
+
+    def set_info(self, key: str, value) -> None:
+        """Attach a JSON-able value (or a zero-argument callable returning
+        one) to every dump under `info[key]`."""
+        with self._lock:
+            self._info[key] = value
 
     def snapshot_json(self) -> str:
         """Compact one-line JSON (summaries only) for the METRICS log line."""
@@ -453,6 +470,10 @@ def reset() -> None:
     REGISTRY.reset()
 
 
+def set_info(key: str, value) -> None:
+    REGISTRY.set_info(key, value)
+
+
 def emit_snapshot() -> None:
     """Log one `METRICS {json}` line (the LogParser scraping contract)."""
     log.info("METRICS %s", snapshot_json())
@@ -483,6 +504,17 @@ def start_periodic_emitter(interval_s: float = 5.0) -> threading.Event | None:
     return stop
 
 
+def start_periodic_emitter_from_env(default_s: float = 5.0):
+    """`start_periodic_emitter` at `HOTSTUFF_METRICS_INTERVAL` seconds
+    (<= 0 disables) — what `node run` and the crypto sidecar both do."""
+    try:
+        interval = float(os.environ.get("HOTSTUFF_METRICS_INTERVAL", default_s))
+    except ValueError:
+        log.warning("ignoring malformed HOTSTUFF_METRICS_INTERVAL")
+        interval = default_s
+    return start_periodic_emitter(interval)
+
+
 # --- canonical namespace ----------------------------------------------------
 #
 # (name, kind, buckets) — the schema of record, documented as the metric
@@ -500,7 +532,6 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("verifier.sigs", "counter", None),
     ("verifier.batches", "counter", None),
     ("verifier.chunks", "counter", None),
-    ("verifier.device_hash_fallbacks", "counter", None),
     # committee-resident key precompute + verified-signature dedup
     ("verifier.decompressions", "counter", None),
     ("verifier.table_builds", "counter", None),
@@ -529,6 +560,10 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("crypto.cpu_batches", "counter", None),
     ("crypto.cpu_sigs", "counter", None),
     ("crypto.batch_size", "histogram", SIZE_BUCKETS),
+    # crypto/remote.py — a node's RemoteBackend (sidecar client)
+    ("crypto.remote_batches", "counter", None),
+    ("crypto.remote_sigs", "counter", None),
+    ("crypto.remote_fallback_batches", "counter", None),
     # crypto/scheduler.py — continuous-batching device scheduler. One
     # queue-delay histogram PER REGISTERED SOURCE CLASS: the starvation
     # lint (the graftlint `scheduler` pass) fails if a class in

@@ -91,7 +91,7 @@ _M_PEER_VIEWS = metrics.counter("telemetry.peer_views")
 
 # End-to-end verify-latency target for one device batch
 # (verifier.e2e_s): a batch habitually slower than this is a degraded
-# relay / host-fallback signature, the same class of anomaly the
+# device / host-fallback signature, the same class of anomaly the
 # watchdog's verify_regression streak looks for — the SLO form makes it
 # a budgeted, scrapeable objective instead of a streak heuristic.
 VERIFY_E2E_SLO_S = float(os.environ.get("HOTSTUFF_VERIFY_E2E_SLO_S", "0.25"))

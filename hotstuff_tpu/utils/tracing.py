@@ -423,7 +423,7 @@ class AnomalyWatchdog:
         BatchVerificationService; a sustained per-signature cost above
         `p99_factor` x the run's own baseline (the MEDIAN of the first
         BASELINE_SAMPLES flushes — cold-compile outliers must not poison
-        it) is a verify regression (device fell back to host, relay
+        it) is a verify regression (device fell back to host, sidecar
         degraded, ...).
 
     Each reason fires at most once per `cooldown_s`; firing records a

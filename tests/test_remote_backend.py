@@ -61,6 +61,7 @@ def test_small_batches_stay_local(triples, run_async, base_port):
         assert mask == [True]
         assert backend.stats["cpu_batches"] == 1
         assert backend.stats["remote_batches"] == 0
+        assert backend.stats["fallback_batches"] == 0  # by policy, not outage
 
     run_async(body())
 
@@ -78,6 +79,9 @@ def test_unreachable_sidecar_falls_back_to_cpu(triples, run_async, base_port):
         )
         assert mask == [True] * len(triples)
         assert backend.stats["cpu_batches"] == 1
+        # ...and the fallback is COUNTED: a served run where this is
+        # non-zero did not verify on the device (chip_smoke.py fails it)
+        assert backend.stats["fallback_batches"] == 1
 
     run_async(body())
 
@@ -159,3 +163,50 @@ def test_parse_request_enforces_per_message_cap():
     # item-count cap now lives in the parser too
     with _pytest.raises(ValueError):
         _parse_request(memoryview(struct.pack("<I", MAX_REQUEST_ITEMS + 1)))
+
+
+def test_boot_line_and_exit_report_name_the_device(monkeypatch, caplog):
+    """The sidecar's boot line carries platform, kind and count (not just
+    the name it was asked for), and its METRICS line carries the backend's
+    report under `info.backend` — the line LogParser scrapes."""
+    import json
+    import logging
+
+    from benchmark.logs import LogParser
+    from hotstuff_tpu.crypto import remote
+    from hotstuff_tpu.utils import metrics
+
+    class FakeDeviceBackend(CpuBackend):
+        name = "tpu"
+        platform, device_kind, device_count = "tpu", "TPU v5 lite", 1
+
+        def report(self):
+            return {
+                "platform": self.platform,
+                "device_kind": self.device_kind,
+                "device_count": self.device_count,
+                "kernels": {"generic": "pallas_p128dh"},
+                "dispatched": {"pallas_p128dh": 7},
+                "tpu_sigs": 1234,
+                "cpu_sigs": 5,
+            }
+
+    assert remote._describe(CpuBackend()) == "cpu"
+    assert remote._describe(FakeDeviceBackend()) == (
+        "tpu: platform=tpu kind='TPU v5 lite' count=1"
+    )
+    metrics.set_info("backend", FakeDeviceBackend().report)
+    try:
+        with caplog.at_level(logging.INFO, logger="hotstuff.metrics"):
+            metrics.emit_snapshot()
+    finally:
+        metrics.REGISTRY._info.clear()
+    line = [r.getMessage() for r in caplog.records if "METRICS" in r.getMessage()][-1]
+    snap = json.loads(line.split("METRICS ", 1)[1])
+    assert snap["info"]["backend"]["tpu_sigs"] == 1234
+    assert "info" not in metrics.dump()  # absent unless a process sets it
+    parser = LogParser([], [], sidecar=f"[2026-01-01T00:00:00.000Z INFO x] {line}\n")
+    assert parser.sidecar_metrics["info"]["backend"]["platform"] == "tpu"
+    assert "Sidecar device: tpu (TPU v5 lite x1), 1,234 sigs on device" in (
+        parser._sidecar_line()
+    )

@@ -146,12 +146,12 @@ def test_sharded_device_hash_matches():
     assert mask.tolist() == want
 
 
-def test_device_hash_failure_falls_back_to_host(monkeypatch):
-    """A runtime failure in the device-hash kernel must latch off and the
-    batch redo with host hashing — verification never goes down with it."""
+def test_device_hash_failure_raises_and_nothing_reruns_on_host(monkeypatch):
+    """A failure of the device-hash program raises like any other device
+    error: no silent rerun through the host-hash twin, no latch. (A
+    program the chip refuses is found at warm-up, which raises at boot.)"""
     v = ed.Ed25519TpuVerifier(kernel="w4", max_bucket=256)
     msgs, pks, sigs = _signed_batch(5, seed=21)
-    sigs[2] = bytes(64)
 
     def boom():
         def fail(*a, **k):
@@ -160,24 +160,23 @@ def test_device_hash_failure_falls_back_to_host(monkeypatch):
         return fail
 
     monkeypatch.setattr(v, "_packed_dh_fn", boom)
-    mask = v.verify_batch_mask(msgs, pks, sigs)
-    assert mask.tolist() == [True, True, False, True, True]
-    assert v._device_hash_ok is False
-    # subsequent batches go straight to host hashing
-    mask2 = v.verify_batch_mask(msgs, pks, sigs)
-    assert mask2.tolist() == [True, True, False, True, True]
+    with pytest.raises(RuntimeError, match="injected lowering failure"):
+        v.verify_batch_mask(msgs, pks, sigs)
+    assert "w4p128" not in v.dispatched  # the host-hash twin never ran
 
 
-def test_transient_device_failure_does_not_latch(monkeypatch):
-    """If the host-hash retry fails TOO (device down, not a kernel bug),
-    the error propagates and the device-hash latch stays on for recovery."""
+def test_dispatched_names_the_program_per_message_length():
+    """32-byte digests ride the device-hash program, any other length the
+    host-hash twin; `dispatched` says which — it is how a run shows what
+    kernel checked its signatures."""
     v = ed.Ed25519TpuVerifier(kernel="w4", max_bucket=256)
     msgs, pks, sigs = _signed_batch(3, seed=22)
-
-    def fail(*a, **k):
-        raise RuntimeError("device unreachable")
-
-    monkeypatch.setattr(v, "_run_packed", fail)
-    with pytest.raises(RuntimeError):
-        v.verify_batch_mask(msgs, pks, sigs)
-    assert v._device_hash_ok is True  # transient: fast path not latched off
+    assert v.verify_batch_mask(msgs, pks, sigs).all()
+    assert dict(v.dispatched) == {"w4p128dh": 1}
+    msgs2, pks2, sigs2 = _signed_batch(3, msg_len=100, seed=23)
+    assert v.verify_batch_mask(msgs2, pks2, sigs2).all()
+    assert dict(v.dispatched) == {"w4p128dh": 1, "w4p128": 1}
+    assert v.program_name(True, True) == "w4c96dh"
+    assert ed.Ed25519TpuVerifier(kernel="pallas").program_name(False, True) == (
+        "pallas_p128dh"
+    )

@@ -2,6 +2,7 @@
 CPU mesh (conftest.py). Mirrors the reference's batch-verification tests
 (crypto/src/tests/crypto_tests.rs:73-114) through the CryptoBackend seam."""
 
+import os
 import random
 
 import numpy as np
@@ -142,13 +143,15 @@ class TestGraftEntry:
 
 class TestWarmup:
     def test_warmup_compiles_every_bucket(self, keys):
-        # Tiny buckets keep the test fast: one dh compile + one host-hash
-        # compile at width 128 (shapes already cached by earlier tests).
+        # Tiny buckets keep the test fast: one device-hash compile at
+        # width 128 (the shape is already cached by earlier tests). The
+        # host-hash twin is NOT warmed: nothing falls back to it.
         backend = make_backend(
             "tpu", crossover=1, min_bucket=128, max_bucket=128
         )
         secs = backend.warmup()
         assert secs > 0
+        assert dict(backend._verifier.dispatched) == {"w4p128dh": 1}
         # Warmed backend still verifies correctly end to end.
         pk, sk = keys[0]
         d = Digest.of(b"warm")
@@ -156,3 +159,56 @@ class TestWarmup:
         assert backend.verify_batch_mask([d.data] * 4, [pk] * 4, [sig] * 4) == [
             True
         ] * 4
+
+
+class TestNamesItsDevice:
+    def test_raises_on_a_cpu_nobody_asked_for(self, monkeypatch):
+        """No chip and no JAX_PLATFORMS=cpu: TpuBackend refuses to exist —
+        the sidecar and `node run --crypto tpu` die at boot instead of
+        serving from the CPU under the name "tpu"."""
+        from hotstuff_tpu import ops
+
+        monkeypatch.setattr(ops, "cpu_requested", lambda: False)
+        with pytest.raises(RuntimeError, match="found no TPU"):
+            make_backend("tpu")
+
+    def test_report_names_platform_kernels_and_routing(self, keys):
+        backend = make_backend(
+            "tpu", crossover=2, min_bucket=128, max_bucket=128
+        )
+        assert (backend.platform, backend.device_count) == ("cpu", 8)
+        pk, sk = keys[0]
+        d = Digest.of(b"report")
+        sig = Signature.new(d, sk)
+        backend.verify_batch_mask([d.data] * 3, [pk] * 3, [sig] * 3)
+        backend.verify_batch_mask([d.data], [pk], [sig])  # sub-crossover
+        rep = backend.report()
+        assert rep["platform"] == "cpu" and rep["device_kind"] == "cpu"
+        assert rep["kernels"] == {"generic": "w4p128dh", "committee": "w4c96dh"}
+        assert rep["dispatched"] == {"w4p128dh": 1}
+        assert (rep["tpu_sigs"], rep["cpu_sigs"]) == (3, 1)
+
+
+class TestCompileCachePlacement:
+    def test_placed_by_the_environment_or_fixed_in_the_checkout(
+        self, monkeypatch
+    ):
+        """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and no code
+        sets a directory. Unset: the one fixed directory in the checkout."""
+        import jax
+
+        from hotstuff_tpu import ops
+
+        updates = []
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.append((k, v))
+        )
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/placed")
+        assert ops.enable_persistent_cache() == "/somewhere/placed"
+        assert "jax_compilation_cache_dir" not in dict(updates)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        updates.clear()
+        assert ops.enable_persistent_cache() == ops.CACHE_DIR
+        assert dict(updates)["jax_compilation_cache_dir"] == ops.CACHE_DIR
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert ops.CACHE_DIR == os.path.join(repo, ".jax_cache")

@@ -90,8 +90,9 @@ def main() -> None:
     ap.add_argument(
         "--rate",
         type=float,
-        default=85_275.0,
-        help="measured device sigs/s (BENCH_r03: 85,275)",
+        required=True,
+        help="device sigs/s measured on the chip for today's code (PERF.md "
+        "says where the current figure comes from; none is assumed)",
     )
     args = ap.parse_args()
 
