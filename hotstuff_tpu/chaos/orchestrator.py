@@ -44,6 +44,7 @@ from ..crypto.batch_service import BatchVerificationService
 from ..crypto.primitives import Digest, PublicKey, Signature
 from ..crypto.scheduler import SchedulerConfig
 from ..network import net
+from ..ops import timeline
 from ..store import Store
 from ..utils import incidents, metrics, telemetry, tracing
 from ..utils.actors import SpawnScope, channel, spawn
@@ -1103,6 +1104,10 @@ class ChaosOrchestrator:
         # deterministically; a fresh ring isolates the run's dump.
         prev_clock = tracing.set_clock(loop.time)
         tracing.reset()
+        # The services' `collect` spans fill the timeline ring too: a ring
+        # that carried over would overflow (`timeline.dropped`, a counter
+        # the telemetry snapshots ship) at another point of a same-seed rerun.
+        timeline.reset()
         self.watchdog_dumps: list[dict] = []
 
         def _capture(reason: str, detail: dict) -> None:

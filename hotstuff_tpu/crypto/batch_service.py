@@ -38,6 +38,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Sequence
 
+from ..ops import timeline
 from ..utils import metrics, tracing
 from .backend import CryptoBackend, get_backend
 from .primitives import PublicKey, Signature
@@ -55,6 +56,8 @@ _M_DEDUP_HITS = metrics.counter("verifier.dedup_hits")
 _M_DEDUP_MISSES = metrics.counter("verifier.dedup_misses")
 _M_DEDUP_INSERTS = metrics.counter("verifier.dedup_inserts")
 _M_DEDUP_EVICTIONS = metrics.counter("verifier.dedup_evictions")
+_M_COLLECT = metrics.histogram("service.collect_s")
+_M_BACKEND = metrics.histogram("service.backend_s")
 
 
 class VerifiedSigCache:
@@ -135,6 +138,10 @@ class _Group:
     source: str = "mempool"
     t_submit: float = 0.0
     t_dequeue: float = 0.0
+    # The sidecar's per-process request number (crypto/remote.py), 0 for
+    # a group that is no wire request: `service.collect` names the
+    # requests it merged by it.
+    rid: int = 0
     future: asyncio.Future = field(default_factory=lambda: asyncio.get_running_loop().create_future())
 
     def __len__(self) -> int:
@@ -260,6 +267,7 @@ class BatchVerificationService:
         dedup: bool = True,
         trace: str | None = None,
         source: str | None = None,
+        rid: int = 0,
     ) -> list[bool]:
         """Submit a correlated group (e.g. one QC's votes or one synthetic
         payload batch); resolves to the per-item validity mask once the
@@ -272,7 +280,8 @@ class BatchVerificationService:
         the verified-signature cache (synthetic benchmark load, where
         repeats are intentional and must pay full verification); `trace`
         tags the group with a causal trace id so the flight recorder can
-        attribute the batch's cost to the block it checks."""
+        attribute the batch's cost to the block it checks; `rid` is the
+        sidecar's number of the wire request the group came in."""
         if not messages:
             return []
         self._ensure_task()
@@ -287,6 +296,7 @@ class BatchVerificationService:
             trace,
             cls.name,
             asyncio.get_running_loop().time(),
+            rid=rid,
         )
         if self.scheduler is not None:
             self.scheduler.submit(group)
@@ -403,48 +413,61 @@ class BatchVerificationService:
         if not urgent:
             await self._dispatch_sem.acquire()
         try:
-            msgs = [m for g in groups for m in g.messages]
-            keys = [k for g in groups for k in g.keys]
-            sigs = [s for g in groups for s in g.signatures]
-            # backend_idx > 0 is a scheduler steal: the bucket rides a
-            # sibling shard's pipeline. Committee routing still resolves
-            # per backend (an unregistered steal target just takes the
-            # generic kernel — correctness never depends on the tag).
-            backend = (
-                self.backend
-                if backend_idx == 0
-                else self._steal_backends[backend_idx - 1]
+            # One synchronous section of the event loop (no await inside:
+            # ops/timeline.py's asyncio rule): the flatten and the dedup
+            # scan, item by item, up to the backend call. The verifier's
+            # chunk spans under the call share its batch number.
+            rids = [g.rid for g in groups if g.rid]
+            collect = timeline.span(
+                "collect", timeline.open_batch(), 0, total, hist=_M_COLLECT,
+                groups=len(groups),
+                rid_first=min(rids, default=0), rid_last=max(rids, default=0),
             )
+            with collect:
+                msgs = [m for g in groups for m in g.messages]
+                keys = [k for g in groups for k in g.keys]
+                sigs = [s for g in groups for s in g.signatures]
+                # backend_idx > 0 is a scheduler steal: the bucket rides a
+                # sibling shard's pipeline. Committee routing still resolves
+                # per backend (an unregistered steal target just takes the
+                # generic kernel — correctness never depends on the tag).
+                backend = (
+                    self.backend
+                    if backend_idx == 0
+                    else self._steal_backends[backend_idx - 1]
+                )
 
-            # Verified-signature dedup: triples the aggregator (or an
-            # earlier flush) already validated resolve True without
-            # touching the backend; only misses dispatch. Per-item
-            # eligibility: a flush may mix dedup-opted-out synthetic
-            # groups with consensus traffic. The scan (and the index-
-            # gather re-copy) is skipped entirely when no group opted in
-            # or nothing hit — the synthetic throughput path pays zero.
-            cache = self.dedup if any(g.dedup for g in groups) else None
-            mask = [False] * len(msgs)
-            miss = range(len(msgs))
-            dedupable = None
-            if cache is not None:
-                dedupable = [g.dedup for g in groups for _ in range(len(g))]
-                miss = []
-                for i, (m, k, s) in enumerate(zip(msgs, keys, sigs)):
-                    if dedupable[i] and cache.hit(m, k, s):
-                        mask[i] = True
-                    else:
-                        miss.append(i)
+                # Verified-signature dedup: triples the aggregator (or an
+                # earlier flush) already validated resolve True without
+                # touching the backend; only misses dispatch. Per-item
+                # eligibility: a flush may mix dedup-opted-out synthetic
+                # groups with consensus traffic. The scan (and the index-
+                # gather re-copy) is skipped entirely when no group opted in
+                # or nothing hit — the synthetic throughput path pays zero.
+                cache = self.dedup if any(g.dedup for g in groups) else None
+                mask = [False] * len(msgs)
+                miss = range(len(msgs))
+                dedupable = None
+                if cache is not None:
+                    dedupable = [g.dedup for g in groups for _ in range(len(g))]
+                    miss = []
+                    for i, (m, k, s) in enumerate(zip(msgs, keys, sigs)):
+                        if dedupable[i] and cache.hit(m, k, s):
+                            mask[i] = True
+                        else:
+                            miss.append(i)
+                collect.set(miss=len(miss))
+                if miss:
+                    full = len(miss) == len(msgs)
+                    kwargs = {}
+                    if all(g.committee for g in groups) and getattr(
+                        backend, "supports_committee_routing", False
+                    ):
+                        kwargs["committee"] = True
+                    m = msgs if full else [msgs[i] for i in miss]
+                    k = keys if full else [keys[i] for i in miss]
+                    s = sigs if full else [sigs[i] for i in miss]
             if miss:
-                full = len(miss) == len(msgs)
-                kwargs = {}
-                if all(g.committee for g in groups) and getattr(
-                    backend, "supports_committee_routing", False
-                ):
-                    kwargs["committee"] = True
-                m = msgs if full else [msgs[i] for i in miss]
-                k = keys if full else [keys[i] for i in miss]
-                s = sigs if full else [sigs[i] for i in miss]
                 t0 = time.perf_counter()
                 try:
                     if self.inline:
@@ -459,6 +482,7 @@ class BatchVerificationService:
                             g.future.set_exception(exc)
                     return
                 dur = time.perf_counter() - t0
+                _M_BACKEND.record(dur)
                 if tracing.enabled():
                     # One verify.batch event per traced group in the flush
                     # (batch tags + the group's scheduler lane and queueing

@@ -26,12 +26,15 @@ stream read and parse it with memoryview slicing — per-item stream awaits
 from __future__ import annotations
 
 import asyncio
+import itertools
 import logging
 import socket
 import struct
 import threading
+import time
 from typing import Sequence
 
+from ..ops import timeline
 from ..utils import metrics
 from .backend import CpuBackend, CryptoBackend
 from .primitives import PublicKey, Signature
@@ -44,6 +47,18 @@ log = logging.getLogger("hotstuff.crypto")
 _M_REMOTE_BATCHES = metrics.counter("crypto.remote_batches")
 _M_REMOTE_SIGS = metrics.counter("crypto.remote_sigs")
 _M_REMOTE_FALLBACKS = metrics.counter("crypto.remote_fallback_batches")
+# One successful round trip of a node's request: `sendall` to mask received.
+_M_REMOTE_RTT = metrics.histogram("crypto.remote_rtt_s")
+# The sidecar's side of the same request. parse and reply are synchronous
+# sections of the event loop (spans: ops/timeline.py); request_s runs from
+# the body read to the reply drained, across every wait in between.
+_M_REQUESTS = metrics.counter("sidecar.requests")
+_M_REQUEST_SIGS = metrics.counter("sidecar.request_sigs")
+_M_PARSE = metrics.histogram("sidecar.parse_s")
+_M_REPLY = metrics.histogram("sidecar.reply_s")
+_M_REQUEST = metrics.histogram("sidecar.request_s")
+# Per-process request numbers: a request's parse and reply spans carry one.
+_RIDS = itertools.count(1)
 
 
 def _encode_request(
@@ -212,11 +227,13 @@ class RemoteBackend(CryptoBackend):
                         # the suspect pool is dropped below.
                         self._flush_pool()
                         sock = self._dial()
+                    t0 = time.perf_counter()
                     sock.sendall(payload)
                     (count,) = struct.unpack("<I", self._recv_exact(sock, 4))
                     if count != n:
                         raise ConnectionError("sidecar count mismatch")
                     mask = self._recv_exact(sock, n)
+                    _M_REMOTE_RTT.record(time.perf_counter() - t0)
                     self._give_back(sock, urgent)
                     self.stats["remote_batches"] += 1
                     self.stats["remote_sigs"] += n
@@ -282,20 +299,28 @@ async def _handle_connection(reader, writer, service, urgent_below: int):
                 log.warning("dropping connection %s: runt request", peer)
                 break
             body = memoryview(await _read_exact(reader, body_len))
+            t_read = time.perf_counter()
+            rid = next(_RIDS)
+            # the item count is the body's first word: known before the parse
+            (n,) = struct.unpack("<I", body[:4])
             try:
-                msgs, pairs = _parse_request(body)
+                with timeline.span("parse", rid, 0, n, hist=_M_PARSE, rid=rid):
+                    msgs, pairs = _parse_request(body)
             except ValueError as e:
                 log.warning("dropping connection %s: malformed request (%s)", peer, e)
                 break
-            n = len(msgs)
+            _M_REQUESTS.inc()
+            _M_REQUEST_SIGS.inc(n)
             del body  # free the wire buffer before the (long) dispatch wait
             # Small requests are consensus-critical (QC/TC checks above the
             # client's crossover but still latency-bound): flush immediately.
             mask = await service.verify_group(
-                msgs, pairs, urgent=n < urgent_below
+                msgs, pairs, urgent=n < urgent_below, rid=rid
             )
-            writer.write(struct.pack("<I", n) + bytes(int(b) for b in mask))
-            await writer.drain()
+            with timeline.span("reply", rid, 0, n, hist=_M_REPLY, rid=rid):
+                writer.write(struct.pack("<I", n) + bytes(int(b) for b in mask))
+            await writer.drain()  # an await: outside the span
+            _M_REQUEST.record(time.perf_counter() - t_read)
     finally:
         writer.close()
 
@@ -344,6 +369,10 @@ async def serve(
     async def handler(reader, writer):
         await _handle_connection(reader, writer, service, urgent_below)
 
+    from ..utils.actors import spawn
+
+    # how much of one core this process's event loop uses (runtime.loop_cpu_s)
+    meter = spawn(metrics.meter_loop_cpu(), name="loop-cpu-meter")
     server = await asyncio.start_server(handler, addr[0], addr[1])
     # NOTE: parsed by the benchmark harness to detect readiness.
     log.info(
@@ -352,8 +381,11 @@ async def serve(
         addr[0],
         addr[1],
     )
-    async with server:
-        await server.serve_forever()
+    try:
+        async with server:
+            await server.serve_forever()
+    finally:
+        meter.cancel()
 
 
 def main(argv: list[str] | None = None) -> None:

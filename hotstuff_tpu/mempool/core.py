@@ -56,6 +56,9 @@ _M_REQUESTS_CLAMPED = metrics.counter("mempool.requests_clamped")
 _M_VERIFY_BATCH = metrics.histogram(
     "mempool.verify_batch_size", metrics.SIZE_BUCKETS
 )
+# How long one workload batch holds one of the node's pipeline slots
+# (`_verify_sem`), on the loop's clock: slots x batch / this is the plateau.
+_M_VERIFY_RTT = metrics.histogram("mempool.verify_rtt_s")
 
 
 class SyntheticPool:
@@ -214,17 +217,27 @@ class Core:
         first runs leaves no never-awaited coroutine behind."""
         sem = self._verify_sem if sem is None else sem
         await sem.acquire()
-        task = spawn(self._release_after(sem, fn, *args), name="mempool-verify")
+        # only a workload batch's hold of its slot is timed
+        held_at = (
+            asyncio.get_running_loop().time() if sem is self._verify_sem else None
+        )
+        task = spawn(
+            self._release_after(sem, held_at, fn, *args), name="mempool-verify"
+        )
         self._inflight.add(task)
         task.add_done_callback(self._inflight.discard)
 
-    async def _release_after(self, sem, fn, *args) -> None:
+    async def _release_after(self, sem, held_at, fn, *args) -> None:
         try:
             await fn(*args)
         except Exception as e:  # must not kill the task group silently
             log.warning("background verification error: %r", e)
         finally:
             sem.release()
+            if held_at is not None:
+                _M_VERIFY_RTT.record(
+                    asyncio.get_running_loop().time() - held_at
+                )
 
     # -- payload handling ----------------------------------------------------
 

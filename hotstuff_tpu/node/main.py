@@ -29,8 +29,12 @@ def _cmd_keys(args) -> None:
 
 
 async def _run_node(args) -> None:
+    from ..utils import metrics
+    from ..utils.actors import spawn
     from .node import Node
 
+    # how much of one core this process's event loop uses (runtime.loop_cpu_s)
+    spawn(metrics.meter_loop_cpu(), name="loop-cpu-meter")
     backend = None
     if args.crypto != "cpu":
         from ..crypto.backend import make_backend, set_backend

@@ -59,6 +59,11 @@ _M_UPLOAD = metrics.histogram("verifier.upload_s")
 _M_DISPATCH = metrics.histogram("verifier.dispatch_s")
 _M_READBACK = metrics.histogram("verifier.readback_s")
 _M_E2E = metrics.histogram("verifier.e2e_s")
+# What the dispatch pipeline's `stage` and `readback` spans also feed
+# (ChunkTask.hists). Under deferred readback a chunk's readback returns
+# its handle at once: the one real fetch is timed in _materialize_deferred.
+_CHUNK_HISTS = {"stage": _M_STAGE, "readback": _M_READBACK}
+_DEFER_HISTS = {"stage": _M_STAGE}
 _M_BATCH_SIZE = metrics.histogram("verifier.batch_size", metrics.SIZE_BUCKETS)
 _M_SIGS = metrics.counter("verifier.sigs")
 _M_BATCHES = metrics.counter("verifier.batches")
@@ -825,13 +830,13 @@ def _pad(arr: np.ndarray, width: int) -> np.ndarray:
     return np.pad(arr, cfg)
 
 
-def _upload_dispatch(fn, padded: np.ndarray, put=None, tlkey=None):
+def _upload_dispatch(fn, padded: np.ndarray, put, tlkey: tuple):
     """Runs on the pipeline's upload worker: ship one packed chunk,
     dispatch the kernel (async), return the device mask handle. `put`
     overrides the host->device transfer (the mesh verifier shards the
     batch axis here, so the jitted shard_map never reshards a device-0
-    array). `tlkey` is the chunk's (batch, chunk, n) device-timeline key
-    (ops/timeline.py), None when timeline recording is disabled.
+    array). `tlkey` is the chunk's (batch, chunk, n) key of the two
+    spans (ops/timeline.py: ring, histogram, profiler annotation).
 
     Each verifier's DispatchPipeline has ONE upload worker, so chunks of
     one verifier upload and dispatch in FIFO order while the main thread
@@ -839,11 +844,9 @@ def _upload_dispatch(fn, padded: np.ndarray, put=None, tlkey=None):
     §5.5i) run their uploads in parallel on their own workers."""
     import jax as _jax
 
-    up_span = timeline.span_for("upload", tlkey)
-    di_span = timeline.span_for("dispatch", tlkey)
-    with metrics.span(_M_UPLOAD), up_span:
+    with timeline.span("upload", *tlkey, hist=_M_UPLOAD):
         dev = (put or _jax.device_put)(padded)
-    with metrics.span(_M_DISPATCH), di_span:
+    with timeline.span("dispatch", *tlkey, hist=_M_DISPATCH):
         return fn(dev)
 
 
@@ -899,6 +902,9 @@ class Ed25519TpuVerifier:
         self.pipeline = DispatchPipeline(
             depth=pipeline_depth, name=f"ed25519-{kernel}"
         )
+        # This process imports jax, so its spans can sit on the profiler's
+        # clock: a TraceMe, free unless a profiler session is open.
+        timeline.set_annotator(jax.profiler.TraceAnnotation)
         self._put = None  # optional device_put override (mesh sharding)
         # Deferred readback (multi-process mesh, parallel/mesh.py): the
         # per-chunk readback returns the raw device handle and the chunk
@@ -990,31 +996,30 @@ class Ed25519TpuVerifier:
     def _run_committee(self, ct, messages, indices, signatures, device_hash: bool):
         n = len(messages)
         program = self.program_name(True, device_hash)
-        tl_on = timeline.enabled()
-        tl_batch = timeline.TIMELINE.next_batch() if tl_on else 0
+        tl_batch = timeline.batch_id()
         pool = self.pipeline.pool
         defer = self._defer_readback
+        hists = _DEFER_HISTS if defer else _CHUNK_HISTS
         tasks, oks = [], []
 
         def make_task(ci: int, lo: int, hi: int) -> ChunkTask:
-            tlkey = (tl_batch, ci, hi - lo) if tl_on else None
+            tlkey = (tl_batch, ci, hi - lo)
             release: list = []
 
             def stage():
                 _M_CHUNKS.inc()
                 idx_chunk = indices[lo:hi]
-                with metrics.span(_M_STAGE):
-                    if device_hash:
-                        staged = prepare_batch_committee_dh(
-                            messages[lo:hi], idx_chunk, signatures[lo:hi]
-                        )
-                    else:
-                        staged = prepare_batch_committee(
-                            messages[lo:hi],
-                            [ct.keys[i] for i in idx_chunk],
-                            idx_chunk,
-                            signatures[lo:hi],
-                        )
+                if device_hash:
+                    staged = prepare_batch_committee_dh(
+                        messages[lo:hi], idx_chunk, signatures[lo:hi]
+                    )
+                else:
+                    staged = prepare_batch_committee(
+                        messages[lo:hi],
+                        [ct.keys[i] for i in idx_chunk],
+                        idx_chunk,
+                        signatures[lo:hi],
+                    )
                 width = self._bucket(hi - lo)
                 _M_PAD_LANES.inc(width - (hi - lo))
                 oks.append((lo, hi, staged["s_ok"]))
@@ -1041,19 +1046,18 @@ class Ed25519TpuVerifier:
             def readback(handle):
                 if defer:
                     return handle
-                with metrics.span(_M_READBACK):
-                    return self._materialize([handle])
+                return self._materialize([handle])
 
             return ChunkTask(
                 stage=stage, submit=submit, readback=readback, tlkey=tlkey,
-                release=release,
+                release=release, hists=hists,
             )
 
         for ci, lo in enumerate(range(0, n, self.chunk)):
             tasks.append(make_task(ci, lo, min(lo + self.chunk, n)))
         hosts = self.pipeline.run(tasks)
         if defer:
-            hosts = self._materialize_deferred(hosts, n)
+            hosts = self._materialize_deferred(hosts, n, tl_batch)
         out = np.empty(n, bool)
         for (lo, hi, ok), host in zip(oks, hosts):
             out[lo:hi] = host[: hi - lo] & ok
@@ -1061,7 +1065,7 @@ class Ed25519TpuVerifier:
 
     def _upload_dispatch_committee(
         self, ct, packed: np.ndarray, idx: np.ndarray, device_hash: bool,
-        tlkey=None,
+        tlkey: tuple,
     ):
         """Uploader-thread leg of the committee path: ship the (96, W) wire
         array + (W,) index vector, dispatch against the RESIDENT tables of
@@ -1070,12 +1074,10 @@ class Ed25519TpuVerifier:
         import jax as _jax
 
         put = self._put or _jax.device_put
-        up_span = timeline.span_for("upload", tlkey)
-        di_span = timeline.span_for("dispatch", tlkey)
-        with metrics.span(_M_UPLOAD), up_span:
+        with timeline.span("upload", *tlkey, hist=_M_UPLOAD):
             dev_p = put(packed)
             dev_i = put(idx)
-        with metrics.span(_M_DISPATCH), di_span:
+        with timeline.span("dispatch", *tlkey, hist=_M_DISPATCH):
             if device_hash:
                 return _verify_w4c96dh_jit(
                     ct.ta_ypx,
@@ -1172,14 +1174,14 @@ class Ed25519TpuVerifier:
         fn = self._packed_dh_fn() if device_hash else self._packed_fn()
         stage_fn = prepare_batch_packed_dh if device_hash else prepare_batch_packed
         program = self.program_name(False, device_hash)
-        tl_on = timeline.enabled()
-        tl_batch = timeline.TIMELINE.next_batch() if tl_on else 0
+        tl_batch = timeline.batch_id()
         pool = self.pipeline.pool
         defer = self._defer_readback
+        hists = _DEFER_HISTS if defer else _CHUNK_HISTS
         tasks, oks = [], []
 
         def make_task(ci: int, lo: int, hi: int) -> ChunkTask:
-            tlkey = (tl_batch, ci, hi - lo) if tl_on else None
+            tlkey = (tl_batch, ci, hi - lo)
             release: list = []
 
             def stage():
@@ -1189,10 +1191,9 @@ class Ed25519TpuVerifier:
                 # cost the committee path amortizes away.
                 _M_TABLE_BUILDS.inc()
                 _M_DECOMPRESSIONS.inc(hi - lo)
-                with metrics.span(_M_STAGE):
-                    staged = stage_fn(
-                        messages[lo:hi], keys[lo:hi], signatures[lo:hi]
-                    )
+                staged = stage_fn(
+                    messages[lo:hi], keys[lo:hi], signatures[lo:hi]
+                )
                 width = self._bucket(hi - lo)
                 _M_PAD_LANES.inc(width - (hi - lo))
                 oks.append((lo, hi, staged["s_ok"]))
@@ -1212,19 +1213,18 @@ class Ed25519TpuVerifier:
             def readback(handle):
                 if defer:
                     return handle
-                with metrics.span(_M_READBACK):
-                    return self._materialize([handle])
+                return self._materialize([handle])
 
             return ChunkTask(
                 stage=stage, submit=submit, readback=readback, tlkey=tlkey,
-                release=release,
+                release=release, hists=hists,
             )
 
         for ci, lo in enumerate(range(0, n, self.chunk)):
             tasks.append(make_task(ci, lo, min(lo + self.chunk, n)))
         hosts = self.pipeline.run(tasks)
         if defer:
-            hosts = self._materialize_deferred(hosts, n)
+            hosts = self._materialize_deferred(hosts, n, tl_batch)
         out = np.empty(n, bool)
         for (lo, hi, ok), host in zip(oks, hosts):
             out[lo:hi] = host[: hi - lo] & ok
@@ -1237,14 +1237,18 @@ class Ed25519TpuVerifier:
             return np.asarray(masks[0])
         return np.asarray(jnp.concatenate(masks))
 
-    def _materialize_deferred(self, handles: list, n: int) -> list:
+    def _materialize_deferred(self, handles: list, n: int, batch: int) -> list:
         """Deferred-readback tail (`_defer_readback`, multi-process
         mesh): ONE `_materialize` over every chunk's device handle — a
         single end-of-batch allgather, the pre-pipeline multihost shape
         ('per-transfer latency is paid once, not per chunk') — split
         back into per-chunk host arrays on the deterministic bucket
-        widths."""
-        with metrics.span(_M_READBACK):
+        widths. The one real readback of the batch: timed under its last
+        chunk (the per-chunk readbacks returned handles and fed no
+        histogram, `_DEFER_HISTS`)."""
+        with timeline.span(
+            "readback", batch, len(handles) - 1, n, hist=_M_READBACK
+        ):
             full = self._materialize(handles)
         out, off = [], 0
         for lo in range(0, n, self.chunk):
@@ -1262,20 +1266,16 @@ class Ed25519TpuVerifier:
         # the jit call), so the timeline records stage/dispatch/readback
         # and the overlap-headroom pairing has nothing to pair — headroom
         # honestly reads 0 for a path with no pipelined transfer.
-        tl_on = timeline.enabled()
-        tlkey = (timeline.TIMELINE.next_batch(), 0, n) if tl_on else None
-        st_span = timeline.span_for("stage", tlkey)
-        with metrics.span(_M_STAGE), st_span:
+        tlkey = (timeline.batch_id(), 0, n)
+        with timeline.span("stage", *tlkey, hist=_M_STAGE):
             staged = prepare_batch(
                 messages, keys, signatures, want_bits=self.kernel == "bits"
             )
         width = self._bucket(n)
         _M_PAD_LANES.inc(width - n)
-        di_span = timeline.span_for("dispatch", tlkey)
-        with di_span:
+        with timeline.span("dispatch", *tlkey):
             mask = _verify_jit_args(staged, width, self.kernel)
-        rb_span = timeline.span_for("readback", tlkey)
-        with metrics.span(_M_READBACK), rb_span:
+        with timeline.span("readback", *tlkey, hist=_M_READBACK):
             host = np.asarray(mask)
         return host[:n] & staged["s_ok"]
 

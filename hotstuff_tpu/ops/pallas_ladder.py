@@ -158,11 +158,16 @@ def ladder_pallas(
 
 def _verify_kernel_pallas(a_y, a_sign, r_enc, s_digits, h_digits):
     """Full verification with the ladder in pallas; same contract as
-    ed._verify_kernel_w4."""
-    x_a, xneg_a, valid = ed.decompress(a_y, a_sign)
-    ta = ed._build_neg_a_table(xneg_a, a_y)
-    result = ladder_pallas(s_digits, h_digits, *ta)
-    enc = ed.compress(result)
+    ed._verify_kernel_w4. The scopes are metadata only: stable names for
+    the program's stages in a device trace, whatever the HLO ops are called."""
+    with jax.named_scope("decompress"):
+        x_a, xneg_a, valid = ed.decompress(a_y, a_sign)
+    with jax.named_scope("table"):
+        ta = ed._build_neg_a_table(xneg_a, a_y)
+    with jax.named_scope("ladder"):
+        result = ladder_pallas(s_digits, h_digits, *ta)
+    with jax.named_scope("compress"):
+        enc = ed.compress(result)
     return valid & jnp.all(enc == r_enc, axis=0)
 
 
@@ -179,7 +184,9 @@ def _verify_kernel_pallas_packed128(packed):
 def _verify_kernel_pallas_packed128_dh(packed):
     """Device-hash wire format: rows 96-127 are the 32-byte message; h is
     computed on device (ops.sha512) in plain jnp around the pallas ladder."""
-    return _verify_kernel_pallas(*ed.unpack_packed_inputs_dh(packed))
+    with jax.named_scope("unpack"):
+        inputs = ed.unpack_packed_inputs_dh(packed)
+    return _verify_kernel_pallas(*inputs)
 
 
 _verify_pallas_p128_jit = jax.jit(_verify_kernel_pallas_packed128)

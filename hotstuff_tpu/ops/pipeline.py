@@ -41,9 +41,10 @@ and (c) leaked its executor for the life of the process.
     virtual-time plane requires (COMPONENTS.md §5.5i), and the "serial"
     leg of `bench.py --pipeline-ab`.
 
-The pipeline stamps the `stage` and `readback` phases of each task's
-DeviceTimeline key; the task's `submit` callable owns the `upload` and
-`dispatch` phases (the existing `_upload_dispatch` /
+The pipeline times the `stage` and `readback` phases of each task
+(`timeline.span`: the ring under the task's key, the task's histogram of
+that phase, the profiler annotation); the task's `submit` callable owns
+the `upload` and `dispatch` phases (the existing `_upload_dispatch` /
 `_upload_dispatch_committee` seams, which the mesh verifier overrides).
 `TIMELINE_STAGES` is the full vocabulary — the graftlint `pipeline`
 pass asserts
@@ -117,9 +118,12 @@ class ChunkTask:
                  `upload`/`dispatch` timeline phases itself — the
                  `_upload_dispatch*` seams already do).
     `readback` — resolve the handle to a host result (readback worker).
-    `tlkey`    — the chunk's (batch, chunk, n) DeviceTimeline key, None
-                 when recording is off; the pipeline stamps `stage` and
-                 `readback` spans with it.
+    `tlkey`    — the chunk's (batch, chunk, n) DeviceTimeline key; the
+                 pipeline times `stage` and `readback` under it. A task
+                 without one is not timed.
+    `hists`    — phase -> the histogram that phase's span also feeds
+                 (`verifier.stage_s`, `verifier.readback_s`); a phase
+                 left out feeds none.
     `release`  — pooled staging buffers to return once the chunk has
                  fully settled (filled by `stage`, drained by the
                  pipeline after `readback` completes — not at
@@ -132,6 +136,7 @@ class ChunkTask:
     readback: Callable[[Any], Any]
     tlkey: tuple | None = None
     release: list = field(default_factory=list)
+    hists: dict = field(default_factory=dict)
 
 
 class StagingBufferPool:
@@ -235,7 +240,7 @@ class DispatchPipeline:
         # depth+1 buffers per shape: `depth` chunks in flight (each holds
         # its buffers until readback settles) plus the one being packed.
         self.pool = pool or StagingBufferPool(max_per_shape=self.depth + 1)
-        self._tl = tl  # None -> the process-global timeline (span_for)
+        self._tl = tl  # None -> the process-global timeline
         self._execs: dict[str, ThreadPoolExecutor] = {}
         self._lock = threading.Lock()
         self._closed = False
@@ -281,19 +286,20 @@ class DispatchPipeline:
 
     # -- timeline spans ------------------------------------------------------
 
-    def _span(self, phase: str, tlkey: tuple | None, start: float | None = None):
-        if tlkey is None:
+    def _span(self, phase: str, task: ChunkTask, start: float | None = None):
+        if task.tlkey is None:
             return timeline.NULL
-        if self._tl is not None:
-            return timeline.span(phase, *tlkey, timeline=self._tl, start=start)
-        return timeline.span_for(phase, tlkey, start=start)
+        return timeline.span(
+            phase, *task.tlkey, timeline=self._tl, start=start,
+            hist=task.hists.get(phase),
+        )
 
     # -- execution -----------------------------------------------------------
 
     def _staged(self, task: ChunkTask):
         self.stats["chunks"] += 1
         _M_CHUNKS.inc()
-        with self._span("stage", task.tlkey):
+        with self._span("stage", task):
             return task.stage()
 
     def _submitted(self, task: ChunkTask, payload):
@@ -319,7 +325,7 @@ class DispatchPipeline:
             # (GIL/scheduler) is not device idle — without the backdate,
             # every worker handoff shows up as an idle gap that cancels
             # exactly the occupancy the overlap bought.
-            with self._span("readback", task.tlkey, start=dispatched_t):
+            with self._span("readback", task, start=dispatched_t):
                 return task.readback(handle)
         finally:
             self._release_buffers(task)
@@ -349,7 +355,7 @@ class DispatchPipeline:
             # Same backdate rule as the windowed path (fair A/B): the span
             # opens at dispatch completion — on this thread that is only
             # microseconds ago, so serial semantics are unchanged.
-            with self._span("readback", task.tlkey, start=dispatched_t):
+            with self._span("readback", task, start=dispatched_t):
                 return task.readback(handle)
         finally:
             self._release_buffers(task)

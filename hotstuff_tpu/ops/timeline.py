@@ -34,6 +34,42 @@ are `time.monotonic()` and dumps carry the flight recorder's (mono, wall)
 anchor pair so `tools/trace_report.py` can align device-timeline rows
 beside the six-stage block rows.
 
+`span` is the ONE way a host phase of the verify plane is timed. One
+`with` block, one clock read per edge, three sinks:
+
+  1. the **ring** above (off under `HOTSTUFF_TIMELINE=0`);
+  2. the phase's **histogram** (`hist=`: `verifier.stage_s`, ...), so no
+     site wraps one interval in `metrics.span` as well;
+  3. an **annotation** named `PHASES[phase]` on the profiler's clock.
+     This module stays jax-free: `set_annotator(factory)` is called by
+     the code that already imports jax (`Ed25519TpuVerifier`) with
+     `jax.profiler.TraceAnnotation`, a TraceMe that costs nothing unless
+     a profiler session is open, and then puts the interval into the
+     `/host:CPU` plane of the very trace that holds the device's plane.
+     A process that never builds a verifier (a node) never installs one.
+
+Two properties of the third sink:
+
+  * **Annotations are per thread and stack-like.** On an asyncio thread
+    wrap only SYNCHRONOUS sections (no `await` inside), or coroutines
+    interleave and nest falsely. A wait that crosses an `await` (the
+    scheduler's queue, the dispatch semaphore, the `to_thread` hop) is a
+    histogram only; in the trace it is the absence of any annotation,
+    which reads "the host was waiting for a request".
+  * **A backdated span (`start=`) is backdated in the ring alone.** The
+    pipeline opens `readback` at dispatch completion for the occupancy
+    math; the histogram and the annotation run from the real enter, so on
+    the profiler's clock `verifier.readback` is the time the worker
+    really blocked on the result, which is what gap attribution wants.
+
+Request-level phases (`parse`, `collect`, `reply`) share the ring with the
+chunk phases; `summary()` reads the chunk phases alone. Spans of one
+bucket share `batch` (`open_batch` in the service's dispatch, `batch_id`
+in the verifier: `asyncio.to_thread` carries the context over); a
+request's `parse` and `reply` carry its `rid` as their `batch`, and
+`collect` names the rids it merged, so a request can be followed from
+parse to chunk to reply.
+
 Dependency-free by design: stdlib + utils.metrics/tracing only — no jax
 (the graftlint tool and the chaos/telemetry planes import this
 module on hosts with no accelerator stack at all).
@@ -41,6 +77,7 @@ module on hosts with no accelerator stack at all).
 
 from __future__ import annotations
 
+import contextvars
 import json
 import os
 import threading
@@ -51,13 +88,16 @@ from ..utils import metrics, tracing
 
 __all__ = [
     "PHASES",
+    "CHUNK_PHASES",
     "DEVICE_PHASES",
     "DeviceTimeline",
     "TIMELINE",
     "enabled",
     "enable",
     "span",
-    "span_for",
+    "set_annotator",
+    "open_batch",
+    "batch_id",
     "NULL",
     "summary",
     "dump",
@@ -65,11 +105,27 @@ __all__ = [
     "reset",
 ]
 
-# The four pipeline phases of one verifier chunk, in pipeline order.
-# `stage` is host CPU (numpy/C++ wire-format staging); the other three
-# face the device and define occupancy.
-PHASES: tuple[str, ...] = ("stage", "upload", "dispatch", "readback")
+# Ring phase -> the span's name on the profiler's clock (its histogram
+# adds `_s`). First the four pipeline phases of one verifier chunk, in
+# pipeline order: `stage` is host CPU (numpy/C++ wire-format staging); the
+# other three face the device and define occupancy. Then the three
+# synchronous event-loop sections of one sidecar request. The names are
+# matched by the benchmark's trace reduction: final.
+PHASES: dict[str, str] = {
+    "stage": "verifier.stage",
+    "upload": "verifier.upload",
+    "dispatch": "verifier.dispatch",
+    "readback": "verifier.readback",
+    "parse": "sidecar.parse",
+    "collect": "service.collect",
+    "reply": "sidecar.reply",
+}
+CHUNK_PHASES: tuple[str, ...] = ("stage", "upload", "dispatch", "readback")
 DEVICE_PHASES: frozenset[str] = frozenset({"upload", "dispatch", "readback"})
+
+# 4,096 intervals wrapped in ~8 s at 145 requests a second (three request
+# phases each, four a chunk); four times that keeps half a minute.
+RING_CAPACITY = 16384
 
 _M_INTERVALS = metrics.counter("timeline.intervals")
 _M_DROPPED = metrics.counter("timeline.dropped")
@@ -97,9 +153,11 @@ class DeviceTimeline:
     def __init__(self, capacity: int | None = None) -> None:
         if capacity is None:
             try:
-                capacity = int(os.environ.get("HOTSTUFF_TIMELINE_RING", "4096"))
+                capacity = int(
+                    os.environ.get("HOTSTUFF_TIMELINE_RING", RING_CAPACITY)
+                )
             except ValueError:
-                capacity = 4096
+                capacity = RING_CAPACITY
         self.capacity = max(16, capacity)
         self._ring: deque = deque(maxlen=self.capacity)
         self._count = 0
@@ -146,25 +204,26 @@ class DeviceTimeline:
     # -- derived numbers -----------------------------------------------------
 
     def summary(self) -> dict:
-        """Occupancy / idle-gap / overlap-headroom over the whole ring.
+        """Occupancy / idle-gap / overlap-headroom over the chunk phases
+        of the whole ring (request phases are no part of a chunk).
 
         All fields derive from ONE ring snapshot. Empty ring -> zeros (the
         shape is stable so BENCH json and dashboards never KeyError)."""
-        iv = list(self._ring)
+        iv = [i for i in list(self._ring) if i[2] in CHUNK_PHASES]
         out = {
             "batches": 0,
             "chunks": 0,
             "span_s": 0.0,
             "occupancy": 0.0,
             "overlap_headroom": 0.0,
-            "phase_s": {p: 0.0 for p in PHASES},
+            "phase_s": {p: 0.0 for p in CHUNK_PHASES},
             "idle": {"count": 0, "total_s": 0.0, "p50_s": 0.0, "max_s": 0.0},
         }
         if not iv:
             return out
         t_lo = min(t0 for _b, _c, _p, t0, _t1, _n in iv)
         t_hi = max(t1 for _b, _c, _p, _t0, t1, _n in iv)
-        phase_s = {p: 0.0 for p in PHASES}
+        phase_s = {p: 0.0 for p in CHUNK_PHASES}
         busy: list[tuple[float, float]] = []
         chunks = set()
         batches = set()
@@ -172,7 +231,7 @@ class DeviceTimeline:
         dispatch_dur: dict[tuple[int, int], float] = {}
         for b, c, p, t0, t1, n in iv:
             dur = max(0.0, t1 - t0)
-            phase_s[p] = phase_s.get(p, 0.0) + dur
+            phase_s[p] += dur
             chunks.add((b, c))
             batches.add(b)
             if p in DEVICE_PHASES:
@@ -258,27 +317,55 @@ class DeviceTimeline:
 TIMELINE = DeviceTimeline()
 
 
-class _Span:
-    """Context manager recording one interval (monotonic enter/exit).
+# The third sink. None until code that imports jax installs
+# `jax.profiler.TraceAnnotation` (module docstring); a test installs a fake.
+_annotate = None
 
-    `start` backdates the interval's opening edge to a moment the caller
-    already observed (clamped to never sit in the future): the dispatch
-    pipeline opens each `readback` span at dispatch completion, because
-    the device has been computing since then even if the readback worker
-    dequeued the chunk late.
+
+def set_annotator(factory):
+    """Install `factory(name, **stats)` -> context manager (with a
+    `set_metadata(**stats)`) as the annotation sink of every later span;
+    None removes it. Returns the one that was installed before."""
+    global _annotate
+    prev, _annotate = _annotate, factory
+    return prev
+
+
+# The batch a `service.collect` opened, for the verifier under it: both
+# asyncio tasks and `asyncio.to_thread` run in a copy of the context.
+_BATCH: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "timeline_batch", default=0
+)
+
+
+def open_batch() -> int:
+    """A fresh batch number, set as this context's batch: the spans of a
+    verifier called under it share it (`batch_id`)."""
+    batch = TIMELINE.next_batch()
+    _BATCH.set(batch)
+    return batch
+
+
+def batch_id() -> int:
+    """The batch the caller's context opened, else a fresh one."""
+    return _BATCH.get() or TIMELINE.next_batch()
+
+
+class _Span:
+    """Context manager feeding one interval to the three sinks (module
+    docstring); one monotonic read per edge, the annotation outermost.
+
+    `start` backdates the RING interval's opening edge to a moment the
+    caller already observed (clamped to never sit in the future): the
+    dispatch pipeline opens each `readback` span at dispatch completion,
+    because the device has been computing since then even if the
+    readback worker dequeued the chunk late.
     """
 
-    __slots__ = ("_tl", "_batch", "_chunk", "_phase", "_n", "_t0", "_start")
+    __slots__ = ("_tl", "_batch", "_chunk", "_phase", "_n", "_t0", "_start",
+                 "_hist", "_ann")
 
-    def __init__(
-        self,
-        tl: DeviceTimeline,
-        phase: str,
-        batch: int,
-        chunk: int,
-        n: int,
-        start: float | None = None,
-    ):
+    def __init__(self, tl, phase, batch, chunk, n, start, hist, stats):
         self._tl = tl
         self._phase = phase
         self._batch = batch
@@ -286,20 +373,40 @@ class _Span:
         self._n = n
         self._t0 = 0.0
         self._start = start
+        self._hist = hist
+        self._ann = (
+            None
+            if _annotate is None
+            else _annotate(PHASES[phase], batch=batch, chunk=chunk, n=n, **stats)
+        )
+
+    def set(self, **stats) -> None:
+        """Stats known only inside the block (a dedup scan's misses); they
+        ride the annotation, the ring's tuple is fixed."""
+        if self._ann is not None:
+            self._ann.set_metadata(**stats)
 
     def __enter__(self) -> "_Span":
-        now = time.monotonic()
-        self._t0 = now if self._start is None else min(self._start, now)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tl.note(
-            self._batch, self._chunk, self._phase, self._t0, time.monotonic(), self._n
-        )
+        t1 = time.monotonic()
+        if self._hist is not None:
+            self._hist.record(t1 - self._t0)
+        t0 = self._t0 if self._start is None else min(self._start, self._t0)
+        self._tl.note(self._batch, self._chunk, self._phase, t0, t1, self._n)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class _NullSpan:
     __slots__ = ()
+
+    def set(self, **stats) -> None:
+        pass
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -318,24 +425,20 @@ def span(
     n: int = 0,
     timeline: DeviceTimeline | None = None,
     start: float | None = None,
+    hist: "metrics.Histogram | None" = None,
+    **stats,
 ):
-    """`with timeline.span("upload", b, c, n): ...` — no-op when disabled."""
-    if not _enabled:
+    """`with timeline.span("upload", b, c, n, hist=_M_UPLOAD): ...` —
+    ring interval, histogram sample and annotation of one interval.
+    `stats` ride the annotation beside batch/chunk/n. A no-op when every
+    sink is off (ring disabled, no histogram, no annotator)."""
+    if not _enabled and hist is None and _annotate is None:
         return NULL
     # `is None`, not truthiness: an EMPTY DeviceTimeline is falsy (__len__).
     return _Span(
-        TIMELINE if timeline is None else timeline, phase, batch, chunk, n, start
+        TIMELINE if timeline is None else timeline,
+        phase, batch, chunk, n, start, hist, stats,
     )
-
-
-def span_for(phase: str, tlkey: tuple | None, start: float | None = None):
-    """`span` over the chunk loops' optional (batch, chunk, n) key:
-    NULL when the key is None (their "timeline off" sentinel). One
-    guard here instead of one per call site — and `is None`, so a
-    future falsy key shape cannot silently disable recording."""
-    if tlkey is None:
-        return NULL
-    return span(phase, *tlkey, start=start)
 
 
 def summary() -> dict:

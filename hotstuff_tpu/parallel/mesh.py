@@ -27,7 +27,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import ed25519 as ed
-from ..utils import metrics
 
 
 def shard_map(f, mesh, in_specs, out_specs):
@@ -318,7 +317,7 @@ class ShardedEd25519Verifier(ed.Ed25519TpuVerifier):
         replicated shard_map operands with zero per-batch movement."""
         return ed.CommitteeTable(keys, put=self._replicate)
 
-    def _upload_dispatch_committee(self, ct, packed, idx, device_hash, tlkey=None):
+    def _upload_dispatch_committee(self, ct, packed, idx, device_hash, tlkey):
         """Uploader-thread leg of the committee path over the mesh: the
         (96, W) wire rows and (W,) index vector land SHARDED on the dp axis
         (no device-0 staging + reshard) and dispatch against the PINNED
@@ -327,12 +326,10 @@ class ShardedEd25519Verifier(ed.Ed25519TpuVerifier):
         the chunk's device-timeline key (ops/timeline.py) through, same as
         the single-chip leg."""
         tl = ed.timeline
-        up_span = tl.span_for("upload", tlkey)
-        di_span = tl.span_for("dispatch", tlkey)
-        with metrics.span(ed._M_UPLOAD), up_span:
+        with tl.span("upload", *tlkey, hist=ed._M_UPLOAD):
             dev_p = self._put(packed)
             dev_i = self._put_lanes(idx)
-        with metrics.span(ed._M_DISPATCH), di_span:
+        with tl.span("dispatch", *tlkey, hist=ed._M_DISPATCH):
             if device_hash:
                 return self._sharded_committee_dh(
                     ct.ta_ypx,

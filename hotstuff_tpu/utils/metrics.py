@@ -71,6 +71,7 @@ __all__ = [
     "write_json",
     "reset",
     "start_periodic_emitter",
+    "meter_loop_cpu",
 ]
 
 # Wall-seconds buckets (1-2-5 series, 10 us .. 60 s): spans from sub-ms
@@ -156,14 +157,14 @@ class Counter:
         self._value = 0
         self._lock = _new_lock()
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: float = 1) -> None:
         if not _enabled:
             return
         with self._lock:
             self._value += n
 
     @property
-    def value(self) -> int:
+    def value(self) -> float:
         return self._value
 
     def _reset(self) -> None:
@@ -504,6 +505,24 @@ def start_periodic_emitter(interval_s: float = 5.0) -> threading.Event | None:
     return stop
 
 
+async def meter_loop_cpu(interval_s: float = 0.05) -> None:
+    """Add the CPU seconds of the calling event loop's thread to
+    `runtime.loop_cpu_s`, every `interval_s`: over a window its advance,
+    divided by the window, is how much of one core the loop's thread
+    used (1.0 = that thread is the bottleneck). Started by a process's
+    entry point alone (`crypto/remote.py:serve`, `node/main.py`), never
+    by a library class: the chaos runner's virtual clock must not see it."""
+    import asyncio
+
+    cpu = counter("runtime.loop_cpu_s")
+    last = time.thread_time()
+    while True:
+        await asyncio.sleep(interval_s)
+        now = time.thread_time()
+        cpu.inc(now - last)
+        last = now
+
+
 def start_periodic_emitter_from_env(default_s: float = 5.0):
     """`start_periodic_emitter` at `HOTSTUFF_METRICS_INTERVAL` seconds
     (<= 0 disables) — what `node run` and the crypto sidecar both do."""
@@ -564,6 +583,18 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("crypto.remote_batches", "counter", None),
     ("crypto.remote_sigs", "counter", None),
     ("crypto.remote_fallback_batches", "counter", None),
+    ("crypto.remote_rtt_s", "histogram", None),
+    # crypto/remote.py — the sidecar's side of a request: counts at parse,
+    # the two synchronous event-loop sections, the whole request
+    ("sidecar.requests", "counter", None),
+    ("sidecar.request_sigs", "counter", None),
+    ("sidecar.parse_s", "histogram", None),
+    ("sidecar.reply_s", "histogram", None),
+    ("sidecar.request_s", "histogram", None),
+    # crypto/batch_service.py — one dispatch: flatten + dedup scan on the
+    # event loop, then the thread hop and the backend call
+    ("service.collect_s", "histogram", None),
+    ("service.backend_s", "histogram", None),
     # crypto/scheduler.py — continuous-batching device scheduler. One
     # queue-delay histogram PER REGISTERED SOURCE CLASS: the starvation
     # lint (the graftlint `scheduler` pass) fails if a class in
@@ -672,6 +703,7 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("mempool.front_dropped", "counter", None),
     ("mempool.ingress_lane_txs", "counter", None),
     ("mempool.verify_batch_size", "histogram", SIZE_BUCKETS),
+    ("mempool.verify_rtt_s", "histogram", None),
     # ingress/ — authenticated client plane with admission control
     ("ingress.received", "counter", None),
     ("ingress.admitted", "counter", None),
@@ -777,6 +809,9 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     # ops/timeline.py — device-occupancy timeline
     ("timeline.intervals", "counter", None),
     ("timeline.dropped", "counter", None),
+    # utils/metrics.py meter_loop_cpu — CPU seconds of the event loop's
+    # thread (a float count), started by the sidecar's and the node's mains
+    ("runtime.loop_cpu_s", "counter", None),
 )
 
 

@@ -1,0 +1,422 @@
+"""The life of a verify request as spans (ops/timeline.py `span`: ring,
+histogram, profiler annotation), the counters beside them, and the
+benchmark's readers of both (chipbench/spans.py, layer_metrics/*).
+
+CPU only: counts, orders and names. No time here is a device's.
+"""
+
+import asyncio
+import glob
+import os
+import time
+
+import pytest
+
+from hotstuff_tpu.ops import timeline
+from hotstuff_tpu.ops.pipeline import ChunkTask, DispatchPipeline
+from hotstuff_tpu.utils import metrics
+
+
+class FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs what it is told."""
+
+    log: list = []
+
+    def __init__(self, name, **stats):
+        self.name, self.stats = name, stats
+        self.log.append(("new", name, dict(stats)))
+
+    def set_metadata(self, **stats):
+        self.log.append(("set", self.name, stats))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, time.monotonic()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, time.monotonic()))
+
+
+@pytest.fixture
+def fake_annotator():
+    FakeAnnotation.log = []
+    prev = timeline.set_annotator(FakeAnnotation)
+    yield FakeAnnotation.log
+    timeline.set_annotator(prev)
+
+
+@pytest.fixture
+def no_annotator():
+    prev = timeline.set_annotator(None)
+    yield
+    timeline.set_annotator(prev)
+
+
+def _hist(name):
+    return metrics.histogram(name)
+
+
+# -- (a) one helper, three sinks ---------------------------------------------
+
+
+def test_one_with_feeds_ring_histogram_and_annotation_nested(fake_annotator):
+    tl = timeline.DeviceTimeline(capacity=16)
+    h = metrics.Histogram("test.span_s")
+    with timeline.span("stage", 7, 2, 99, timeline=tl, hist=h, rid=5) as sp:
+        time.sleep(0.002)
+        sp.set(miss=3)
+    (iv,) = tl.intervals()
+    assert (iv["phase"], iv["batch"], iv["chunk"], iv["n"]) == ("stage", 7, 2, 99)
+    assert h.count == 1
+    # one clock read per edge: ring interval and histogram sample are one
+    assert h.sum == pytest.approx(iv["t1"] - iv["t0"], abs=2e-6)
+    kinds = [e[0] for e in fake_annotator]
+    assert kinds == ["new", "enter", "set", "exit"]
+    new, enter, meta, exit_ = fake_annotator
+    # the annotation carries the profiler's name of the phase and the stats
+    assert new[1] == "verifier.stage" == timeline.PHASES["stage"]
+    assert new[2] == {"batch": 7, "chunk": 2, "n": 99, "rid": 5}
+    assert meta[2] == {"miss": 3}
+    # nesting: the annotation is outermost, the timed interval inside it
+    assert enter[2] <= iv["t0"] + 1e-6 and iv["t1"] <= exit_[2] + 1e-6
+
+
+def test_no_annotator_costs_no_call(fake_annotator):
+    assert timeline.set_annotator(None) is FakeAnnotation  # taken out again
+    tl = timeline.DeviceTimeline(capacity=16)
+    h = metrics.Histogram("test.span_s")
+    with timeline.span("upload", 1, 0, 8, timeline=tl, hist=h) as sp:
+        sp.set(miss=1)  # nowhere to go, and no error
+    assert len(tl) == 1 and h.count == 1 and fake_annotator == []
+
+
+def test_ring_off_still_feeds_histogram_and_annotation(fake_annotator):
+    tl = timeline.DeviceTimeline(capacity=16)
+    h = metrics.Histogram("test.span_s")
+    timeline.enable(False)
+    try:
+        with timeline.span("dispatch", 1, 0, 8, timeline=tl, hist=h):
+            pass
+    finally:
+        timeline.enable(True)
+    assert len(tl) == 0 and h.count == 1
+    assert [e[0] for e in fake_annotator] == ["new", "enter", "exit"]
+
+
+def test_every_sink_off_is_the_null_span(no_annotator):
+    timeline.enable(False)
+    try:
+        assert timeline.span("upload", 1, 0, 8) is timeline.NULL
+    finally:
+        timeline.enable(True)
+
+
+def test_backdate_moves_the_ring_edge_alone(fake_annotator):
+    """`start=` (the pipeline's readback) opens the RING interval earlier;
+    the histogram and the annotation run from the real enter."""
+    tl = timeline.DeviceTimeline(capacity=16)
+    h = metrics.Histogram("test.span_s")
+    began = time.monotonic() - 1.0
+    with timeline.span("readback", 1, 0, 8, timeline=tl, hist=h, start=began):
+        pass
+    (iv,) = tl.intervals()
+    assert iv["t1"] - iv["t0"] >= 1.0
+    assert h.sum < 0.5
+    enter = [e for e in fake_annotator if e[0] == "enter"][0]
+    assert enter[2] > began + 0.9
+
+
+def test_request_phases_share_the_ring_not_the_summary():
+    tl = timeline.DeviceTimeline(capacity=16)
+    tl.note(1, 0, "upload", 0.0, 1.0, 64)
+    tl.note(41, 0, "parse", 5.0, 6.0, 64)
+    tl.note(9, 0, "collect", 6.0, 7.0, 64)
+    tl.note(41, 0, "reply", 8.0, 9.0, 64)
+    assert len(tl.intervals()) == 4
+    s = tl.summary()
+    assert s["chunks"] == 1 and s["batches"] == 1
+    assert s["span_s"] == pytest.approx(1.0)
+    assert set(s["phase_s"]) == set(timeline.CHUNK_PHASES)
+    assert set(timeline.CHUNK_PHASES) < set(timeline.PHASES)
+    assert not timeline.DEVICE_PHASES & {"parse", "collect", "reply"}
+
+
+def test_pipeline_spans_feed_the_tasks_histograms(no_annotator):
+    tl = timeline.DeviceTimeline(capacity=64)
+    stage_h = metrics.Histogram("test.stage_s")
+    read_h = metrics.Histogram("test.readback_s")
+    pipe = DispatchPipeline(depth=2, name="spans-test", tl=tl)
+    tasks = [
+        ChunkTask(
+            stage=lambda: 1, submit=lambda p: p, readback=lambda h: h,
+            tlkey=(3, ci, 10),
+            hists={"stage": stage_h, "readback": read_h} if ci else {"stage": stage_h},
+        )
+        for ci in range(3)
+    ]
+    try:
+        assert pipe.run(tasks) == [1, 1, 1]
+    finally:
+        pipe.close()
+    phases = sorted((i["chunk"], i["phase"]) for i in tl.intervals())
+    assert phases == [(c, p) for c in range(3) for p in ("readback", "stage")]
+    # a phase a task names no histogram for feeds none (deferred readback)
+    assert stage_h.count == 3 and read_h.count == 2
+
+
+def test_batch_number_crosses_the_thread_hop():
+    async def body():
+        opened = timeline.open_batch()
+        assert timeline.batch_id() == opened
+        assert await asyncio.to_thread(timeline.batch_id) == opened
+
+    asyncio.run(body())
+    # outside any opened batch every caller gets a fresh number
+    a, b = timeline.batch_id(), timeline.batch_id()
+    assert a != b
+
+
+def test_collect_and_verifier_spans_share_one_batch(run_async, no_annotator):
+    """`service.collect` opens the batch; a backend called under it (here a
+    fake that asks, as Ed25519TpuVerifier does) sees the same number."""
+    from hotstuff_tpu.crypto.batch_service import BatchVerificationService
+    from hotstuff_tpu.crypto.primitives import PublicKey, Signature
+
+    seen = []
+
+    class Backend:
+        name = "fake"
+
+        def verify_batch_mask(self, m, k, s):
+            seen.append(timeline.batch_id())
+            return [True] * len(m)
+
+    async def body():
+        timeline.TIMELINE.reset()
+        before = _hist("service.collect_s").count, _hist("service.backend_s").count
+        svc = BatchVerificationService(Backend(), max_delay=0.001)
+        pairs = [(PublicKey(bytes(32)), Signature(bytes(64)))] * 5
+        assert await svc.verify_group([b"m"] * 5, pairs, dedup=False, rid=17) == [True] * 5
+        collects = [i for i in timeline.TIMELINE.intervals() if i["phase"] == "collect"]
+        assert len(collects) == 1 and collects[0]["n"] == 5
+        assert seen == [collects[0]["batch"]]
+        assert _hist("service.collect_s").count == before[0] + 1
+        assert _hist("service.backend_s").count == before[1] + 1
+
+    run_async(body())
+
+
+# -- (b) a span on the profiler's clock --------------------------------------
+
+
+def test_span_lands_in_the_profilers_host_plane(tmp_path):
+    """Under a real jax.profiler session, with the profiler options the
+    benchmark's shim uses, a `timeline.span` is an event of the `/host:CPU`
+    plane under its profiler name, with its stats. No kernel is compiled."""
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+
+    prev = timeline.set_annotator(jax.profiler.TraceAnnotation)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    tl = timeline.DeviceTimeline(capacity=16)
+    try:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with timeline.span("stage", 12, 3, 4001, timeline=tl):
+                time.sleep(0.003)
+            with timeline.span("collect", 13, 0, 77, timeline=tl, groups=2) as sp:
+                sp.set(miss=70)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        timeline.set_annotator(prev)
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    data = ProfileData.from_file(path)
+    (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+    found = {}
+    for line in host.lines:
+        for ev in line.events:
+            if ev.name in timeline.PHASES.values():
+                found[ev.name] = (ev.duration_ns, dict(ev.stats))
+    assert set(found) == {"verifier.stage", "service.collect"}
+    dur, stats = found["verifier.stage"]
+    assert dur >= 3e6
+    assert (stats["batch"], stats["chunk"], stats["n"]) == (12, 3, 4001)
+    assert found["service.collect"][1]["groups"] == 2
+    assert found["service.collect"][1]["miss"] == 70
+
+
+# -- (c) a request's counters, node to sidecar and back ----------------------
+
+
+def test_loopback_sidecar_counts_requests_and_slot_holds(run_async, base_port):
+    pytest.importorskip("cryptography")
+    from hotstuff_tpu.crypto.backend import CpuBackend
+    from hotstuff_tpu.crypto.batch_service import BatchVerificationService
+    from hotstuff_tpu.crypto.remote import RemoteBackend, serve
+    from hotstuff_tpu.mempool import MempoolParameters
+    from hotstuff_tpu.mempool.core import Core
+    from hotstuff_tpu.store import Store
+    from hotstuff_tpu.utils.actors import channel
+    from tests.common import keys
+    from tests.common_mempool import mempool_committee
+
+    names = ("sidecar.requests", "sidecar.request_sigs", "mempool.synthetic_skipped",
+             "runtime.loop_cpu_s")
+    hists = ("sidecar.request_s", "sidecar.parse_s", "sidecar.reply_s",
+             "crypto.remote_rtt_s", "mempool.verify_rtt_s")
+
+    def read():
+        return ({n: metrics.counter(n).value for n in names},
+                {n: _hist(n).count for n in hists})
+
+    async def body():
+        server = asyncio.create_task(
+            serve(("127.0.0.1", base_port), CpuBackend(), max_delay=0.001)
+        )
+        await asyncio.sleep(0.2)
+        c0, h0 = read()
+        timeline.TIMELINE.reset()
+        try:
+            service = BatchVerificationService(
+                RemoteBackend(("127.0.0.1", base_port), crossover=1), max_delay=0.001
+            )
+            core = Core(
+                keys(4)[0][0], mempool_committee(base_port + 1, 4),
+                MempoolParameters(benchmark_mode=True, synthetic_pool_size=32),
+                Store(), None, None, channel(), channel(), channel(),
+                verification_service=service, max_inflight_verifications=1,
+            )
+            for _ in range(2):
+                await core._submit_synthetic_batch("OWN", 16)  # takes the one slot
+                await core._submit_synthetic_batch("OWN", 16)  # finds it taken: skipped
+                await asyncio.wait_for(asyncio.gather(*core._inflight), 20)
+            await asyncio.sleep(0.1)  # the sidecar's drain, the meter's tick
+        finally:
+            server.cancel()
+        c1, h1 = read()
+        d = {n: c1[n] - c0[n] for n in names}
+        dh = {n: h1[n] - h0[n] for n in hists}
+        # two admitted workload batches made two requests of 16 signatures
+        assert d["sidecar.requests"] == 2 and d["sidecar.request_sigs"] == 32
+        assert dh == {n: 2 for n in hists}
+        # one slot-hold per admitted batch, none per skipped one
+        assert d["mempool.synthetic_skipped"] == 32
+        # the sidecar's main started the loop's CPU meter
+        assert d["runtime.loop_cpu_s"] > 0
+        # a request's parse and reply share its rid; collect names it
+        ring = timeline.TIMELINE.intervals()
+        parses = [i["batch"] for i in ring if i["phase"] == "parse"]
+        replies = [i["batch"] for i in ring if i["phase"] == "reply"]
+        assert len(parses) == 2 and sorted(parses) == sorted(replies)
+
+    run_async(body())
+
+
+# -- (d) the benchmark's readers ----------------------------------------------
+
+NEW_METRICS = (
+    "node.verify_rtt_ms", "remote.rtt_ms", "sidecar.request_ms", "sidecar.queue_ms",
+    "sidecar.loop_us_per_sig", "sidecar.loop_cpu_share", "node.loop_cpu_share",
+    "verifier.stage_ms", "verifier.readback_ms",
+)
+# what a program older than these spans still has (the parent commit)
+OLD_NAMES = {"scheduler.queue_mempool_s", "verifier.stage_s", "verifier.readback_s"}
+
+
+def _snap(counters=None, hists=None):
+    return {
+        "counters": dict(counters or {}),
+        "histograms": {k: {"sum": s, "count": c} for k, (s, c) in (hists or {}).items()},
+    }
+
+
+def _src(keep=None):
+    """Window [100, 140); snapshots 0.5 s before each edge and one after
+    the close, 40 s apart. Since-boot sums are large (an 83.8 s warm-up
+    sample): only the difference may show. `keep`: the names a snapshot
+    holds (None: all)."""
+
+    def pick(d):
+        return d if keep is None else {k: v for k, v in d.items() if k in keep}
+
+    def log(first, last):
+        snaps = [(99.5, first), (139.5, last), (141.0, last)]
+        return {"snapshots": [
+            (t, _snap(pick(s["counters"]), pick(s["hists"]))) for t, s in snaps
+        ]}
+
+    sidecar = log(
+        {"counters": {"sidecar.request_sigs": 500_000, "runtime.loop_cpu_s": 31.0},
+         "hists": {"sidecar.request_s": (90.0, 300), "scheduler.queue_mempool_s": (2.0, 100),
+                   "sidecar.parse_s": (3.0, 300), "service.collect_s": (4.0, 120),
+                   "sidecar.reply_s": (0.5, 300), "verifier.stage_s": (83.8, 10),
+                   "verifier.readback_s": (1.0, 10)}},
+        {"counters": {"sidecar.request_sigs": 600_000, "runtime.loop_cpu_s": 51.0},
+         "hists": {"sidecar.request_s": (99.0, 360), "scheduler.queue_mempool_s": (3.2, 140),
+                   "sidecar.parse_s": (3.5, 360), "service.collect_s": (5.0, 160),
+                   "sidecar.reply_s": (0.6, 360), "verifier.stage_s": (87.2, 210),
+                   "verifier.readback_s": (11.4, 210)}},
+    )
+    node0 = log(
+        {"counters": {"runtime.loop_cpu_s": 10.0},
+         "hists": {"mempool.verify_rtt_s": (10.0, 100), "crypto.remote_rtt_s": (1.0, 10)}},
+        {"counters": {"runtime.loop_cpu_s": 18.0},
+         "hists": {"mempool.verify_rtt_s": (30.0, 140), "crypto.remote_rtt_s": (5.0, 30)}},
+    )
+    node1 = log(
+        {"counters": {"runtime.loop_cpu_s": 2.0},
+         "hists": {"mempool.verify_rtt_s": (5.0, 50), "crypto.remote_rtt_s": (0.0, 0)}},
+        {"counters": {"runtime.loop_cpu_s": 32.0},
+         "hists": {"mempool.verify_rtt_s": (25.0, 60), "crypto.remote_rtt_s": (2.0, 10)}},
+    )
+    return {"window": {"t0": 100.0, "t1": 140.0, "seconds": 40.0},
+            "sidecar": sidecar, "nodes": [node0, node1]}
+
+
+# by hand: pooled over nodes (20 + 20) s / (40 + 10); (4 + 2) / (20 + 10);
+# 9 s / 60; 1.2 s / 40; (0.5 + 1.0 + 0.1) s / 100,000; 20 s / 40 s;
+# max(8, 30) s / 40 s; 3.4 s / 200; 10.4 s / 200
+EXPECTED = dict(zip(NEW_METRICS, (800.0, 200.0, 150.0, 30.0, 16.0, 50.0, 75.0, 17.0, 52.0)))
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_reader_is_a_window_mean(name):
+    from chipbench import run
+
+    read = run.load_reader("per_layer", name)
+    assert read(_src()) == pytest.approx(EXPECTED[name])
+    # no snapshots around the window: nothing to read
+    empty = _src()
+    for log in [empty["sidecar"], *empty["nodes"]]:
+        log["snapshots"] = []
+    assert read(empty) is None
+    # a program older than the span: what it has reads as before, the rest 0
+    old = read(_src(keep=OLD_NAMES))
+    still = {"sidecar.queue_ms", "verifier.stage_ms", "verifier.readback_ms"}
+    assert old == (pytest.approx(EXPECTED[name]) if name in still else 0.0)
+
+
+def test_every_cell_reports_the_new_metrics():
+    from chipbench import run
+
+    bench = run.load_benchmark()
+    for cell in bench["workloads"]:
+        names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
+        assert set(NEW_METRICS) <= set(names), cell["name"]
+    # added at the end, in the table's order
+    assert tuple(m["name"] for m in bench["per_layer"][-len(NEW_METRICS):]) == NEW_METRICS
+
+
+def test_every_new_name_is_in_the_namespace():
+    declared = {name for name, _kind, _b in metrics._DEFAULT_NAMESPACE}
+    assert {
+        "sidecar.requests", "sidecar.request_sigs", "sidecar.parse_s", "sidecar.reply_s",
+        "sidecar.request_s", "service.collect_s", "service.backend_s",
+        "crypto.remote_rtt_s", "mempool.verify_rtt_s", "runtime.loop_cpu_s",
+    } <= declared
+    # a phase's histogram is its profiler name plus `_s`
+    for name in timeline.PHASES.values():
+        assert name + "_s" in declared, name
