@@ -1360,16 +1360,17 @@ class ChaosOrchestrator:
         """How many adversary-forged (msg, pk, sig) triples ended up in any
         honest node's VerifiedSigCache — must be ZERO (only successes are
         cached, and a forged signature never verifies)."""
-        forged: list[tuple[bytes, bytes, bytes]] = []
-        for i in self.byzantine:
-            policy = getattr(self.nodes[i], "policy", None)
-            for msg, pk, sig in getattr(policy, "forged", ()):
-                forged.append((msg, pk.data, sig.data))
+        forged = [
+            triple
+            for i in self.byzantine
+            for triple in getattr(
+                getattr(self.nodes[i], "policy", None), "forged", ()
+            )
+        ]
         count = 0
         for i in self.honest:
             service = self.nodes[i].service
             if service is None or service.dedup is None:
                 continue
-            entries = service.dedup._entries
-            count += sum(1 for t in forged if t in entries)
+            count += sum(1 for t in forged if service.dedup.holds(*t))
         return count

@@ -13,7 +13,33 @@ import abc
 import threading
 from typing import Sequence
 
+import numpy as np
+
 from .primitives import InvalidSignature, PublicKey, Signature
+
+# A verify request kept columnar (crypto/remote.py): n wire records of a
+# 32-byte message as ONE (n, 128) uint8 array, msg | pk | sig in the wire's
+# own order. It reaches a backend as the array's three column views.
+ROW = 128
+
+
+def row_columns(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(n, 128) rows -> their message, key and signature columns (views)."""
+    return rows[:, :32], rows[:, 32:64], rows[:, 64:]
+
+
+def columns_to_lists(
+    messages: np.ndarray, keys: np.ndarray, signatures: np.ndarray
+) -> tuple[list[bytes], list[PublicKey], list[Signature]]:
+    """The three columns as the sequences every backend takes: where a
+    columnar batch meets code that wants objects (a host backend, a
+    bucket shared with list groups), it is taken apart here."""
+    m, k, s = messages.tobytes(), keys.tobytes(), signatures.tobytes()
+    return (
+        [m[i : i + 32] for i in range(0, len(m), 32)],
+        [PublicKey(k[i : i + 32]) for i in range(0, len(k), 32)],
+        [Signature(s[i : i + 64]) for i in range(0, len(s), 64)],
+    )
 
 
 class CryptoBackend(abc.ABC):
@@ -25,6 +51,11 @@ class CryptoBackend(abc.ABC):
     one Byzantine vote is bad)."""
 
     name: str = "abstract"
+    # True on a backend whose `verify_batch_mask` also takes the three uint8
+    # column arrays of a columnar batch (`row_columns`) and may then answer
+    # with a bool array; BatchVerificationService probes it and hands every
+    # other backend `columns_to_lists` of them.
+    accepts_columns = False
 
     @abc.abstractmethod
     def verify_batch_mask(

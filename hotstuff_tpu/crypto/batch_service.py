@@ -26,6 +26,13 @@ is in flight — the same pipelining the reference gets from tokio). Groups
 are enqueued whole (one lane entry, one future per group), so per-item
 asyncio overhead is O(1) per group, not O(n) — at 100k+ sigs/s the Python
 queue would otherwise dominate the TPU kernel.
+
+A group is either three lists (every caller inside a node) or, from the
+sidecar's wire (crypto/remote.py), one (n, 128) uint8 array of rows msg |
+pk | sig (`verify_rows`). A bucket of columnar groups stays one array from
+the flatten through the cache scan to the backend's three column views, and
+its mask is one bool array; a bucket that mixes the kinds takes the list
+path. One cache serves both, keyed by the bytes msg + pk + sig.
 """
 
 from __future__ import annotations
@@ -38,9 +45,17 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Sequence
 
+import numpy as np
+
 from ..ops import timeline
 from ..utils import metrics, tracing
-from .backend import CryptoBackend, get_backend
+from .backend import (
+    ROW,
+    CryptoBackend,
+    columns_to_lists,
+    get_backend,
+    row_columns,
+)
 from .primitives import PublicKey, Signature
 from .scheduler import (
     DeviceScheduler,
@@ -58,6 +73,8 @@ _M_DEDUP_INSERTS = metrics.counter("verifier.dedup_inserts")
 _M_DEDUP_EVICTIONS = metrics.counter("verifier.dedup_evictions")
 _M_COLLECT = metrics.histogram("service.collect_s")
 _M_BACKEND = metrics.histogram("service.backend_s")
+_M_SCATTER = metrics.histogram("service.scatter_s")
+_ROW_BYTES = np.dtype((np.void, ROW))
 
 
 class VerifiedSigCache:
@@ -70,6 +87,13 @@ class VerifiedSigCache:
     nothing), and the triple is the full (message, key, signature) — a
     forged signature over the same digest can never alias a cached entry.
 
+    One key format: the bytes message + pk + sig. pk and sig are
+    fixed-width suffixes, so it is unambiguous for any message length, and
+    for a columnar request (crypto/remote.py) it is a row as it came off
+    the wire. A bucket is scanned, and its verified misses inserted, under
+    one lock acquisition each (`scan`, `add_many`); the LRU order and the
+    four counters are those of the same items taken one by one.
+
     Thread-safe: the consensus event loop seeds it while backend dispatch
     worker threads look entries up.
     """
@@ -80,39 +104,63 @@ class VerifiedSigCache:
         if maxsize <= 0:
             raise ValueError("dedup cache needs maxsize >= 1")
         self.maxsize = maxsize
-        self._entries: OrderedDict[tuple[bytes, bytes, bytes], None] = (
-            OrderedDict()
-        )
+        self._entries: OrderedDict[bytes, None] = OrderedDict()
         self._lock = threading.Lock()
 
     def __len__(self) -> int:
         return len(self._entries)
 
+    @staticmethod
+    def key(message: bytes, key: PublicKey, sig: Signature) -> bytes:
+        return message + key.data + sig.data
+
+    def holds(self, message: bytes, key: PublicKey, sig: Signature) -> bool:
+        """Membership alone: no recency refresh, no counter (audits)."""
+        return self.key(message, key, sig) in self._entries
+
+    def scan(self, keys: Sequence[bytes | None]) -> list[int]:
+        """Indices, in order, of the keys that did NOT previously verify;
+        each hit refreshes LRU recency. A None (an item opted out of the
+        cache) is a miss and counts neither way; the others count into
+        verifier.dedup_hits/misses."""
+        entries = self._entries
+        miss: list[int] = []
+        with self._lock:
+            for i, k in enumerate(keys):
+                if k in entries:
+                    entries.move_to_end(k)
+                else:
+                    miss.append(i)
+        _M_DEDUP_HITS.inc(len(keys) - len(miss))
+        _M_DEDUP_MISSES.inc(len(miss) - keys.count(None))
+        return miss
+
+    def add_many(self, keys: Sequence[bytes]) -> None:
+        """Record VERIFIED triples in order; evicts least-recently-used
+        past maxsize (memory stays bounded at ~128 B/entry)."""
+        entries = self._entries
+        inserts = evictions = 0
+        with self._lock:
+            for k in keys:
+                if k in entries:
+                    entries.move_to_end(k)
+                    continue
+                entries[k] = None
+                inserts += 1
+                while len(entries) > self.maxsize:
+                    entries.popitem(last=False)
+                    evictions += 1
+        _M_DEDUP_INSERTS.inc(inserts)
+        _M_DEDUP_EVICTIONS.inc(evictions)
+
     def hit(self, message: bytes, key: PublicKey, sig: Signature) -> bool:
         """True iff this exact triple previously verified (refreshes LRU
         recency); counts into verifier.dedup_hits/misses."""
-        k = (message, key.data, sig.data)
-        with self._lock:
-            if k in self._entries:
-                self._entries.move_to_end(k)
-                _M_DEDUP_HITS.inc()
-                return True
-        _M_DEDUP_MISSES.inc()
-        return False
+        return not self.scan((self.key(message, key, sig),))
 
     def add(self, message: bytes, key: PublicKey, sig: Signature) -> None:
-        """Record a VERIFIED triple; evicts least-recently-used past
-        maxsize (memory stays bounded at ~128 B/entry)."""
-        k = (message, key.data, sig.data)
-        with self._lock:
-            if k in self._entries:
-                self._entries.move_to_end(k)
-                return
-            self._entries[k] = None
-            _M_DEDUP_INSERTS.inc()
-            while len(self._entries) > self.maxsize:
-                self._entries.popitem(last=False)
-                _M_DEDUP_EVICTIONS.inc()
+        """Record one VERIFIED triple."""
+        self.add_many((self.key(message, key, sig),))
 
 
 @dataclass
@@ -142,10 +190,14 @@ class _Group:
     # a group that is no wire request: `service.collect` names the
     # requests it merged by it.
     rid: int = 0
+    # A columnar group (`verify_rows`) carries its (n, 128) uint8 rows
+    # msg | pk | sig here and leaves the three lists empty; its future
+    # resolves to a bool array, a list group's to list[bool].
+    rows: np.ndarray | None = None
     future: asyncio.Future = field(default_factory=lambda: asyncio.get_running_loop().create_future())
 
     def __len__(self) -> int:
-        return len(self.messages)
+        return len(self.messages) if self.rows is None else len(self.rows)
 
 
 class BatchVerificationService:
@@ -284,20 +336,37 @@ class BatchVerificationService:
         sidecar's number of the wire request the group came in."""
         if not messages:
             return []
-        self._ensure_task()
         cls = resolve_source(source, urgent)
-        group = _Group(
-            list(messages),
-            [pk for pk, _ in pairs],
-            [sig for _, sig in pairs],
-            cls.preemptive,
-            committee,
-            dedup,
-            trace,
-            cls.name,
-            asyncio.get_running_loop().time(),
-            rid=rid,
+        return await self._submit(
+            _Group(
+                list(messages),
+                [pk for pk, _ in pairs],
+                [sig for _, sig in pairs],
+                cls.preemptive,
+                committee,
+                dedup,
+                trace,
+                cls.name,
+                rid=rid,
+            )
         )
+
+    async def verify_rows(
+        self, rows: np.ndarray, urgent: bool = False, rid: int = 0
+    ) -> np.ndarray:
+        """`verify_group` for a request that stayed columnar
+        (crypto/remote.py): `rows` is its (n, 128) uint8 array msg | pk |
+        sig, n >= 1, and the mask comes back as one bool array. Same lanes,
+        same cache, same backend call; the rows are never taken apart into
+        objects unless the bucket they land in holds a list group too."""
+        cls = resolve_source(None, urgent)
+        return await self._submit(
+            _Group([], [], [], cls.preemptive, source=cls.name, rid=rid, rows=rows)
+        )
+
+    async def _submit(self, group: _Group):
+        self._ensure_task()
+        group.t_submit = asyncio.get_running_loop().time()
         if self.scheduler is not None:
             self.scheduler.submit(group)
         else:
@@ -413,20 +482,18 @@ class BatchVerificationService:
         if not urgent:
             await self._dispatch_sem.acquire()
         try:
-            # One synchronous section of the event loop (no await inside:
-            # ops/timeline.py's asyncio rule): the flatten and the dedup
-            # scan, item by item, up to the backend call. The verifier's
-            # chunk spans under the call share its batch number.
+            # Two synchronous sections of the event loop (no await inside:
+            # ops/timeline.py's asyncio rule), `collect` up to the backend
+            # call and `scatter` after it. The verifier's chunk spans under
+            # the call share their batch number.
+            batch = timeline.open_batch()
             rids = [g.rid for g in groups if g.rid]
             collect = timeline.span(
-                "collect", timeline.open_batch(), 0, total, hist=_M_COLLECT,
+                "collect", batch, 0, total, hist=_M_COLLECT,
                 groups=len(groups),
                 rid_first=min(rids, default=0), rid_last=max(rids, default=0),
             )
             with collect:
-                msgs = [m for g in groups for m in g.messages]
-                keys = [k for g in groups for k in g.keys]
-                sigs = [s for g in groups for s in g.signatures]
                 # backend_idx > 0 is a scheduler steal: the bucket rides a
                 # sibling shard's pipeline. Committee routing still resolves
                 # per backend (an unregistered steal target just takes the
@@ -436,45 +503,37 @@ class BatchVerificationService:
                     if backend_idx == 0
                     else self._steal_backends[backend_idx - 1]
                 )
-
                 # Verified-signature dedup: triples the aggregator (or an
                 # earlier flush) already validated resolve True without
-                # touching the backend; only misses dispatch. Per-item
-                # eligibility: a flush may mix dedup-opted-out synthetic
-                # groups with consensus traffic. The scan (and the index-
-                # gather re-copy) is skipped entirely when no group opted in
-                # or nothing hit — the synthetic throughput path pays zero.
+                # touching the backend; only misses dispatch. The scan (and
+                # the index-gather re-copy) is skipped entirely when no
+                # group opted in or nothing hit — the synthetic throughput
+                # path pays zero.
                 cache = self.dedup if any(g.dedup for g in groups) else None
-                mask = [False] * len(msgs)
-                miss = range(len(msgs))
-                dedupable = None
-                if cache is not None:
-                    dedupable = [g.dedup for g in groups for _ in range(len(g))]
-                    miss = []
-                    for i, (m, k, s) in enumerate(zip(msgs, keys, sigs)):
-                        if dedupable[i] and cache.hit(m, k, s):
-                            mask[i] = True
-                        else:
-                            miss.append(i)
+                # A bucket of columnar groups alone stays one array; one
+                # that mixes the two kinds takes the list path.
+                columnar = all(g.rows is not None for g in groups)
+                if columnar:
+                    mask, miss, keys, args = _collect_rows(
+                        groups, cache,
+                        getattr(backend, "accepts_columns", False),
+                    )
+                else:
+                    mask, miss, keys, args = _collect_lists(groups, cache)
                 collect.set(miss=len(miss))
-                if miss:
-                    full = len(miss) == len(msgs)
-                    kwargs = {}
-                    if all(g.committee for g in groups) and getattr(
-                        backend, "supports_committee_routing", False
-                    ):
-                        kwargs["committee"] = True
-                    m = msgs if full else [msgs[i] for i in miss]
-                    k = keys if full else [keys[i] for i in miss]
-                    s = sigs if full else [sigs[i] for i in miss]
-            if miss:
+                kwargs = {}
+                if all(g.committee for g in groups) and getattr(
+                    backend, "supports_committee_routing", False
+                ):
+                    kwargs["committee"] = True
+            if len(miss):
                 t0 = time.perf_counter()
                 try:
                     if self.inline:
-                        sub = backend.verify_batch_mask(m, k, s, **kwargs)
+                        sub = backend.verify_batch_mask(*args, **kwargs)
                     else:
                         sub = await asyncio.to_thread(
-                            backend.verify_batch_mask, m, k, s, **kwargs
+                            backend.verify_batch_mask, *args, **kwargs
                         )
                 except Exception as exc:  # backend failure must not hang callers
                     for g in groups:
@@ -483,36 +542,117 @@ class BatchVerificationService:
                     return
                 dur = time.perf_counter() - t0
                 _M_BACKEND.record(dur)
-                if tracing.enabled():
-                    # One verify.batch event per traced group in the flush
-                    # (batch tags + the group's scheduler lane and queueing
-                    # delay, the per-class attribution trace_report.py's
-                    # verify-lane table aggregates), plus a watchdog sample
-                    # of the flush's per-signature cost.
-                    for g in groups:
-                        if g.trace is not None:
-                            tracing.event(
-                                "verify.batch", g.trace, dur,
-                                n=len(g), flush=len(miss), lane=g.source,
-                                queue_s=round(
-                                    max(0.0, g.t_dequeue - g.t_submit), 6
-                                ),
-                            )
-                    tracing.WATCHDOG.note_verify(dur, len(miss))
-                for i, ok in zip(miss, sub):
-                    mask[i] = bool(ok)
-                    if ok and cache is not None and dedupable[i]:
-                        cache.add(msgs[i], keys[i], sigs[i])
-            self.stats["flushes"] += 1
-            self.stats["size_flushes"] += total >= self.max_batch
-            self.stats["urgent_flushes"] += urgent
-            self.stats["verified"] += total
-            lo = 0
-            for g in groups:
-                hi = lo + len(g)
-                if not g.future.cancelled():
-                    g.future.set_result([bool(b) for b in mask[lo:hi]])
-                lo = hi
+            with timeline.span("scatter", batch, 0, total, hist=_M_SCATTER):
+                if len(miss):
+                    if tracing.enabled():
+                        # One verify.batch event per traced group in the
+                        # flush (batch tags + the group's scheduler lane and
+                        # queueing delay, the per-class attribution
+                        # trace_report.py's verify-lane table aggregates),
+                        # plus a watchdog sample of the flush's
+                        # per-signature cost.
+                        for g in groups:
+                            if g.trace is not None:
+                                tracing.event(
+                                    "verify.batch", g.trace, dur,
+                                    n=len(g), flush=len(miss), lane=g.source,
+                                    queue_s=round(
+                                        max(0.0, g.t_dequeue - g.t_submit), 6
+                                    ),
+                                )
+                        tracing.WATCHDOG.note_verify(dur, len(miss))
+                    # Only what verified now is inserted, in lane order.
+                    if columnar:
+                        sub = np.asarray(sub, bool)
+                        mask[miss] = sub
+                        verified = miss[sub].tolist()
+                    else:
+                        verified = []
+                        for i, ok in zip(miss, sub):
+                            if ok:
+                                mask[i] = True
+                                verified.append(i)
+                    if keys is not None:
+                        cache.add_many(
+                            [keys[i] for i in verified if keys[i] is not None]
+                        )
+                self.stats["flushes"] += 1
+                self.stats["size_flushes"] += total >= self.max_batch
+                self.stats["urgent_flushes"] += urgent
+                self.stats["verified"] += total
+                lo = 0
+                for g in groups:
+                    hi = lo + len(g)
+                    if not g.future.cancelled():
+                        part = mask[lo:hi]
+                        g.future.set_result(
+                            np.asarray(part, bool)
+                            if g.rows is not None
+                            else [bool(b) for b in part]
+                        )
+                    lo = hi
         finally:
             if not urgent:
                 self._dispatch_sem.release()
+
+
+def _collect_lists(groups: list[_Group], cache: VerifiedSigCache | None):
+    """Flatten a bucket item by item: (mask with the cache's hits set, the
+    lanes still to verify, the lanes' cache keys or None when no group uses
+    the cache, the backend call's three arguments). A columnar group that
+    shares the bucket is taken apart here."""
+    parts = [
+        (g.messages, g.keys, g.signatures)
+        if g.rows is None
+        else columns_to_lists(*row_columns(g.rows))
+        for g in groups
+    ]
+    msgs = [m for p in parts for m in p[0]]
+    pks = [k for p in parts for k in p[1]]
+    sigs = [s for p in parts for s in p[2]]
+    mask = [False] * len(msgs)
+    if cache is None:
+        return mask, range(len(msgs)), None, (msgs, pks, sigs)
+    # Per-item eligibility: a flush may mix dedup-opted-out synthetic
+    # groups with consensus traffic.
+    dedupable = [g.dedup for g in groups for _ in range(len(g))]
+    keys = [
+        cache.key(m, k, s) if d else None
+        for m, k, s, d in zip(msgs, pks, sigs, dedupable)
+    ]
+    miss = cache.scan(keys)
+    if len(miss) == len(msgs):
+        return mask, miss, keys, (msgs, pks, sigs)
+    mask = [True] * len(msgs)
+    for i in miss:
+        mask[i] = False
+    return mask, miss, keys, (
+        [msgs[i] for i in miss], [pks[i] for i in miss], [sigs[i] for i in miss]
+    )
+
+
+def _collect_rows(
+    groups: list[_Group], cache: VerifiedSigCache | None, as_columns: bool
+):
+    """`_collect_lists` for a bucket of columnar groups: one array from the
+    flatten to the backend's arguments, its three column views, or
+    `columns_to_lists` of them for a backend that takes no columns; the mask
+    and the miss lanes are arrays, a cache key is a row's bytes."""
+    rows = (
+        groups[0].rows
+        if len(groups) == 1
+        else np.concatenate([g.rows for g in groups])
+    )
+    mask = np.zeros(len(rows), bool)
+    miss = np.arange(len(rows))
+    keys = None
+    if cache is not None:
+        # one bytes object a row, made in numpy (void items: nothing stripped)
+        keys = np.ascontiguousarray(rows).view(_ROW_BYTES).ravel().tolist()
+        miss = np.array(cache.scan(keys), np.intp)
+        if len(miss) < len(rows):
+            mask[:] = True
+            mask[miss] = False
+            rows = rows[miss]
+    args = row_columns(rows)
+    return mask, miss, keys, args if as_columns else columns_to_lists(*args)

@@ -23,8 +23,10 @@ import logging
 import threading
 from typing import Sequence
 
+import numpy as np
+
 from ..utils import metrics
-from .backend import CpuBackend, CryptoBackend
+from .backend import CpuBackend, CryptoBackend, columns_to_lists
 from .primitives import PublicKey, Signature
 
 log = logging.getLogger("hotstuff.crypto")
@@ -56,6 +58,10 @@ class TpuBackend(CryptoBackend):
     name = "tpu"
     # BatchVerificationService probes this to tag committee flushes.
     supports_committee_routing = True
+    # A columnar batch (crypto/backend.py `row_columns`) goes through
+    # `verify_batch_mask` like any other and stays arrays down to the
+    # verifier's staging.
+    accepts_columns = True
 
     def __init__(
         self,
@@ -322,11 +328,24 @@ class TpuBackend(CryptoBackend):
         registered table, the lower `committee_crossover` governs the CPU
         fallback, and the batch rides the committee kernel. Batches with
         any unregistered key (or no registration) fall back to the generic
-        path — correctness never depends on the tag."""
+        path — correctness never depends on the tag.
+
+        The three arguments may be the uint8 column arrays of one columnar
+        batch; above the crossover they reach the verifier as they are and
+        the mask comes back as a bool array. The committee table, the
+        host's OpenSSL and the f32 argument path want objects."""
         n = len(messages)
         if n == 0:
             return []
         _M_BATCH_SIZE.record(n)
+        columnar = isinstance(messages, np.ndarray)
+        if columnar and (
+            committee or n < self.crossover or not self._verifier.packed
+        ):
+            messages, keys, signatures = columns_to_lists(
+                messages, keys, signatures
+            )
+            columnar = False
         # Resolve committee routing BEFORE the crossover decision: the
         # committee kernel's cheaper per-batch cost earns it a lower
         # CPU/device break-even than the generic path.
@@ -373,17 +392,20 @@ class TpuBackend(CryptoBackend):
             ).tolist()
             self._count_rejections(mask, True)
             return mask
-        mask = self._verifier.verify_batch_mask(
-            list(messages),
-            [k.data for k in keys],
-            [s.data for s in signatures],
-        ).tolist()
+        if columnar:
+            mask = self._verifier.verify_batch_mask(messages, keys, signatures)
+        else:
+            mask = self._verifier.verify_batch_mask(
+                list(messages),
+                [k.data for k in keys],
+                [s.data for s in signatures],
+            ).tolist()
         self._count_rejections(mask, False)
         return mask
 
     @staticmethod
     def _count_rejections(mask: Sequence[bool], committee: bool) -> None:
-        bad = sum(1 for ok in mask if not ok)
+        bad = len(mask) - int(np.count_nonzero(mask))
         if bad:
             _M_REJECTED.inc(bad)
             if committee:

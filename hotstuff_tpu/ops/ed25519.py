@@ -756,6 +756,21 @@ def prepare_batch_packed_dh(
     return dict(packed=packed, s_ok=_s_canonical_mask(s))
 
 
+def prepare_rows_packed_dh(
+    messages: np.ndarray, keys: np.ndarray, signatures: np.ndarray
+) -> dict:
+    """`prepare_batch_packed_dh`, byte for byte, of a columnar batch: the
+    (n, 32) message, (n, 32) key and (n, 64) signature uint8 columns of the
+    sidecar's wire rows (crypto/backend.py `row_columns`, any strides).
+    Three strided copies into the (128, n) wire array and the vectorized
+    s < L check: no Python object per signature, no join."""
+    packed = np.empty((128, len(messages)), np.uint8)
+    packed[0:32] = keys.T
+    packed[32:96] = signatures.T
+    packed[96:128] = messages.T
+    return dict(packed=packed, s_ok=_s_canonical_mask(signatures[:, 32:]))
+
+
 def prepare_batch_committee(
     messages: Sequence[bytes],
     key_bytes: Sequence[bytes],
@@ -1166,13 +1181,20 @@ class Ed25519TpuVerifier:
         # pure byte concatenation. Other lengths ride the host-hash twin,
         # a separate program compiled on first use. A device-hash failure
         # raises like any other device error — no rerun with host hashing.
-        device_hash = all(len(m) == 32 for m in messages)
+        # A columnar batch (three uint8 column arrays) is 32-byte messages
+        # by its shape.
+        device_hash = isinstance(messages, np.ndarray) or all(
+            len(m) == 32 for m in messages
+        )
         return self._run_packed(messages, keys, signatures, device_hash)
 
     def _run_packed(self, messages, keys, signatures, device_hash: bool):
         n = len(messages)
         fn = self._packed_dh_fn() if device_hash else self._packed_fn()
-        stage_fn = prepare_batch_packed_dh if device_hash else prepare_batch_packed
+        if isinstance(messages, np.ndarray):
+            stage_fn = prepare_rows_packed_dh
+        else:
+            stage_fn = prepare_batch_packed_dh if device_hash else prepare_batch_packed
         program = self.program_name(False, device_hash)
         tl_batch = timeline.batch_id()
         pool = self.pipeline.pool

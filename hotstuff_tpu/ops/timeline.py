@@ -62,13 +62,14 @@ Two properties of the third sink:
     the profiler's clock `verifier.readback` is the time the worker
     really blocked on the result, which is what gap attribution wants.
 
-Request-level phases (`parse`, `collect`, `reply`) share the ring with the
-chunk phases; `summary()` reads the chunk phases alone. Spans of one
+Request-level phases (`parse`, `collect`, `scatter`, `reply`) share the ring
+with the chunk phases; `summary()` reads the chunk phases alone. Spans of one
 bucket share `batch` (`open_batch` in the service's dispatch, `batch_id`
 in the verifier: `asyncio.to_thread` carries the context over); a
 request's `parse` and `reply` carry its `rid` as their `batch`, and
-`collect` names the rids it merged, so a request can be followed from
-parse to chunk to reply.
+`collect` names the rids it merged (`scatter`, the section after the
+backend call, shares its batch), so a request can be followed from parse
+to chunk to reply.
 
 Dependency-free by design: stdlib + utils.metrics/tracing only — no jax
 (the graftlint tool and the chaos/telemetry planes import this
@@ -108,7 +109,7 @@ __all__ = [
 # Ring phase -> the span's name on the profiler's clock (its histogram
 # adds `_s`). First the four pipeline phases of one verifier chunk, in
 # pipeline order: `stage` is host CPU (numpy/C++ wire-format staging); the
-# other three face the device and define occupancy. Then the three
+# other three face the device and define occupancy. Then the four
 # synchronous event-loop sections of one sidecar request. The names are
 # matched by the benchmark's trace reduction: final.
 PHASES: dict[str, str] = {
@@ -118,6 +119,7 @@ PHASES: dict[str, str] = {
     "readback": "verifier.readback",
     "parse": "sidecar.parse",
     "collect": "service.collect",
+    "scatter": "service.scatter",
     "reply": "sidecar.reply",
 }
 CHUNK_PHASES: tuple[str, ...] = ("stage", "upload", "dispatch", "readback")

@@ -588,13 +588,16 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     # the two synchronous event-loop sections, the whole request
     ("sidecar.requests", "counter", None),
     ("sidecar.request_sigs", "counter", None),
+    ("sidecar.columnar_sigs", "counter", None),
     ("sidecar.parse_s", "histogram", None),
     ("sidecar.reply_s", "histogram", None),
     ("sidecar.request_s", "histogram", None),
     # crypto/batch_service.py — one dispatch: flatten + dedup scan on the
-    # event loop, then the thread hop and the backend call
+    # event loop, the thread hop and the backend call, then the mask's
+    # scatter, the cache inserts and the futures on the loop again
     ("service.collect_s", "histogram", None),
     ("service.backend_s", "histogram", None),
+    ("service.scatter_s", "histogram", None),
     # crypto/scheduler.py — continuous-batching device scheduler. One
     # queue-delay histogram PER REGISTERED SOURCE CLASS: the starvation
     # lint (the graftlint `scheduler` pass) fails if a class in

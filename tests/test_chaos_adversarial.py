@@ -90,7 +90,7 @@ def test_forged_burst_rejected_on_committee_path_and_never_cached(
         # Dedup cache: valid triples cached, every forged triple absent.
         cache = service.dedup
         for (m, (pk, sig)), ok in zip(zip(msgs, pairs), want):
-            cached = (m, pk.data, sig.data) in cache._entries
+            cached = cache.holds(m, pk, sig)
             assert cached == ok, (
                 f"forged triple cached={cached} ok={ok} — rejected triples "
                 "must never enter the VerifiedSigCache"

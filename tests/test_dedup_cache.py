@@ -190,3 +190,174 @@ class TestServiceDedup:
             assert StrictBackend.verified == 1
 
         run_async(body())
+
+
+# -- columnar groups (verify_rows) beside list groups --------------------------
+
+
+def _row(i: int) -> bytes:
+    m, pk, sig = _triple(i)
+    return m + pk.data + sig.data
+
+
+def _rows(ids):
+    import numpy as np
+
+    return np.frombuffer(b"".join(_row(i) for i in ids), np.uint8).reshape(-1, 128)
+
+
+class _OddIdsBackend(CryptoBackend):
+    """Verifies a `_triple(i)` iff i is odd; takes lists only, so the
+    service has to take a columnar bucket apart for it."""
+
+    name = "odd"
+
+    def __init__(self):
+        self.calls: list[list[int]] = []
+
+    def verify_batch_mask(self, messages, keys, signatures):
+        assert all(type(m) is bytes for m in messages)
+        assert all(type(k) is PublicKey for k in keys)
+        assert all(type(s) is Signature for s in signatures)
+        ids = [m[0] for m in messages]
+        assert [k.data[0] for k in keys] == ids
+        assert [s.data[0] for s in signatures] == ids
+        self.calls.append(ids)
+        return [i % 2 == 1 for i in ids]
+
+
+_DEDUP_COUNTERS = ("hits", "misses", "inserts", "evictions")
+
+
+def _dedup_counters():
+    return [metrics.counter(f"verifier.dedup_{n}").value for n in _DEDUP_COUNTERS]
+
+
+# buckets of one sequence: duplicates inside a bucket, repeats across
+# buckets, failures (even ids), more distinct successes than the cache holds
+_SEQUENCE = ([1, 2, 3, 3, 5], [3, 1, 7, 2, 9, 11], [13, 15, 1, 17], [5, 3, 19, 4, 4])
+
+
+class TestColumnarGroups:
+    @pytest.mark.parametrize("columnar", [False, True], ids=["lists", "rows"])
+    def test_same_cache_trajectory_either_path(self, columnar, run_async):
+        """Masks, backend calls, LRU order, evictions and the four counters
+        of the columnar path are the list path's on the same sequence; a
+        failure is never cached."""
+
+        async def drive(as_rows):
+            backend = _OddIdsBackend()
+            svc = BatchVerificationService(
+                backend, max_delay=0.001, dedup_cache_size=4
+            )
+            before = _dedup_counters()
+            masks = []
+            for ids in _SEQUENCE:
+                if as_rows:
+                    mask = await svc.verify_rows(_rows(ids))
+                    assert mask.dtype == bool
+                    masks.append(mask.tolist())
+                else:
+                    t = [_triple(i) for i in ids]
+                    masks.append(await svc.verify_group(
+                        [m for m, _, _ in t], [(pk, s) for _, pk, s in t]
+                    ))
+            moved = [b - a for a, b in zip(before, _dedup_counters())]
+            return masks, backend.calls, list(svc.dedup._entries), moved
+
+        masks, calls, order, moved = run_async(drive(columnar))
+        assert masks == [[i % 2 == 1 for i in ids] for ids in _SEQUENCE]
+        # by hand, cache of 4: bucket 1 misses all five (the duplicate 3
+        # twice), inserts 1 3 5; bucket 2 hits 3 1, inserts 7 (full), 9 and
+        # 11 (evicting 5, 3); bucket 3 hits 1, inserts 13 15 17 (evicting 7
+        # 9 11); bucket 4 misses all five, inserts 5 3 19 (evicting 1 13 15)
+        assert calls == [[1, 2, 3, 3, 5], [7, 2, 9, 11], [13, 15, 17], [5, 3, 19, 4, 4]]
+        assert order == [_row(i) for i in (17, 5, 3, 19)]
+        assert dict(zip(_DEDUP_COUNTERS, moved)) == {
+            "hits": 3, "misses": 17, "inserts": 12, "evictions": 8,
+        }
+
+    def test_triple_cached_by_one_path_hits_by_the_other(self, run_async):
+        async def body():
+            backend = _OddIdsBackend()
+            svc = BatchVerificationService(backend, max_delay=0.001)
+            # list path and the aggregator's seam in, rows out
+            m, pk, sig = _triple(21)
+            assert await svc.verify(m, pk, sig)
+            svc.seed_verified(*_triple(23))
+            assert (await svc.verify_rows(_rows([21, 23, 25]))).tolist() == [True] * 3
+            assert backend.calls == [[21], [25]]
+            # rows in, list path out; the forged sibling of a cached triple
+            # (same message and key, another signature) is a miss
+            assert await svc.verify(*_triple(25))
+            assert backend.calls == [[21], [25]]
+            assert svc.dedup.holds(*_triple(25))
+            assert not svc.dedup.hit(m, pk, Signature(bytes(64)))
+
+        run_async(body())
+
+    def test_mixed_bucket_takes_the_list_path(self, run_async):
+        """A columnar and a list group coalesced into one bucket: one
+        backend call, each group its own slice in its own form, a
+        dedup-opted-out list group neither served nor learned."""
+
+        async def body():
+            backend = _OddIdsBackend()
+            svc = BatchVerificationService(backend, max_delay=0.05)
+            svc.seed_verified(*_triple(31))
+            t = [_triple(i) for i in (31, 32, 33)]
+            got = await asyncio.gather(
+                svc.verify_rows(_rows([31, 33, 34, 35])),
+                svc.verify_group(
+                    [m for m, _, _ in t], [(pk, s) for _, pk, s in t], dedup=False
+                ),
+            )
+            assert got[0].tolist() == [True, True, False, True]
+            assert got[1] == [True, False, True]
+            assert backend.calls == [[33, 34, 35, 31, 32, 33]]
+            assert svc.stats["flushes"] == 1
+            assert svc.dedup.holds(*_triple(35))
+            assert not svc.dedup.holds(*_triple(34))
+
+        run_async(body())
+
+    def test_columnar_bucket_coalesces_into_one_array(self, run_async):
+        """Two columnar groups in one bucket reach a backend that takes
+        columns as three column arrays of one flattened batch."""
+        import numpy as np
+
+        seen = []
+
+        class ColumnBackend(CryptoBackend):
+            name = "columns"
+            accepts_columns = True
+
+            def verify_batch_mask(self, messages, keys, signatures):
+                seen.append((messages, keys, signatures))
+                return messages[:, 0] % 2 == 1
+
+        async def body():
+            svc = BatchVerificationService(ColumnBackend(), max_delay=0.05)
+            svc.seed_verified(*_triple(43))
+            got = await asyncio.gather(
+                svc.verify_rows(_rows([41, 42])), svc.verify_rows(_rows([43, 44, 45]))
+            )
+            assert [g.tolist() for g in got] == [[True, False], [True, False, True]]
+            ((m, k, s),) = seen
+            assert (m.shape, k.shape, s.shape) == ((4, 32), (4, 32), (4, 64))
+            assert all(a.dtype == np.uint8 for a in (m, k, s))
+            assert m[:, 0].tolist() == k[:, 0].tolist() == s[:, 0].tolist() == [41, 42, 44, 45]
+
+        run_async(body())
+
+    def test_forged_audit_reads_the_new_key(self):
+        """chaos `forged_triples_cached` asks `holds`: membership without
+        touching recency or the counters."""
+        cache = VerifiedSigCache(2)
+        cache.add(*_triple(1))
+        cache.add(*_triple(2))
+        before = _dedup_counters()
+        assert cache.holds(*_triple(1)) and not cache.holds(*_triple(3))
+        assert _dedup_counters() == before
+        cache.add(*_triple(3))  # 1 was not refreshed: it goes
+        assert not cache.holds(*_triple(1)) and cache.holds(*_triple(2))

@@ -64,6 +64,33 @@ class TestPackedStaging:
         staged = ed.prepare_batch_packed(msgs, pks, sigs)
         assert staged["s_ok"].tolist() == [True, True, False, True]
 
+    @pytest.mark.parametrize("n", [1, 63, 300])
+    @pytest.mark.parametrize("framed", [False, True], ids=["rows", "wire-view"])
+    def test_rows_staging_equals_device_hash_staging(self, n, framed):
+        """`prepare_rows_packed_dh` of the column views of (n, 128) wire rows
+        msg | pk | sig is `prepare_batch_packed_dh` of the same triples byte
+        for byte, `packed` and `s_ok`, s >= L included; also on the strided
+        view that the sidecar cuts out of a request body (132-byte records)."""
+        msgs, pks, sigs = _signed(n, seed=n)
+        for i, s in ((0, ed.L_ORDER), (n // 2, ed.L_ORDER + 1), (n - 1, 2**256 - 1)):
+            sigs[i] = sigs[i][:32] + int(s).to_bytes(32, "little")
+        if framed:
+            body = b"".join(b"\x20\0\0\0" + m + k + s for m, k, s in zip(msgs, pks, sigs))
+            rows = np.frombuffer(body, np.uint8).reshape(n, 132)[:, 4:]
+            assert rows.strides == (132, 1)
+        else:
+            rows = np.frombuffer(
+                b"".join(m + k + s for m, k, s in zip(msgs, pks, sigs)), np.uint8
+            ).reshape(n, 128)
+        want = ed.prepare_batch_packed_dh(msgs, pks, sigs)
+        got = ed.prepare_rows_packed_dh(rows[:, :32], rows[:, 32:64], rows[:, 64:])
+        assert got["packed"].dtype == np.uint8 and got["packed"].flags.c_contiguous
+        assert got["packed"].tobytes() == want["packed"].tobytes()
+        assert got["packed"].shape == want["packed"].shape == (128, n)
+        assert np.array_equal(got["s_ok"], want["s_ok"])
+        assert not got["s_ok"][[0, n // 2, n - 1]].any()
+        assert got["s_ok"].sum() == n - len({0, n // 2, n - 1})
+
 
 class TestPipelinedVerifier:
     def test_chunked_pipeline_matches_openssl(self):

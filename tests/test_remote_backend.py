@@ -1,6 +1,7 @@
 """Crypto sidecar: RemoteBackend <-> serve() round-trip and fallback."""
 
 import asyncio
+import functools
 import random
 
 import pytest
@@ -210,3 +211,130 @@ def test_boot_line_and_exit_report_name_the_device(monkeypatch, caplog):
     assert "Sidecar device: tpu (TPU v5 lite x1), 1,234 sigs on device" in (
         parser._sidecar_line()
     )
+
+
+# -- the two parses of one request body (crypto/remote.py `_parse`) -----------
+
+
+def _body(records, n=None, tail=b""):
+    """A request body (after the length prefix) of (msg, pk, sig) bytes
+    records; `n` overrides the count word."""
+    import struct
+
+    parts = [struct.pack("<I", len(records) if n is None else n)]
+    for m, pk, sig in records:
+        parts += [struct.pack("<I", len(m)), m, pk, sig]
+    return b"".join(parts) + tail
+
+
+def _seeded_records(n, seed, mlen=lambda i: 32):
+    rng = random.Random(seed)
+    return [
+        (rng.randbytes(mlen(i)), rng.randbytes(32), rng.randbytes(64))
+        for i in range(n)
+    ]
+
+
+@functools.lru_cache(maxsize=None)
+def _parse_cases():
+    import struct
+
+    from hotstuff_tpu.crypto.remote import MAX_MESSAGE_LEN, MAX_REQUEST_ITEMS
+
+    fixed = {
+        f"fixed-{n}": (_body(_seeded_records(n, n)), True)
+        for n in (1, 63, 64, 255, 256, 4097)
+    }
+    odd = lambda bad: lambda i: bad if i == 5 else 32  # noqa: E731
+    return {
+        **fixed,
+        "empty": (_body([]), False),
+        "ragged": (_body(_seeded_records(40, 7, lambda i: i % 70)), False),
+        "one-mlen-31": (_body(_seeded_records(9, 8, odd(31))), False),
+        "one-mlen-33": (_body(_seeded_records(9, 9, odd(33))), False),
+        # 132-byte framing by accident: two records whose lengths sum to 64
+        "31-and-33": (_body(_seeded_records(2, 10, lambda i: 31 + 2 * i)), False),
+        "truncated": (_body(_seeded_records(6, 11))[:-1], None),
+        "truncated-record": (_body(_seeded_records(6, 12))[:-132], None),
+        "trailing": (_body(_seeded_records(6, 13), tail=b"\x00"), None),
+        "trailing-record": (_body(_seeded_records(6, 14), n=5), None),
+        "count-over-cap": (struct.pack("<I", MAX_REQUEST_ITEMS + 1), None),
+        "mlen-over-cap": (
+            struct.pack("<II", 1, MAX_MESSAGE_LEN + 1) + bytes(128),
+            None,
+        ),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_parse_cases()))
+def test_columnar_parse_equals_list_parse(case):
+    """`_parse` accepts and rejects exactly what `_parse_request` does and
+    yields the same triples; only bodies of 32-byte-message records alone
+    stay columnar."""
+    import numpy as np
+
+    from hotstuff_tpu.crypto.backend import columns_to_lists, row_columns
+    from hotstuff_tpu.crypto.remote import _parse, _parse_request
+
+    body, columnar = _parse_cases()[case]
+    if columnar is None:
+        with pytest.raises(ValueError):
+            _parse_request(memoryview(body))
+        with pytest.raises(ValueError):
+            _parse(body)
+        return
+    msgs, pairs = _parse_request(memoryview(body))
+    parsed = _parse(body)
+    assert isinstance(parsed, np.ndarray) == columnar
+    if columnar:
+        assert parsed.shape == (len(msgs), 128) and parsed.dtype == np.uint8
+        assert not parsed.flags.owndata  # a view of the body, no copy
+        m, k, s = columns_to_lists(*row_columns(parsed))
+        parsed = (m, list(zip(k, s)))
+    assert parsed == (msgs, pairs)
+
+
+@pytest.mark.parametrize("mlen", [32, 31], ids=["columnar", "list"])
+def test_live_serve_same_mask_whichever_parse(mlen, run_async, base_port):
+    """One request with invalid lanes through a live `serve`: 32-byte
+    messages take the columnar parse, 31-byte ones the list parse; the mask
+    is OpenSSL's either way, and the counter says which parse ran."""
+    from hotstuff_tpu.crypto import PublicKey
+    from hotstuff_tpu.utils import metrics
+
+    rng = random.Random(mlen)
+    msgs, keys, sigs, want = [], [], [], []
+    for i in range(70):
+        pk, sk = generate_keypair(rng)
+        m = rng.randbytes(mlen)
+        sig = sk.to_crypto().sign(m)
+        if i % 7 == 3:  # a corrupted signature
+            sig = sig[:9] + bytes([sig[9] ^ 1]) + sig[10:]
+        elif i % 7 == 5:  # the wrong key
+            pk = PublicKey(rng.randbytes(32))
+        want.append(i % 7 not in (3, 5))
+        msgs.append(m), keys.append(pk), sigs.append(Signature(sig))
+    assert CpuBackend().verify_batch_mask(msgs, keys, sigs) == want
+    columnar = metrics.counter("sidecar.columnar_sigs")
+    arrived = metrics.counter("sidecar.request_sigs")
+
+    async def body():
+        server = asyncio.create_task(
+            serve(("127.0.0.1", base_port), CpuBackend(), max_delay=0.001)
+        )
+        await asyncio.sleep(0.2)
+        c0, a0 = columnar.value, arrived.value
+        try:
+            backend = RemoteBackend(("127.0.0.1", base_port), crossover=1)
+            for _ in range(2):  # the second time the cache answers the valid
+                mask = await asyncio.to_thread(
+                    backend.verify_batch_mask, msgs, keys, sigs
+                )
+                assert mask == want
+            assert backend.stats["fallback_batches"] == 0
+        finally:
+            server.cancel()
+        assert arrived.value - a0 == 140
+        assert columnar.value - c0 == (140 if mlen == 32 else 0)
+
+    run_async(body())

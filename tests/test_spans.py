@@ -193,15 +193,20 @@ def test_collect_and_verifier_spans_share_one_batch(run_async, no_annotator):
 
     async def body():
         timeline.TIMELINE.reset()
-        before = _hist("service.collect_s").count, _hist("service.backend_s").count
+        hists = ("service.collect_s", "service.backend_s", "service.scatter_s")
+        before = [_hist(h).count for h in hists]
         svc = BatchVerificationService(Backend(), max_delay=0.001)
         pairs = [(PublicKey(bytes(32)), Signature(bytes(64)))] * 5
         assert await svc.verify_group([b"m"] * 5, pairs, dedup=False, rid=17) == [True] * 5
-        collects = [i for i in timeline.TIMELINE.intervals() if i["phase"] == "collect"]
+        ring = timeline.TIMELINE.intervals()
+        collects = [i for i in ring if i["phase"] == "collect"]
         assert len(collects) == 1 and collects[0]["n"] == 5
         assert seen == [collects[0]["batch"]]
-        assert _hist("service.collect_s").count == before[0] + 1
-        assert _hist("service.backend_s").count == before[1] + 1
+        # the section after the backend call: same batch, after the collect
+        (scatter,) = [i for i in ring if i["phase"] == "scatter"]
+        assert (scatter["batch"], scatter["n"]) == (collects[0]["batch"], 5)
+        assert scatter["t0"] >= collects[0]["t1"]
+        assert [_hist(h).count for h in hists] == [c + 1 for c in before]
 
     run_async(body())
 
@@ -263,8 +268,8 @@ def test_loopback_sidecar_counts_requests_and_slot_holds(run_async, base_port):
     from tests.common import keys
     from tests.common_mempool import mempool_committee
 
-    names = ("sidecar.requests", "sidecar.request_sigs", "mempool.synthetic_skipped",
-             "runtime.loop_cpu_s")
+    names = ("sidecar.requests", "sidecar.request_sigs", "sidecar.columnar_sigs",
+             "mempool.synthetic_skipped", "runtime.loop_cpu_s")
     hists = ("sidecar.request_s", "sidecar.parse_s", "sidecar.reply_s",
              "crypto.remote_rtt_s", "mempool.verify_rtt_s")
 
@@ -301,6 +306,8 @@ def test_loopback_sidecar_counts_requests_and_slot_holds(run_async, base_port):
         dh = {n: h1[n] - h0[n] for n in hists}
         # two admitted workload batches made two requests of 16 signatures
         assert d["sidecar.requests"] == 2 and d["sidecar.request_sigs"] == 32
+        # the pool's messages are 32-byte digests: both stayed columnar
+        assert d["sidecar.columnar_sigs"] == 32
         assert dh == {n: 2 for n in hists}
         # one slot-hold per admitted batch, none per skipped one
         assert d["mempool.synthetic_skipped"] == 32
@@ -406,8 +413,28 @@ def test_every_cell_reports_the_new_metrics():
     for cell in bench["workloads"]:
         names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
         assert set(NEW_METRICS) <= set(names), cell["name"]
-    # added at the end, in the table's order
-    assert tuple(m["name"] for m in bench["per_layer"][-len(NEW_METRICS):]) == NEW_METRICS
+    # added at the end, in the table's order; PR 27's one after them
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW_METRICS[0])
+    assert tuple(names[first:]) == NEW_METRICS + ("sidecar.columnar_share",)
+
+
+def test_columnar_share_reads_the_windows_two_counters():
+    from chipbench import run
+
+    read = run.load_reader("per_layer", "sidecar.columnar_share")
+    src = _src()
+    first, last = (snap for _t, snap in src["sidecar"]["snapshots"][:2])
+    # a program older than the counter: 0 of the 100,000 that arrived
+    assert read(src) == 0.0
+    first["counters"]["sidecar.columnar_sigs"] = 400_000
+    last["counters"]["sidecar.columnar_sigs"] = 475_000
+    assert read(src) == pytest.approx(75.0)
+    # no signature arrived, or no snapshots around the window: nothing to read
+    last["counters"]["sidecar.request_sigs"] = first["counters"]["sidecar.request_sigs"]
+    assert read(src) is None
+    src["sidecar"]["snapshots"] = []
+    assert read(src) is None
 
 
 def test_every_new_name_is_in_the_namespace():
@@ -416,6 +443,7 @@ def test_every_new_name_is_in_the_namespace():
         "sidecar.requests", "sidecar.request_sigs", "sidecar.parse_s", "sidecar.reply_s",
         "sidecar.request_s", "service.collect_s", "service.backend_s",
         "crypto.remote_rtt_s", "mempool.verify_rtt_s", "runtime.loop_cpu_s",
+        "sidecar.columnar_sigs", "service.scatter_s",
     } <= declared
     # a phase's histogram is its profiler name plus `_s`
     for name in timeline.PHASES.values():

@@ -134,6 +134,84 @@ class TestShardedVerifier:
         assert mask.tolist() == want
 
 
+class TestColumnarBatch:
+    """A columnar batch (three uint8 column views of one (n, 128) array,
+    crypto/backend.py) through `TpuBackend.verify_batch_mask` itself."""
+
+    @staticmethod
+    def _rows(keys, n=12, seed=9):
+        """n wire rows msg | pk | sig with lanes 3 (corrupted signature),
+        5 (s + L: the same scalar, not canonical) and 8 (wrong key) invalid."""
+        from hotstuff_tpu.ops.ed25519 import L_ORDER
+
+        rng = random.Random(seed)
+        rows = []
+        for i in range(n):
+            pk, sk = keys[i % 4]
+            m = rng.randbytes(32)
+            sig = Signature.new(Digest(m), sk).data
+            if i == 3:
+                sig = sig[:40] + bytes([sig[40] ^ 4]) + sig[41:]
+            if i == 5:
+                s = int.from_bytes(sig[32:], "little") + L_ORDER
+                sig = sig[:32] + s.to_bytes(32, "little")
+            if i == 8:
+                pk = keys[(i + 1) % 4][0]
+            rows.append(m + pk.data + sig)
+        want = [i not in (3, 5, 8) for i in range(n)]
+        return np.frombuffer(b"".join(rows), np.uint8).reshape(n, 128), want
+
+    def test_columns_equal_lists(self, keys, tpu_backend):
+        from hotstuff_tpu.crypto.backend import columns_to_lists, row_columns
+
+        rows, want = self._rows(keys)
+        cols = row_columns(rows)
+        as_lists = tpu_backend.verify_batch_mask(*columns_to_lists(*cols))
+        before = dict(tpu_backend.report())
+        mask = tpu_backend.verify_batch_mask(*cols)
+        assert isinstance(mask, np.ndarray) and mask.dtype == bool
+        assert mask.tolist() == as_lists == want
+        assert CpuBackend().verify_batch_mask(*columns_to_lists(*cols)) == want
+        # counted like any other batch: the benchmark reads these
+        after = tpu_backend.report()
+        assert after["tpu_sigs"] - before["tpu_sigs"] == 12
+        assert after["tpu_batches"] - before["tpu_batches"] == 1
+        assert after["dispatched"]["w4p128dh"] - before["dispatched"]["w4p128dh"] == 1
+
+    def test_columns_below_the_crossover_verify_on_the_host(self, keys):
+        from hotstuff_tpu.crypto.backend import row_columns
+
+        backend = make_backend("tpu", crossover=100)
+        rows, want = self._rows(keys)
+        assert backend.verify_batch_mask(*row_columns(rows)) == want
+        assert backend.stats["cpu_sigs"] == 12 and backend.stats["tpu_sigs"] == 0
+
+    def test_service_calls_verify_batch_mask_itself(self, keys, monkeypatch, run_async):
+        """The benchmark's control (`chipbench/faulty.py --fault skip_half`)
+        patches `TpuBackend.verify_batch_mask`: a columnar bucket that took
+        another way to the device would make the control read correct."""
+        from chipbench import faulty
+        from hotstuff_tpu.crypto.batch_service import BatchVerificationService
+        from hotstuff_tpu.crypto.tpu_backend import TpuBackend
+
+        # monkeypatch restores the honest method afterwards
+        monkeypatch.setattr(TpuBackend, "verify_batch_mask", TpuBackend.verify_batch_mask)
+        faulty.break_sidecar("skip_half")
+        rows, want = self._rows(keys)
+
+        async def body():
+            svc = BatchVerificationService(
+                make_backend("tpu", crossover=1), max_delay=0.001
+            )
+            mask = await svc.verify_rows(rows)
+            assert mask.tolist() == [ok or i % 2 == 1 for i, ok in enumerate(want)]
+            assert mask.tolist() != want
+            # the cache believes what the backend said: every lane but 8
+            assert len(svc.dedup) == 11
+
+        run_async(body())
+
+
 class TestGraftEntry:
     def test_dryrun_multichip(self):
         from __graft_entry__ import dryrun_multichip
