@@ -1,12 +1,8 @@
-"""Median of the sidecar's `verifier.e2e_s` histogram (host clock around one
-verifier call). The histogram runs from boot, warm-up included."""
+"""One verifier call on the host's clock, prepare to mask in hand, mean of the
+window (`verifier.e2e_s`). From PR 28 on; through PR 27 this read the
+histogram's p50 since boot, warm-up included."""
+from chipbench import spans
 
 
 def read(src):
-    snaps = src["sidecar"]["snapshots"]
-    if not snaps:
-        return None
-    h = snaps[-1][1]["histograms"].get("verifier.e2e_s")
-    if not h or not h["count"]:
-        return None
-    return 1000.0 * h["p50"]
+    return spans.window_mean_ms(src, "sidecar", "verifier.e2e_s")

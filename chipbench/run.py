@@ -24,7 +24,7 @@ import time
 
 T_PROCESS_START = time.time()
 
-from . import arith, collect, drain, judge, launch, probe  # noqa: E402
+from . import arith, collect, drain, judge, launch, probe, trace_reduce  # noqa: E402
 from . import reference as ref  # noqa: E402
 from .traffic import Traffic  # noqa: E402
 
@@ -188,15 +188,19 @@ def build_result(bench, cell, traced: bool, src, device, took, compared):
         "metrics": metrics,
         "device": dev_out,
     }
+    stretch = None
     if traced and src.get("trace"):
         t = src["trace"]
         dev_out["busy_s"] = t.get("busy_s", 0.0)
         dev_out["window_s"] = t.get("window_s", 0.0)
+        # window_s is the longer of the two (`trace_reduce.stretch_seconds`)
+        stretch = {"clocked_s": src["trace_done"]["window_s"], "span_s": t.get("span_s")}
         result["breakdown"] = {
             "device_ops": t.get("device_ops", []),
             "idle_gaps": t.get("idle_gaps", []),
         }
     result["notes"] = {
+        "traced_stretch": stretch,
         "cpus": os.cpu_count(),
         "boot_s": took,
         "tx_checked": src["tx_checked"],
@@ -283,7 +287,7 @@ def main(argv=None) -> int:
                     f"the device is a TPU and the trace holds no TPU plane: {tr.get('planes')}")
             if not tr.get("busy_s"):
                 raise launch.LaunchError(f"no operation ran on the device while traced: {tr}")
-            tr["window_s"] = src["trace_done"]["window_s"]
+            tr["window_s"] = trace_reduce.stretch_seconds(src["trace_done"]["window_s"], tr)
 
         compared = judge.judge(src, work)
         result, missing = build_result(bench, cell, bool(args.trace), src, device, took, compared)

@@ -12,6 +12,8 @@ rehearsal (`python3 -m chipbench.selfcheck`); exits non-zero on a mismatch.
   6. the wait after the window on hand-made logs and a clock of its own: a
      backlog that commits late is waited for, a shed transaction is given up
   7. `attempted` and `failed` where a front port shed and a node lost
+  8. the all-nodes verified share beside the worst node's skipped share, and
+     a span's window mean where the histogram holds a warm-up from before it
 """
 
 from __future__ import annotations
@@ -88,6 +90,13 @@ def trace_case() -> int:
     s, merged = trace_reduce.union_seconds([(0, 10), (5, 20), (30, 40), (40, 45), (100, 101)])
     bad += check("union of overlapping intervals (ns -> s)", s, 36e-9, 1e-15)
     bad += check("merged intervals", merged, [(0, 20), (30, 45), (100, 101)])
+    # a stretch clocked at 0.4001 s whose operations span 0.4048 s is 0.4048 s
+    # long; one whose operations span less is as long as it was clocked
+    bad += check("stretch, operations past its clocked ends",
+                 trace_reduce.stretch_seconds(0.4001, {"span_s": 0.4048}), 0.4048, 1e-12)
+    bad += check("stretch, device idle at its ends",
+                 trace_reduce.stretch_seconds(0.4001, {"span_s": 0.3726}), 0.4001, 1e-12)
+    bad += check("stretch, no operation", trace_reduce.stretch_seconds(0.4001, {}), 0.4001, 1e-12)
     path = os.path.join(HERE, "selfcheck_trace.xplane.pb")
     if not os.path.exists(path):
         print("skip trace file: none recorded beside selfcheck.py")
@@ -100,6 +109,7 @@ def trace_case() -> int:
         expect = json.load(f)
     if "program_ms" in out:
         out["program_runs"] = out["program_ms"]["count"]
+    bad += check("recorded trace busy_s within its span", out["busy_s"] <= out["span_s"], True)
     for key, value in expect.items():
         got = out.get(key)
         if isinstance(value, float):
@@ -217,6 +227,42 @@ def shed_case() -> int:
     return bad
 
 
+def share_case() -> int:
+    """The window [100, 140), snapshots at 99.5 and 139.5. Node 0 verified
+    6,000 workload signatures in it and skipped 4,000, node 1 verified 9,000
+    and skipped 1,000: 15,000 of 20,000 due, 75 % over all nodes, and the
+    worst node skipped 40 %. The sidecar's `verifier.e2e_s` held ten warm-up
+    calls of 5 s before the window and takes 200 calls of 0.1 s in it: the
+    window mean is 100 ms (since boot it would be 333). Its snapshots hold no
+    `service.scatter_s` (a program older than the span): 0, not None."""
+    from .run import load_reader
+
+    hist = lambda total, count: {"sum": total, "count": count}  # noqa: E731
+    node = lambda t, done, skipped: (  # noqa: E731
+        t, {"counters": {"mempool.synthetic_skipped": skipped},
+            "histograms": {"mempool.verify_batch_size": hist(done, 1)}})
+    side = lambda t, total, count: (  # noqa: E731
+        t, {"counters": {}, "histograms": {"verifier.e2e_s": hist(total, count)}})
+    src = {
+        "window": {"t0": 100.0, "t1": 140.0, "seconds": 40.0},
+        "nodes": [{"snapshots": [node(99.5, 500, 70), node(139.5, 6500, 4070)]},
+                  {"snapshots": [node(99.5, 800, 0), node(139.5, 9800, 1000)]}],
+        "sidecar": {"snapshots": [side(99.5, 50.0, 10), side(139.5, 70.0, 210)]},
+    }
+    bad = check("verified share over all nodes, %",
+                load_reader("per_layer", "flood.verified_share")(src), 75.0, 1e-9)
+    bad += check("skipped share of the worst node, %",
+                 load_reader("per_layer", "mempool.skipped_share")(src), 40.0, 1e-9)
+    bad += check("verifier.e2e_ms, window mean",
+                 load_reader("per_layer", "verifier.e2e_ms")(src), 100.0, 1e-9)
+    bad += check("service.scatter_ms of a program without the span",
+                 load_reader("per_layer", "service.scatter_ms")(src), 0.0, 1e-9)
+    src["nodes"][1]["snapshots"].pop(0)
+    bad += check("a node whose snapshots do not bracket the window: no share",
+                 load_reader("per_layer", "flood.verified_share")(src), None)
+    return bad
+
+
 def record(out_dir: str) -> int:
     """Record the small trace kept beside this file: five runs of one small
     jitted program on whatever device JAX has (meant for the chip), traced,
@@ -262,7 +308,7 @@ def main() -> int:
     if len(sys.argv) > 2 and sys.argv[1] == "--record":
         return record(sys.argv[2])
     bad = (latency_case() + schedule_case() + trace_case() + roofline_case()
-           + device_count_case() + drain_case() + shed_case())
+           + device_count_case() + drain_case() + shed_case() + share_case())
     print("selfcheck:", "all ok" if not bad else f"{bad} FAILED")
     return 1 if bad else 0
 

@@ -4,7 +4,13 @@ table at the end. Not part of a benchmark run; its result goes into the traffic
 files as plain numbers. Give every rate twice (two passes) and 20 s or more:
 two 10 s readings of one rate have differed by a factor of two (PERF.md).
 
-    python3 -m chipbench.sweep --config fork-n4 --rates 8400,15800,24000,8400,15800,24000 --seconds 20
+    python3 -m chipbench.sweep --config fork-n4 --rates 24000,30000,36000,24000,30000,36000 --seconds 20
+    python3 -m chipbench.sweep --config fork-n10 --traffic flood-n10 --rates 18000,21000,24000,27000,18000,21000,24000,27000 --seconds 20
+
+One sweep a chip call: every node stores every payload, so a deployment writes
+nodes x rate x tx_size bytes a second (eight 20 s windows with their ramps
+wrote 19 GB at n=4 and 26 GB at n=10, PR 28), and a call that has written
+45 GiB is ended.
 """
 
 from __future__ import annotations

@@ -121,6 +121,16 @@ def reduce(data) -> dict:
     return out
 
 
+def stretch_seconds(clocked_s: float, reduced: dict) -> float:
+    """The traced stretch's length, `device.window_s` of the result line. The
+    profiler records device operations from a little before the shim's clock
+    says the stretch began to a little after it says it ended (0.4048 s of
+    operations in a stretch clocked at 0.4001 s, PR 28), so the stretch is at
+    least as long as from the first recorded operation's start to the last
+    one's end: `busy_s` can never read over it."""
+    return max(clocked_s, reduced.get("span_s", 0.0))
+
+
 def summary_text(data, top: int = 12) -> str:
     rows = []
     for p in data.planes:
