@@ -166,6 +166,21 @@ class MempoolEpochView:
         return out
 
 
+def synthetic_pool(value) -> tuple[int, bool]:
+    """(triples, per node?) of a `synthetic_pool_size`: a positive integer
+    k -> (k, False), the object {"per_node": k} -> (k, True); anything
+    else is a ValueError, raised where the parameters are read."""
+    size, per_node = value, False
+    if isinstance(value, dict) and list(value) == ["per_node"]:
+        size, per_node = value["per_node"], True
+    if type(size) is not int or size < 1:
+        raise ValueError(
+            "synthetic_pool_size must be a positive integer or "
+            f'{{"per_node": <positive integer>}}, not {value!r}'
+        )
+    return size, per_node
+
+
 @dataclass(slots=True)
 class MempoolParameters:
     """Reference defaults (mempool/src/config.rs:15-24), plus the benchmark
@@ -180,9 +195,14 @@ class MempoolParameters:
     # synthetic (message, key, signature) triples. The reference pre-generates
     # 200_000 triples at startup (mempool/src/core.rs:71-84); the pool size is
     # configurable here (the per-payload verification WORK is identical --
-    # triples are drawn cyclically from the pool).
+    # triples are drawn cyclically from the pool). Two forms
+    # (`synthetic_pool`): an integer k is ONE pool of k triples that every
+    # node generates alike (seed 7), so co-located nodes send a shared
+    # sidecar the same signatures; the object {"per_node": k} is the fork's
+    # own deployment, k triples from a seed derived from the node's public
+    # key, no triple shared between two nodes.
     benchmark_mode: bool = False
-    synthetic_pool_size: int = 10_000
+    synthetic_pool_size: int | dict = 10_000
     # Bound on the Front's client-tx intake queue (drop-oldest past it,
     # counted in mempool.front_dropped) — the raw benchmark port's share
     # of the admission-control story (hotstuff_tpu/ingress has the
@@ -215,6 +235,9 @@ class MempoolParameters:
     # a hostile requester can extract from one small frame.
     max_request_digests: int = 1_024
 
+    def __post_init__(self) -> None:
+        synthetic_pool(self.synthetic_pool_size)
+
     def log(self, log) -> None:
         # NOTE: these log entries are parsed by the benchmark harness.
         log.info("Queue capacity set to %s", self.queue_capacity)
@@ -240,8 +263,7 @@ class MempoolParameters:
 
     @staticmethod
     def from_json(obj: dict) -> "MempoolParameters":
-        p = MempoolParameters()
-        for k in (
+        keys = (
             "queue_capacity",
             "sync_retry_delay",
             "max_payload_size",
@@ -254,7 +276,6 @@ class MempoolParameters:
             "ingress_port_offset",
             "ingress_queue_capacity",
             "proofs_port_offset",
-        ):
-            if k in obj:
-                setattr(p, k, obj[k])
-        return p
+        )
+        # the constructor validates (`__post_init__`)
+        return MempoolParameters(**{k: obj[k] for k in keys if k in obj})

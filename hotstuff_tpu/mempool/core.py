@@ -13,6 +13,7 @@ votes-verified/sec.
 from __future__ import annotations
 
 import asyncio
+import hashlib
 import logging
 import os
 
@@ -28,7 +29,7 @@ from ..consensus.mempool_driver import (
     MempoolVerify,
     PayloadStatus,
 )
-from .config import MempoolCommittee, MempoolParameters
+from .config import MempoolCommittee, MempoolParameters, synthetic_pool
 from .errors import (
     InvalidPayloadSignatureError,
     MempoolError,
@@ -59,12 +60,29 @@ _M_VERIFY_BATCH = metrics.histogram(
 # How long one workload batch holds one of the node's pipeline slots
 # (`_verify_sem`), on the loop's clock: slots x batch / this is the plateau.
 _M_VERIFY_RTT = metrics.histogram("mempool.verify_rtt_s")
+# Generating the pool is most of a node's boot (30 s for 200,000 triples):
+# one sample a node, and the triples it holds.
+_M_POOL_BUILD = metrics.histogram("mempool.pool_build_s")
+_M_POOL_TRIPLES = metrics.counter("mempool.pool_triples")
 
 
 class SyntheticPool:
     """Pre-generated (message, key, signature) triples for the benchmark
     workload (mempool/src/core.rs:68-101: 200k at startup in the fork; size is
-    configurable here, drawn cyclically so per-payload work is identical)."""
+    configurable here, drawn cyclically so per-payload work is identical).
+
+    Two kinds, chosen by `MempoolParameters.synthetic_pool_size`: with the
+    default seed every node holds the SAME pool ("shared": an integer size);
+    seeded with `node_seed(name)` each node holds its own, as each of the
+    fork's machines does, and no two share a triple ("per_node": the object
+    {"per_node": size})."""
+
+    @staticmethod
+    def node_seed(name: PublicKey) -> int:
+        """A node's own pool seed: from its public key alone, so the pool
+        is the same after a restart and differs from every other node's."""
+        tagged = hashlib.sha512(b"hotstuff-tpu synthetic pool v1:" + name.data)
+        return int.from_bytes(tagged.digest()[:8], "big")
 
     def __init__(self, size: int, seed: int = 7) -> None:
         import random
@@ -88,6 +106,14 @@ class SyntheticPool:
             pairs.append(self.pairs[i])
             self._cursor = (i + 1) % size
         return msgs, pairs
+
+    def fingerprint(self) -> str:
+        """8 hex digits of SHA-256 over the triples in order: tells two
+        nodes' pools apart from their logs."""
+        h = hashlib.sha256()
+        for msg, (pk, sig) in zip(self.messages, self.pairs):
+            h.update(msg + pk.data + sig.data)
+        return h.hexdigest()[:8]
 
 
 class Core:
@@ -153,11 +179,24 @@ class Core:
         self._cleaned_cap = 4 * parameters.queue_capacity
         self.pool: SyntheticPool | None = None
         if parameters.benchmark_mode:
+            size, per_node = synthetic_pool(parameters.synthetic_pool_size)
             log.info(
                 "Generating %s synthetic signatures for the benchmark workload",
-                parameters.synthetic_pool_size,
+                size,
             )
-            self.pool = SyntheticPool(parameters.synthetic_pool_size)
+            with metrics.span(_M_POOL_BUILD):
+                self.pool = (
+                    SyntheticPool(size, SyntheticPool.node_seed(name))
+                    if per_node
+                    else SyntheticPool(size)
+                )
+            _M_POOL_TRIPLES.inc(size)
+            log.info(
+                "Synthetic pool: %s triples, %s, fingerprint %s",
+                size,
+                "per_node" if per_node else "shared",
+                self.pool.fingerprint(),
+            )
 
     # -- persistence ---------------------------------------------------------
 

@@ -707,6 +707,8 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("mempool.ingress_lane_txs", "counter", None),
     ("mempool.verify_batch_size", "histogram", SIZE_BUCKETS),
     ("mempool.verify_rtt_s", "histogram", None),
+    ("mempool.pool_build_s", "histogram", None),
+    ("mempool.pool_triples", "counter", None),
     # ingress/ — authenticated client plane with admission control
     ("ingress.received", "counter", None),
     ("ingress.admitted", "counter", None),

@@ -413,10 +413,12 @@ def test_every_cell_reports_the_new_metrics():
     for cell in bench["workloads"]:
         names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
         assert set(NEW_METRICS) <= set(names), cell["name"]
-    # added at the end, in the table's order; PR 27's one after them
+    # they stand together, in the table's order; what later PRs appended
+    # after them (PR 27's `sidecar.columnar_share` first) is theirs to pin
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index(NEW_METRICS[0])
-    assert tuple(names[first:]) == NEW_METRICS + ("sidecar.columnar_share",)
+    assert tuple(names[first:first + len(NEW_METRICS)]) == NEW_METRICS
+    assert len(set(names)) == len(names)
 
 
 def test_columnar_share_reads_the_windows_two_counters():
@@ -437,6 +439,42 @@ def test_columnar_share_reads_the_windows_two_counters():
     assert read(src) is None
 
 
+def test_cache_hit_share_reads_the_windows_two_counters():
+    from chipbench import run
+
+    read = run.load_reader("per_layer", "sidecar.cache_hit_share")
+    src = _src()
+    first, last = (snap for _t, snap in src["sidecar"]["snapshots"][:2])
+    # nothing was looked up in the window: nothing to read
+    assert read(src) is None
+    # since boot 300,000 hits in 400,000 lookups; in the window 15,000 in 60,000
+    first["counters"].update({"verifier.dedup_hits": 300_000, "verifier.dedup_misses": 100_000})
+    last["counters"].update({"verifier.dedup_hits": 315_000, "verifier.dedup_misses": 145_000})
+    assert read(src) == pytest.approx(25.0)
+    # per-node pools: every lookup a miss
+    last["counters"]["verifier.dedup_hits"] = 300_000
+    assert read(src) == 0.0
+    src["sidecar"]["snapshots"] = []
+    assert read(src) is None
+
+
+def test_pool_build_s_is_the_slowest_nodes_one_sample():
+    from chipbench import run
+
+    read = run.load_reader("per_layer", "node.pool_build_s")
+    src = _src()
+    # a program older than the histogram: 0, as spans.py reads every absent name
+    assert read(src) == 0.0
+    # one sample a node, taken at boot: the snapshot before the close holds it
+    for node, took in zip(src["nodes"], (31.5, 33.25)):
+        for _t, snap in node["snapshots"]:
+            snap["histograms"]["mempool.pool_build_s"] = {"sum": took, "count": 1}
+    assert read(src) == pytest.approx(33.25)
+    # a node with no snapshot by the close: nothing to read
+    src["nodes"][0]["snapshots"] = [(141.0, src["nodes"][0]["snapshots"][-1][1])]
+    assert read(src) is None
+
+
 def test_every_new_name_is_in_the_namespace():
     declared = {name for name, _kind, _b in metrics._DEFAULT_NAMESPACE}
     assert {
@@ -444,6 +482,7 @@ def test_every_new_name_is_in_the_namespace():
         "sidecar.request_s", "service.collect_s", "service.backend_s",
         "crypto.remote_rtt_s", "mempool.verify_rtt_s", "runtime.loop_cpu_s",
         "sidecar.columnar_sigs", "service.scatter_s",
+        "mempool.pool_build_s", "mempool.pool_triples",
     } <= declared
     # a phase's histogram is its profiler name plus `_s`
     for name in timeline.PHASES.values():
