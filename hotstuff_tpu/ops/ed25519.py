@@ -563,20 +563,21 @@ def _verify_kernel_w4_packed128_dh(packed):
     return _verify_kernel_w4(*unpack_packed_inputs_dh(packed))
 
 
-def decompress(y_limbs: jnp.ndarray, sign: jnp.ndarray):
+def decompress(y_limbs: jnp.ndarray, sign: jnp.ndarray, pow2523=f.pow2523):
     """Compressed y (+ sign of x) -> affine (x, -x, y) + validity mask.
 
     Follows the ref10 recipe: x = u*v^3 * (u*v^7)^((p-5)/8) with
     u = y^2-1, v = d*y^2+1; multiply by sqrt(-1) when v*x^2 == -u; invalid
     when v*x^2 != +-u (no square root exists). Returns canonical x and p-x
-    so the caller can pick either A or -A cheaply.
+    so the caller can pick either A or -A cheaply. `pow2523` is the
+    square-root exponentiation: the jnp chain, or the Pallas program's own.
     """
     yy = f.sqr(y_limbs)
     u = f.sub(yy, f.ONE)
     v = f.add(f.mul(D, yy), f.ONE)
     v3 = f.mul(f.sqr(v), v)
     v7 = f.mul(f.sqr(v3), v)
-    w = f.pow2523(f.mul(u, v7))
+    w = pow2523(f.mul(u, v7))
     r = f.mul(f.mul(u, v3), w)
     chk = f.canonical(f.mul(v, f.sqr(r)))
     u_c = f.canonical(u)
@@ -593,9 +594,10 @@ def decompress(y_limbs: jnp.ndarray, sign: jnp.ndarray):
     return x_final, xneg_final, valid
 
 
-def compress(p: Point) -> jnp.ndarray:
-    """Point -> canonical 32-limb encoding (y with sign bit of x in bit 255)."""
-    zinv = f.invert(p[2])
+def compress(p: Point, invert=f.invert) -> jnp.ndarray:
+    """Point -> canonical 32-limb encoding (y with sign bit of x in bit 255).
+    `invert` as `decompress`'s `pow2523`."""
+    zinv = invert(p[2])
     x_c = f.canonical(f.mul(p[0], zinv))
     y_c = f.canonical(f.mul(p[1], zinv))
     return y_c.at[f.NLIMB - 1].add(128.0 * f.parity(x_c))

@@ -14,9 +14,15 @@ entries (VPU fma chains — no gathers, which TPUs do poorly). Digit rows are
 selected by an iota-mask reduction instead of dynamic slicing (supported +
 cheap: 64xBLOCK fma per group).
 
-Decompression, table construction and final compression stay in plain jnp
-around the pallas_call (~15% of total work) — they run once per batch, not
-per ladder step, so VMEM residency buys little there.
+The two fixed-exponent chains around the ladder (decompress's square root,
+compress's 1/Z: ~265 field multiplications each) run in a second kernel,
+`chain_pallas`, for the same reason. They run once per batch, not per
+ladder step, and were first left in plain jnp as "~15% of total work";
+the device trace said otherwise: of 39.9 ms a 4096-lane program the ladder
+took 17.4 ms and the chains' sixteen jnp `while` loops 16.5 ms, 33 us an
+iteration against 6.3 us a multiplication in here (PERF.md §6, PR 30, has
+the program by device op before and after). Table construction, SHA-512
+and the canonical reductions stay in plain jnp around the two kernels.
 """
 
 from __future__ import annotations
@@ -33,7 +39,11 @@ from jax.experimental.pallas import tpu as pltpu
 from . import field as f
 from . import ed25519 as ed
 
-BLOCK = 256  # lanes per grid program (multiple of 128)
+# Lanes per grid program (multiple of 128), of both kernels. One chain over
+# 4096 lanes, alone on a v5e with the host's dispatch: 1.9 / 1.9 / 2.2 /
+# 2.7 ms at 128 / 256 / 512 / 1024 lanes a program (a (66, B) product
+# outgrows the 64 vregs past 256), VMEM exhausted at 2048 (PR 30).
+BLOCK = 256
 
 
 def _digit_row(digits: jnp.ndarray, row) -> jnp.ndarray:
@@ -156,18 +166,58 @@ def ladder_pallas(
     return x, y, z, t
 
 
+_CHAINS = {"invert": f.invert, "pow2523": f.pow2523}
+
+
+def _chain_kernel(z_ref, out_ref, *, power):
+    with f.mosaic_safe():
+        out_ref[:] = power(z_ref[:])
+
+
+@functools.partial(jax.jit, static_argnames=("tail", "interpret"))
+def chain_pallas(z, tail: str, interpret: bool = False):
+    """(32, B) element -> z^(2^255-21) (`tail="invert"`) or z^(2^252-3)
+    (`"pow2523"`): field.py's addition chain, all ~265 squarings and
+    multiplications of it VMEM-resident in one grid program per BLOCK
+    lanes, where the jnp form is sixteen `while` loops whose every
+    iteration goes through HBM.
+
+    Input bound: `f.mul`/`f.sqr`'s (limbs <= 700). Both callers hand it a
+    `f.mul` output (u*v^7 in decompress, the ladder's Z in compress), i.e.
+    normalized limbs <= ~295. 0 -> 0, so an invalid key's lane flows on.
+    `interpret` is for the tests, as in `ladder_pallas`."""
+    batch = z.shape[1]
+    assert batch % BLOCK == 0, f"batch {batch} must be a multiple of {BLOCK}"
+    spec = pl.BlockSpec(
+        (f.NLIMB, BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM
+    )
+    return pl.pallas_call(
+        functools.partial(_chain_kernel, power=_CHAINS[tail]),
+        grid=(batch // BLOCK,),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(z.shape, jnp.float32),
+        interpret=interpret,
+        name=f"chain_pallas_{tail}",
+    )(z)
+
+
+pow2523_pallas = functools.partial(chain_pallas, tail="pow2523")
+invert_pallas = functools.partial(chain_pallas, tail="invert")
+
+
 def _verify_kernel_pallas(a_y, a_sign, r_enc, s_digits, h_digits):
     """Full verification with the ladder in pallas; same contract as
     ed._verify_kernel_w4. The scopes are metadata only: stable names for
     the program's stages in a device trace, whatever the HLO ops are called."""
     with jax.named_scope("decompress"):
-        x_a, xneg_a, valid = ed.decompress(a_y, a_sign)
+        x_a, xneg_a, valid = ed.decompress(a_y, a_sign, pow2523=pow2523_pallas)
     with jax.named_scope("table"):
         ta = ed._build_neg_a_table(xneg_a, a_y)
     with jax.named_scope("ladder"):
         result = ladder_pallas(s_digits, h_digits, *ta)
     with jax.named_scope("compress"):
-        enc = ed.compress(result)
+        enc = ed.compress(result, invert=invert_pallas)
     return valid & jnp.all(enc == r_enc, axis=0)
 
 

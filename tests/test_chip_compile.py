@@ -20,6 +20,7 @@ minutes each to compile and are marked `slow`:
     JAX_PLATFORMS=cpu python -m pytest tests/test_chip_compile.py -m slow -s
 """
 
+import functools
 import os
 import time
 
@@ -104,6 +105,23 @@ def test_ladder_pallas_compiles_for_v5e(one_chip, width):
         f"ladder_pallas@{width}",
         pallas_ladder.ladder_pallas,
         *_ladder_shapes(width, one_chip),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tail", ["invert", "pow2523"])
+@pytest.mark.parametrize("width", ["BLOCK", SERVED_WIDTH])
+def test_chain_pallas_compiles_for_v5e(one_chip, width, tail):
+    """The fixed-exponent chain kernel (decompress's square root, compress's
+    inversion) at one grid program and at the served width."""
+    from hotstuff_tpu.ops import pallas_ladder
+
+    if width == "BLOCK":
+        width = pallas_ladder.BLOCK
+    compiled = _compile(
+        f"chain_pallas {tail}@{width}",
+        jax.jit(functools.partial(pallas_ladder.chain_pallas, tail=tail)),
+        jax.ShapeDtypeStruct((32, width), jnp.float32, sharding=one_chip),
     )
     assert "tpu_custom_call" in compiled.as_text()
 

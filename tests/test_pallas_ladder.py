@@ -9,14 +9,17 @@ VMEM, the chip's own result — is tests/test_chip_compile.py's and
 chip_smoke.py's job.
 """
 
+import functools
 import random
 
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from hotstuff_tpu.ops import ed25519 as ed
+from hotstuff_tpu.ops import field as f
 from hotstuff_tpu.ops import pallas_ladder
 
 pytest.importorskip("cryptography")
@@ -102,6 +105,96 @@ def test_interpreted_pallas_ladder_point_matches_integer_math(block, lane):
     want = (y | ((x & 1) << 255)).to_bytes(32, "little")
     got = bytes(block["enc"][:, lane].astype(np.uint8))
     assert got == want
+
+
+# --- the fixed-exponent chains (decompress's square root, compress's 1/Z) ---
+
+CHAIN_EXPONENTS = {"invert": f.P - 2, "pow2523": (f.P - 5) // 8}
+
+
+def _chain_inputs():
+    """One BLOCK of elements: the corners, canonical random values,
+    and lazily reduced ones (limbs past 255, up to `f.mul`'s stated input
+    bound of 700 in every limb: more than either caller hands the chain)."""
+    rng = random.Random(30)
+    n = pallas_ladder.BLOCK
+    canon = [0, 1, 2, f.P - 1] + [rng.randrange(f.P) for _ in range(n - 68)]
+    z = np.concatenate([f.limbs_of_int(v) for v in canon], axis=1)
+    lazy = np.array(
+        [[rng.randrange(701) for _ in range(64)] for _ in range(f.NLIMB)],
+        np.float32,
+    )
+    lazy[:, 0] = 700.0
+    lazy[:, 1] = 295.0  # what a normalized `f.mul` output can reach
+    return np.concatenate([z, lazy], axis=1)
+
+
+@pytest.mark.parametrize("tail", sorted(CHAIN_EXPONENTS))
+def test_interpreted_chain_kernel_matches_integer_pow(tail):
+    z = _chain_inputs()
+    assert z.shape == (f.NLIMB, pallas_ladder.BLOCK)
+    out = pallas_ladder.chain_pallas(jnp.asarray(z), tail=tail, interpret=True)
+    assert float(jnp.max(out)) <= 295.0  # normalized, as `f.mul` promises
+    got = f.int_of_limbs(np.asarray(f.canonical(out)))
+    want = [pow(v, CHAIN_EXPONENTS[tail], f.P) for v in f.int_of_limbs(z)]
+    assert got == want
+    assert got[0] == 0  # 0 -> 0: an invalid key's lane flows on
+
+
+def test_sqr_n_squares_truly_only_inside_a_pallas_body():
+    """Plain XLA keeps `mul(x, x)` in the chains' loops (the jnp programs'
+    HLO does not move); a Mosaic body gets `sqr`."""
+    from jax import lax
+
+    a = jnp.zeros((f.NLIMB, 128), jnp.float32)
+
+    def loop_of(step):
+        return str(jax.make_jaxpr(lambda x: lax.fori_loop(0, 3, lambda _, y: step(y), x))(a))
+
+    def chain():
+        return str(jax.make_jaxpr(lambda x: f.sqr_n(x, 3))(a))
+
+    assert chain() == loop_of(lambda y: f.mul(y, y))
+    with f.mosaic_safe():
+        inside, want = chain(), loop_of(f.sqr)
+    assert inside == want != chain()
+
+
+NONCANONICAL_R, NO_SQRT_KEY = 60, 150
+
+
+def test_whole_pallas_program_with_interpreted_chains_matches_w4(monkeypatch):
+    """`_verify_kernel_pallas` as the chip runs it (decompress with the
+    Pallas square root, table, Pallas ladder, compress with the Pallas
+    inversion), its three kernels interpreted: every lane's verdict equals
+    the jnp w4 program's, whose chains are the jnp `while` loops."""
+    from __graft_entry__ import _signed_batch
+    from chip_smoke import _off_curve_key
+
+    n = pallas_ladder.BLOCK
+    msgs, pks, sigs = _signed_batch(n, seed=30)
+    s_bad = bytearray(sigs[BAD_S])
+    s_bad[40] ^= 0x01
+    sigs[BAD_S] = bytes(s_bad)
+    # R's y is p + 3: the same field element as y = 3, never the encoding
+    sigs[NONCANONICAL_R] = (f.P + 3).to_bytes(32, "little") + sigs[NONCANONICAL_R][32:]
+    pks[NO_SQRT_KEY] = _off_curve_key()
+    msgs[WRONG_MSG] = bytes(32)
+    staged = ed.prepare_batch(msgs, pks, sigs)
+    args = ed.kernel_args(staged, n, "w4")
+
+    for name in ("ladder_pallas", "pow2523_pallas", "invert_pallas"):
+        monkeypatch.setattr(
+            pallas_ladder,
+            name,
+            functools.partial(getattr(pallas_ladder, name), interpret=True),
+        )
+    mask = np.asarray(jax.jit(pallas_ladder._verify_kernel_pallas)(*args))
+    w4_mask = np.asarray(ed._verify_w4_jit(*args))
+    want = np.ones(n, bool)
+    want[[BAD_S, NONCANONICAL_R, NO_SQRT_KEY, WRONG_MSG]] = False
+    assert (mask & staged["s_ok"]).tolist() == want.tolist()
+    assert mask.tolist() == w4_mask.tolist()
 
 
 def test_mosaic_safe_trace_mode_is_per_thread():
