@@ -1296,7 +1296,7 @@ class ChaosOrchestrator:
             "scheduler": {
                 str(i): node.service.scheduler.summary()
                 for i, node in enumerate(self.nodes)
-                if node.service is not None and node.service.scheduler is not None
+                if node.service is not None
             },
             # Per-node epoch switches (EpochManager on_switch): every
             # node's observed boundary, with the activation round the

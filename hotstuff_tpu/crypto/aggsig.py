@@ -13,7 +13,7 @@ reference implementation: plain-integer BLS12-381 with the optimal ate
 pairing, importable on hosts with no jax and no `cryptography` wheel
 (the graftlint import-boundary contract for everything chaos-reachable).
 It is deliberately slow (~0.1-0.3 s per pairing on one core) — unit
-tests and the `bench.py --aggregate-ab` artifact run it; virtual-time
+tests run it; virtual-time
 fleets install the trusted-stub aggregate analogue
 (chaos/trusted_crypto.TrustedAggScheme) through `install_agg_scheme`,
 and the device path (`ops/bls.py`) accelerates the point-aggregation

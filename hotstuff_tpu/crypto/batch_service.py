@@ -16,9 +16,6 @@ Batching policy lives in the continuous-batching DeviceScheduler
 alignment-grid bucket sizing, continuous refill. This class remains the
 DISPATCH EXECUTOR — dedup cache, committee tagging, the backend call,
 future resolution — and the thin source-registration façade callers see.
-The pre-scheduler single-queue flush heuristics survive as
-`use_scheduler=False` (`_run_legacy`), kept as the measured baseline for
-`bench.py --scheduler-ab`.
 
 The backend call runs in a worker thread so the TPU dispatch never blocks
 the event loop (the mempool/consensus cores keep processing while a batch
@@ -61,7 +58,6 @@ from .scheduler import (
     DeviceScheduler,
     LaneStats,
     SchedulerConfig,
-    note_queue_delay,
     resolve_source,
 )
 
@@ -180,7 +176,7 @@ class _Group:
     # block whose QC/vote/proposal it checks.
     trace: str | None = None
     # Source class (crypto/scheduler.py) + queueing timestamps: t_submit is
-    # stamped at admission, t_dequeue when a bucket (or legacy flush) takes
+    # stamped at admission, t_dequeue when a bucket takes
     # the group — their difference is the per-lane queueing delay the
     # scheduler metrics and verify.batch trace events attribute.
     source: str = "mempool"
@@ -205,21 +201,18 @@ class BatchVerificationService:
         self,
         backend: CryptoBackend | None = None,
         max_batch: int = 8192,
-        max_delay: float = 0.002,
         max_concurrent_dispatches: int = 4,
         dedup_cache_size: int = 65536,
         inline: bool = False,
-        use_scheduler: bool = True,
         scheduler_config: SchedulerConfig | None = None,
         steal_backends: Sequence[CryptoBackend] | None = None,
     ) -> None:
         self._backend = backend
         self.max_batch = max_batch
-        self.max_delay = max_delay
         # Cross-chip work stealing (crypto/scheduler.py): sibling shard
         # backends bulk buckets may be stolen to when the home backend's
         # pipeline window is full. Backend 0 (the `backend` arg) stays
-        # home for every critical dispatch and all legacy-loop flushes.
+        # home for every critical dispatch.
         # inline=True (the chaos virtual-time mode) FORCES stealing off:
         # which backend a bucket lands on must not depend on wall-clock
         # thread timing when a scenario replays bit-for-bit (§5.5i).
@@ -233,30 +226,24 @@ class BatchVerificationService:
         # the one nondeterminism its virtual-time replay cannot control.
         self.inline = inline
         # Verified-signature dedup: set dedup_cache_size=0 to disable
-        # (the bench A/B switch and the uncached-baseline tests).
+        # (the uncached-baseline tests).
         self.dedup: VerifiedSigCache | None = (
             VerifiedSigCache(dedup_cache_size) if dedup_cache_size else None
         )
-        self._queue: asyncio.Queue[_Group] = asyncio.Queue()
         self._task: asyncio.Task | None = None
-        # Per-lane queueing-delay reservoir, fed by BOTH flush paths (the
-        # scheduler's dequeue and the legacy loop) — the bench A/B and the
-        # chaos scheduler expectations read per-service p50/p99 from here.
+        # Per-lane queueing-delay reservoir, fed by the scheduler's
+        # dequeue — the chaos scheduler expectations read per-service
+        # p50/p99 from here.
         self.lane_stats = LaneStats()
-        # The continuous-batching device scheduler (crypto/scheduler.py) is
-        # the default flush policy; use_scheduler=False keeps the legacy
-        # single-queue heuristics as the measured A/B baseline.
-        self.scheduler: DeviceScheduler | None = (
-            DeviceScheduler(
-                self._spawn_dispatch,
-                max_batch=max_batch,
-                alignment_fn=self._bucket_alignment,
-                config=scheduler_config,
-                lane_stats=self.lane_stats,
-                n_backends=1 + len(self._steal_backends),
-            )
-            if use_scheduler
-            else None
+        # The continuous-batching device scheduler (crypto/scheduler.py):
+        # the flush policy.
+        self.scheduler = DeviceScheduler(
+            self._spawn_dispatch,
+            max_batch=max_batch,
+            alignment_fn=self._bucket_alignment,
+            config=scheduler_config,
+            lane_stats=self.lane_stats,
+            n_backends=1 + len(self._steal_backends),
         )
         # Flushes dispatch CONCURRENTLY (bounded): an urgent 3-signature QC
         # check must not wait out a multi-thousand-signature workload batch
@@ -270,7 +257,7 @@ class BatchVerificationService:
         # per-backend accounting that admitted it. Without steal
         # backends the caller's max_concurrent_dispatches stands as-is.
         dispatch_bound = max_concurrent_dispatches
-        if self.scheduler is not None and self._steal_backends:
+        if self._steal_backends:
             dispatch_bound = max(
                 dispatch_bound,
                 self.scheduler.config.bulk_concurrency
@@ -292,12 +279,9 @@ class BatchVerificationService:
             # node tears down its verification flush loop too.
             from ..utils.actors import spawn
 
-            loop = (
-                self.scheduler.run()
-                if self.scheduler is not None
-                else self._run_legacy()
+            self._task = spawn(
+                self.scheduler.run(), name="batch-verification-service"
             )
-            self._task = spawn(loop, name="batch-verification-service")
 
     @property
     def backend(self) -> CryptoBackend:
@@ -367,10 +351,7 @@ class BatchVerificationService:
     async def _submit(self, group: _Group):
         self._ensure_task()
         group.t_submit = asyncio.get_running_loop().time()
-        if self.scheduler is not None:
-            self.scheduler.submit(group)
-        else:
-            await self._queue.put(group)
+        self.scheduler.submit(group)
         return await group.future
 
     async def verify(
@@ -399,67 +380,7 @@ class BatchVerificationService:
         if self.dedup is not None:
             self.dedup.add(message, key, signature)
 
-    # -- flush loops ---------------------------------------------------------
-    #
-    # Production rides DeviceScheduler.run() (crypto/scheduler.py). The
-    # legacy single-queue heuristics below are retained as the measured
-    # baseline for `bench.py --scheduler-ab` (use_scheduler=False): size /
-    # deadline / urgent flushing with no lanes, no alignment sizing, no
-    # continuous refill.
-
-    async def _run_legacy(self) -> None:
-        loop = asyncio.get_running_loop()
-        while True:
-            first = await self._queue.get()
-            groups = [first]
-            total = len(first)
-            urgent = first.urgent
-            deadline = loop.time() + self.max_delay
-            while total < self.max_batch:
-                # Opportunistic drain of whatever is already enqueued.
-                while not self._queue.empty() and total < self.max_batch:
-                    g = self._queue.get_nowait()
-                    groups.append(g)
-                    total += len(g)
-                    urgent |= g.urgent
-                if urgent or total >= self.max_batch:
-                    break
-                timeout = deadline - loop.time()
-                if timeout <= 0:
-                    break
-                try:
-                    g = await asyncio.wait_for(self._queue.get(), timeout)
-                except asyncio.TimeoutError:
-                    break
-                groups.append(g)
-                total += len(g)
-                urgent |= g.urgent
-
-            # The legacy path stamps dequeue time at flush decision, so the
-            # per-lane queue-delay attribution is directly comparable with
-            # the scheduler's (same submit -> dequeue definition).
-            now = loop.time()
-            for g in groups:
-                g.t_dequeue = now
-                note_queue_delay(self.lane_stats, g.source, max(0.0, now - g.t_submit))
-
-            # Urgent groups dispatch in their OWN flush, immediately: a
-            # 3-signature QC check must neither ride a multi-thousand-
-            # signature workload batch down the device path nor wait for a
-            # dispatch slot held by one (backends send small batches down
-            # the CPU fast path, so unbounded urgent dispatches are bounded
-            # in practice by the consensus message rate). Workload groups
-            # coalesced in the same pass flush separately, gated by the
-            # dispatch bound — acquired inside _dispatch so this loop keeps
-            # draining the queue while every slot is in flight.
-            if urgent:
-                hot = [g for g in groups if g.urgent]
-                cold = [g for g in groups if not g.urgent]
-                self._spawn_dispatch(hot, sum(len(g) for g in hot), True)
-                if cold:
-                    self._spawn_dispatch(cold, sum(len(g) for g in cold), False)
-            else:
-                self._spawn_dispatch(groups, total, False)
+    # -- dispatch (the flush loop is DeviceScheduler.run) ---------------------
 
     def _spawn_dispatch(
         self, groups: list[_Group], total: int, urgent: bool,

@@ -400,16 +400,13 @@ async def serve(
     addr: tuple[str, int],
     backend: CryptoBackend,
     max_batch: int = 8192,
-    max_delay: float = 0.002,
     urgent_below: int = 256,
 ) -> None:
     """Run the sidecar server forever. One BatchVerificationService shared by
     every connection: batches coalesce across the whole committee."""
     from .batch_service import BatchVerificationService
 
-    service = BatchVerificationService(
-        backend, max_batch=max_batch, max_delay=max_delay
-    )
+    service = BatchVerificationService(backend, max_batch=max_batch)
 
     async def handler(reader, writer):
         await _handle_connection(reader, writer, service, urgent_below)
@@ -469,13 +466,11 @@ def main(argv: list[str] | None = None) -> None:
         "replicated table copy per chip, so committee-tagged batches ride "
         "the zero-decompression kernel on every device",
     )
-    p.add_argument("--max-delay", type=float, default=0.002)
     p.add_argument(
         "--chunk",
         type=int,
         default=None,
-        help="upload-pipeline chunk size (clamped to the bucket cap); the "
-        "device chunk sweep (tools/tune_device.py --chunks) decides this",
+        help="upload-pipeline chunk size (clamped to the bucket cap)",
     )
     p.add_argument(
         "--no-warmup", action="store_true", help="skip bucket pre-compilation"
@@ -536,7 +531,6 @@ def main(argv: list[str] | None = None) -> None:
             (args.host, args.port),
             backend,
             max_batch=args.max_batch,
-            max_delay=args.max_delay,
         )
     )
 

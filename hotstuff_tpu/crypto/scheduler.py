@@ -1,13 +1,12 @@
 """Continuous-batching device scheduler with preemptive priority lanes.
 
 Every device-bound verification — QC/TC-critical consensus checks, mempool
-bulk, sync/payload re-verification, and client ingress — used to funnel
-through one set of per-service flush heuristics (batch_service._run_legacy):
-a single queue, a single deadline, one `urgent` bit. That design has no
-vocabulary for "ingress is latency-sensitive but not commit-critical" and
-no way to size buckets against the device's alignment grid, so a bulk or
-ingress flood and a quorum-sized QC check were fate-shared into the same
-coalesced flushes.
+bulk, sync/payload re-verification, and client ingress — goes through this
+one flush policy. A single queue with a single deadline and one `urgent`
+bit has no vocabulary for "ingress is latency-sensitive but not
+commit-critical" and no way to size buckets against the device's alignment
+grid, so a bulk or ingress flood and a quorum-sized QC check would be
+fate-shared into the same coalesced flushes.
 
 This module is the LLM-serving continuous-batching pattern applied to the
 verify plane (ROADMAP item 4): typed **sources**, each with a priority
@@ -52,8 +51,7 @@ cache, committee tagging, backend call, future resolution) — its public
 
 Observability: per-lane queueing-delay histograms (`scheduler.queue_<lane>_s`)
 plus bucket/flush counters in the `scheduler.*` namespace, a per-service
-`LaneStats` reservoir (the bench A/B and chaos expectations read p50/p99
-from it), and `lane=`/`queue_s=` fields on every traced group's
+`LaneStats` reservoir (the chaos expectations read p50/p99 from it), and `lane=`/`queue_s=` fields on every traced group's
 `verify.batch` event so `tools/trace_report.py` attributes queueing delay
 per class.
 
@@ -192,8 +190,7 @@ _QUEUE_HIST = {
 
 def note_queue_delay(lane_stats: "LaneStats", source: str, queue_s: float) -> None:
     """Record one group's queueing delay into the lane's global histogram
-    and the service-local reservoir. Shared by the scheduler's dequeue and
-    the legacy flush loop, so before/after attribution is comparable."""
+    and the service-local reservoir."""
     hist = _QUEUE_HIST.get(source)
     if hist is not None:
         hist.record(queue_s)
@@ -204,18 +201,16 @@ class LaneStats:
     """Per-service per-lane queueing-delay reservoir.
 
     The global `scheduler.queue_<lane>_s` histograms aggregate across every
-    service in the process; chaos scenarios and the bench A/B need
-    PER-SERVICE percentiles (one node's critical lane, one A/B leg), so
-    each BatchVerificationService keeps its own bounded sample ring here —
-    both the scheduler and the legacy flush loop feed it, which is exactly
-    what makes the before/after queueing attribution comparable.
+    service in the process; chaos scenarios need PER-SERVICE percentiles
+    (one node's critical lane), so each BatchVerificationService keeps its
+    own bounded sample ring here, fed by the scheduler's dequeue.
 
     The ring ROTATES at CAP (oldest evicted) rather than saturating: the
     telemetry plane (utils/telemetry.py) windows per-snapshot deltas off
     `total()`'s monotonic count, and a saturating list would freeze its
     live lane SLOs for the rest of the process once a long-running node
     crossed CAP. `summary()` therefore describes the most recent CAP
-    samples — every bench leg and chaos scenario stays well under that."""
+    samples — every chaos scenario stays well under that."""
 
     CAP = 65_536  # samples retained per lane (rotating window)
 
@@ -367,8 +362,8 @@ class DeviceScheduler:
     # -- admission -----------------------------------------------------------
 
     def submit(self, group) -> None:
-        """Admit one group into its lane (synchronous — lanes are unbounded
-        like the legacy queue; backpressure stays with the callers, e.g.
+        """Admit one group into its lane (synchronous — lanes are
+        unbounded; backpressure stays with the callers, e.g.
         ingress admission and the mempool's verify semaphores)."""
         self.lanes[group.source].queue.append(group)
         self.lanes[group.source].enqueued += 1

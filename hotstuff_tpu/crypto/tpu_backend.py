@@ -8,7 +8,7 @@ batch workload mempool/src/core.rs:135-148) to the JAX ed25519 kernel
 
 Small batches fall back to the host CPU: the TPU wins only past a crossover
 size (dispatch + transfer amortisation — SURVEY.md §7 "hard parts" item 3).
-The crossover is configurable and can be measured with bench.py.
+The crossover is configurable.
 
 `register_committee()` installs the validator keys as device-resident
 precompute (ops.ed25519.CommitteeTable); batches tagged as committee
@@ -93,8 +93,8 @@ class TpuBackend(CryptoBackend):
                 "process was told to (JAX_PLATFORMS=cpu, as the tests do)"
             )
         # pallas ladder on the chip; the jnp w4 kernel where the process
-        # asked for the CPU (pallas has no CPU lowering). Packed wire
-        # format + upload pipeline either way.
+        # asked for the CPU (pallas has no CPU lowering). The one place
+        # that chooses: the verifier's program table follows from it.
         kernel = "pallas" if self.platform == "tpu" else "w4"
         if sharded or mesh is not None:
             from ..parallel.mesh import ShardedEd25519Verifier
@@ -130,7 +130,7 @@ class TpuBackend(CryptoBackend):
         # its device break-even sits well below the generic crossover.
         # Default crossover/4 so quorum-sized QC/TC batches (2f+1 votes)
         # actually ride the device-resident tables instead of falling to
-        # the host CPU; tune with bench.py --committee-cache.
+        # the host CPU.
         if committee_crossover is not None:
             self.committee_crossover = committee_crossover
         else:
@@ -332,16 +332,14 @@ class TpuBackend(CryptoBackend):
 
         The three arguments may be the uint8 column arrays of one columnar
         batch; above the crossover they reach the verifier as they are and
-        the mask comes back as a bool array. The committee table, the
-        host's OpenSSL and the f32 argument path want objects."""
+        the mask comes back as a bool array. The committee table and the
+        host's OpenSSL want objects."""
         n = len(messages)
         if n == 0:
             return []
         _M_BATCH_SIZE.record(n)
         columnar = isinstance(messages, np.ndarray)
-        if columnar and (
-            committee or n < self.crossover or not self._verifier.packed
-        ):
+        if columnar and (committee or n < self.crossover):
             messages, keys, signatures = columns_to_lists(
                 messages, keys, signatures
             )

@@ -31,7 +31,7 @@ turns certificate bitmaps into aggregate public keys in one batched
 kernel launch. On hosts without jax the same API degrades to the exact
 integer backend (`bls.host_fallbacks` counts it) — the chaos plane and
 graftlint never import this module (it is lazy in ops/__init__), so the
-dependency gate only matters for direct callers like bench.py.
+dependency gate only matters for direct callers.
 """
 
 from __future__ import annotations

@@ -14,11 +14,11 @@ come for free, strictly stronger than the reference's all-or-nothing batch.
 
 TPU mapping:
   * All field math is `ops.field` (32, B)-limb f32 vectors: batch on lanes.
-  * The double-scalar multiply is a shared-doubling (Straus) ladder:
-    253 iterations of [double; conditional mixed-add of the constant base
-    point B; conditional mixed-add of the per-item -A_i] under
+  * The double-scalar multiply is a shared-doubling (Straus) ladder with
+    4-bit windows: 64 groups of [4 doublings; add of a multiple of the
+    constant base point B; add of a multiple of the per-item -A_i] under
     `lax.fori_loop` — fixed trip count, no data-dependent control flow,
-    selects instead of branches (SIMD over the batch).
+    table lookups by one-hot instead of branches (SIMD over the batch).
   * Point decompression (sqrt via x^((p-5)/8)) and final compression
     (inverse via x^(p-2)) run on-device with ref10 addition chains.
   * SHA-512 and the mod-L scalar reductions are host-side (cheap, byte-
@@ -112,8 +112,6 @@ BASE_YPX = f.limbs_of_int((BY_INT + BX_INT) % P)
 BASE_YMX = f.limbs_of_int((BY_INT - BX_INT) % P)
 BASE_XY2D = f.limbs_of_int((D2_INT * BX_INT * BY_INT) % P)
 
-SCALAR_BITS = 253  # both s < L < 2^253 and h < L
-
 Point = tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]  # X,Y,Z,T
 
 
@@ -159,10 +157,6 @@ def point_madd(p: Point, q_ypx, q_ymx, q_xy2d, with_t: bool = True) -> Point:
     t3 = f.sub(d2z, c)
     t_out = f.mul(x3, y3) if with_t else jnp.zeros_like(x3)
     return f.mul(x3, t3), f.mul(y3, z3), f.mul(z3, t3), t_out
-
-
-def _select_point(mask: jnp.ndarray, a: Point, b: Point) -> Point:
-    return tuple(f.select(mask, x, y) for x, y in zip(a, b))
 
 
 def point_add_cached(p: Point, q_ypx, q_ymx, q_z, q_t2d, with_t: bool = True) -> Point:
@@ -271,8 +265,12 @@ def _build_neg_a_table(x_neg, a_y):
 
 
 def _verify_kernel_w4(a_y, a_sign, r_enc, s_digits, h_digits):
-    """Windowed variant of `_verify_kernel`; digits are (64, B) f32 of 4-bit
-    windows, most-significant window last (row 63)."""
+    """(32,B) a_y, (B,) a_sign, (32,B) r_enc, (64,B) s/h digits -> (B,) bool.
+
+    Computes enc([s]B + [h](-A)) and compares to the signature's R bytes;
+    byte equality against a canonical re-encoding also enforces canonical R
+    (the reference's verify_strict semantics, crypto/src/lib.rs:186-192).
+    Digits are f32 4-bit windows, most-significant window last (row 63)."""
     x_a, xneg_a, valid = decompress(a_y, a_sign)
     ta_ypx, ta_ymx, ta_z, ta_t2d = _build_neg_a_table(xneg_a, a_y)
     b_ypx, b_ymx, b_xy2d = BASE_TABLE
@@ -541,10 +539,6 @@ def unpack_packed_inputs_dh(packed):
     )
 
 
-def _verify_kernel_w4_packed(a_bytes, r_bytes, s_bytes, h_bytes):
-    return _verify_kernel_w4(*unpack_packed_inputs(a_bytes, r_bytes, s_bytes, h_bytes))
-
-
 def split_packed128(packed: jnp.ndarray) -> tuple:
     """(128, B) u8 wire array -> (a, r, s, h) (32, B) row groups."""
     return packed[0:32], packed[32:64], packed[64:96], packed[96:128]
@@ -603,44 +597,21 @@ def compress(p: Point, invert=f.invert) -> jnp.ndarray:
     return y_c.at[f.NLIMB - 1].add(128.0 * f.parity(x_c))
 
 
-def _verify_kernel(a_y, a_sign, r_enc, s_bits, h_bits):
-    """(32,B) a_y, (B,) a_sign, (32,B) r_enc, (253,B) s/h bits -> (B,) bool.
-
-    Computes enc([s]B + [h](-A)) and compares to the signature's R bytes;
-    byte equality against a canonical re-encoding also enforces canonical R
-    (the reference's verify_strict semantics, crypto/src/lib.rs:186-192).
-    """
-    x_a, xneg_a, valid = decompress(a_y, a_sign)
-    # Affine precomp of -A = (p - x, y) for the ladder's mixed adds.
-    na_ypx = f.add(a_y, xneg_a)
-    na_ymx = f.add(a_y, x_a)
-    na_xy2d = f.mul(D2, f.mul(xneg_a, a_y))
-
-    batch = a_y.shape[1]
-
-    def body(i, acc: Point) -> Point:
-        acc = point_dbl(acc)
-        bit = SCALAR_BITS - 1 - i
-        sb = lax.dynamic_index_in_dim(s_bits, bit, 0, keepdims=False) > 0.5
-        hb = lax.dynamic_index_in_dim(h_bits, bit, 0, keepdims=False) > 0.5
-        with_b = point_madd(acc, BASE_YPX, BASE_YMX, BASE_XY2D)
-        acc = _select_point(sb, with_b, acc)
-        with_a = point_madd(acc, na_ypx, na_ymx, na_xy2d)
-        return _select_point(hb, with_a, acc)
-
-    result = lax.fori_loop(0, SCALAR_BITS, body, point_identity(batch))
-    enc = compress(result)
-    return valid & jnp.all(enc == r_enc, axis=0)
-
-
-_verify_jit = jax.jit(_verify_kernel)
+# The jnp verify programs, under the names `Ed25519TpuVerifier.program_name`
+# returns: what a process runs where it was told to use the CPU, and the
+# committee family on every platform (ops/pallas_ladder.py holds the chip's
+# two). KERNELS are the traceable functions (the mesh verifier wraps them in
+# shard_map), PROGRAMS their jitted forms, one per process.
+KERNELS = {
+    "w4p128": _verify_kernel_w4_packed128,
+    "w4p128dh": _verify_kernel_w4_packed128_dh,
+    "w4c96": _verify_kernel_w4_committee_packed96,
+    "w4c96dh": _verify_kernel_w4_committee_packed96_dh,
+}
+PROGRAMS = {name: jax.jit(fn) for name, fn in KERNELS.items()}
+# The f32-argument reference the tests and __graft_entry__ compare against;
+# no verifier dispatches it.
 _verify_w4_jit = jax.jit(_verify_kernel_w4)
-_verify_w4p_jit = jax.jit(_verify_kernel_w4_packed)
-_verify_w4p128_jit = jax.jit(_verify_kernel_w4_packed128)
-_verify_w4p128dh_jit = jax.jit(_verify_kernel_w4_packed128_dh)
-_verify_w4c_jit = jax.jit(_verify_kernel_w4_committee)
-_verify_w4c96_jit = jax.jit(_verify_kernel_w4_committee_packed96)
-_verify_w4c96dh_jit = jax.jit(_verify_kernel_w4_committee_packed96_dh)
 
 
 # ---------------------------------------------------------------------------
@@ -652,17 +623,14 @@ def prepare_batch(
     messages: Sequence[bytes],
     keys: Sequence[bytes],
     signatures: Sequence[bytes],
-    want_bits: bool = False,
     allow_native: bool = True,
 ) -> dict:
     """numpy staging of a batch. keys: 32-byte pks; signatures: 64 bytes.
 
     Dispatches to the C++ staging plane (crypto/native_staging) when built —
     the Python path below is the reference implementation and fallback.
-    `want_bits` additionally materialises the (253, B) bit arrays used only
-    by the legacy bit-ladder kernel.
     """
-    if allow_native and not want_bits:
+    if allow_native:
         from ..crypto import native_staging
 
         staged = native_staging.stage_batch(messages, keys, signatures)
@@ -680,7 +648,7 @@ def prepare_batch(
 
     s_ok, h_bytes = _stage_scalars(messages, a, r, s)
 
-    staged = dict(
+    return dict(
         a_y=a_y,
         a_sign=a_sign,
         r_enc=r_enc,
@@ -688,12 +656,6 @@ def prepare_batch(
         h_digits=_nibbles(h_bytes),
         s_ok=s_ok,
     )
-    if want_bits:  # legacy bit-ladder kernel only
-        sb = np.unpackbits(s, axis=1, bitorder="little").T[:SCALAR_BITS]
-        hb = np.unpackbits(h_bytes, axis=1, bitorder="little").T[:SCALAR_BITS]
-        staged["s_bits"] = sb.astype(np.float32)
-        staged["h_bits"] = hb.astype(np.float32)
-    return staged
 
 
 def prepare_batch_packed(
@@ -847,43 +809,38 @@ def _pad(arr: np.ndarray, width: int) -> np.ndarray:
     return np.pad(arr, cfg)
 
 
-def _upload_dispatch(fn, padded: np.ndarray, put, tlkey: tuple):
-    """Runs on the pipeline's upload worker: ship one packed chunk,
-    dispatch the kernel (async), return the device mask handle. `put`
-    overrides the host->device transfer (the mesh verifier shards the
-    batch axis here, so the jitted shard_map never reshards a device-0
-    array). `tlkey` is the chunk's (batch, chunk, n) key of the two
-    spans (ops/timeline.py: ring, histogram, profiler annotation).
-
-    Each verifier's DispatchPipeline has ONE upload worker, so chunks of
-    one verifier upload and dispatch in FIFO order while the main thread
-    stages the next chunk; sibling backends (cross-chip work stealing,
-    §5.5i) run their uploads in parallel on their own workers."""
-    import jax as _jax
-
-    with timeline.span("upload", *tlkey, hist=_M_UPLOAD):
-        dev = (put or _jax.device_put)(padded)
-    with timeline.span("dispatch", *tlkey, hist=_M_DISPATCH):
-        return fn(dev)
+def _checked_kernel(kernel: str) -> str:
+    """The two flavours of the generic family: the jnp ladder or the Pallas
+    one. Both take the same arguments, 4-bit digits included."""
+    if kernel not in ("w4", "pallas"):
+        raise ValueError(f"kernel must be 'w4' or 'pallas', not {kernel!r}")
+    return kernel
 
 
 class Ed25519TpuVerifier:
-    """Bucketed, pipelined dispatcher for the jitted kernel.
+    """Bucketed, pipelined dispatcher for the jitted verify programs.
 
-    Batches are padded up to power-of-two lane widths (>= 128 so the lane
-    dimension is full) to bound the number of XLA compilations; oversize
-    batches are split at `chunk` and ride an owned `DispatchPipeline`
-    (ops/pipeline.py): each chunk ships as a packed (128, W) u8 wire array
-    (`prepare_batch_packed`) packed into a REUSED staging buffer, uploaded
-    + dispatched from the pipeline's FIFO upload worker while the NEXT
-    chunk stages, and its mask is fetched on the streaming readback worker
-    while the next chunk dispatches — a bounded window of `pipeline_depth`
-    chunks (default 2 = double buffering) is in flight between staging and
-    readback. `pipeline_depth=1` is the serial/inline mode: no worker
-    threads, deterministic order (the chaos rule, COMPONENTS.md §5.5i).
+    `programs` is the table of what this verifier can dispatch: four
+    callables under the names `program_name` returns, the generic pair of
+    its `kernel` ("w4": the jnp programs `w4p128` / `w4p128dh`; "pallas":
+    the chip's `pallas_p128` / `pallas_p128dh`) and the committee pair
+    `w4c96` / `w4c96dh`, with "dh" where the device hashes (32-byte
+    messages). A chunk dispatches `programs[program_name(...)]`.
 
-    `packed=False` restores the f32 argument path (used by the sharded
-    mesh verifier and the legacy bit-ladder kernel).
+    Both families ride ONE chunk loop (`_run`). Batches are padded up to
+    power-of-two lane widths (>= 128 so the lane dimension is full) to
+    bound the number of XLA compilations; oversize batches are split at
+    `chunk` and ride an owned `DispatchPipeline` (ops/pipeline.py): each
+    chunk ships as a packed u8 wire array ((128, W) generic; (96, W) plus a
+    (W,) index vector committee) packed into a REUSED staging buffer,
+    uploaded + dispatched from the pipeline's FIFO upload worker while the
+    NEXT chunk stages, and its mask is fetched on the streaming readback
+    worker while the next chunk dispatches — a bounded window of
+    `pipeline_depth` chunks (default 2 = double buffering) is in flight
+    between staging and readback. `pipeline_depth=1` is the serial/inline
+    mode: no worker threads, deterministic order (the chaos rule,
+    COMPONENTS.md §5.5i). A family contributes only how a chunk is staged
+    and which device-resident operands precede the staged ones.
     """
 
     # Committee-resident fast path (set_committee /
@@ -897,11 +854,10 @@ class Ed25519TpuVerifier:
         min_bucket: int = 128,
         max_bucket: int = 8192,
         kernel: str = "w4",
-        packed: bool | None = None,
         chunk: int | None = None,
         pipeline_depth: int | None = None,
     ):
-        self.kernel = kernel
+        self.kernel = _checked_kernel(kernel)
         if kernel == "pallas":
             # the pallas grid tiles the batch in BLOCK-lane programs
             from .pallas_ladder import BLOCK
@@ -910,7 +866,6 @@ class Ed25519TpuVerifier:
             max_bucket = max(BLOCK, max_bucket // BLOCK * BLOCK)
         self.min_bucket = min_bucket
         self.max_bucket = max_bucket
-        self.packed = packed if packed is not None else kernel != "bits"
         self.chunk = min(chunk or 4096, max_bucket)
         # The owned dispatch pipeline (ops/pipeline.py): bounded in-flight
         # window, pooled staging buffers, streamed per-chunk readback.
@@ -922,7 +877,12 @@ class Ed25519TpuVerifier:
         # This process imports jax, so its spans can sit on the profiler's
         # clock: a TraceMe, free unless a profiler session is open.
         timeline.set_annotator(jax.profiler.TraceAnnotation)
-        self._put = None  # optional device_put override (mesh sharding)
+        # Placement hooks, all plain transfers on one chip: 2-D wire
+        # arrays, 1-D lane vectors (committee indices), resident tables.
+        # The mesh verifier shards the first two on the batch axis (so a
+        # jitted shard_map never reshards a device-0 array) and replicates
+        # the third.
+        self._put = self._put_lanes = self._replicate = jax.device_put
         # Deferred readback (multi-process mesh, parallel/mesh.py): the
         # per-chunk readback returns the raw device handle and the chunk
         # loop materializes ALL handles in one end-of-batch
@@ -942,6 +902,19 @@ class Ed25519TpuVerifier:
         # has no committee variant yet, and skipping decompress + table
         # build dominates the flavour difference at committee batch sizes.
         self._committee: CommitteeTable | None = None
+        self.programs: dict = self._program_table()
+
+    def _program_table(self) -> dict:
+        """Name -> callable for the four programs `program_name` can
+        return (the mesh verifier supplies the same names shard_map-
+        wrapped)."""
+        if self.kernel == "pallas":
+            from . import pallas_ladder
+
+            generic = pallas_ladder.PROGRAMS
+        else:
+            generic = {n: PROGRAMS[n] for n in ("w4p128", "w4p128dh")}
+        return {**generic, "w4c96": PROGRAMS["w4c96"], "w4c96dh": PROGRAMS["w4c96dh"]}
 
     # -- committee-resident fast path ---------------------------------------
 
@@ -962,15 +935,14 @@ class Ed25519TpuVerifier:
         keys = [bytes(k) for k in keys]
         if self._committee is not None and self._committee.keys == keys:
             return self._committee
-        self._committee = self._build_committee_table(keys)
+        # Built once per registration and placed by `_replicate`: on a mesh
+        # every chip gets its own device-resident copy of the window tables /
+        # validity mask / key bytes, so the sharded committee programs take
+        # them as replicated shard_map operands with zero per-batch movement.
+        self._committee = CommitteeTable(keys, put=self._replicate)
         _M_COMMITTEE_REGS.inc()
         _M_COMMITTEE_SIZE.set(self._committee.size)
         return self._committee
-
-    def _build_committee_table(self, keys: Sequence[bytes]) -> CommitteeTable:
-        """Placement hook: the mesh verifier overrides this to push one
-        replicated copy of the tables to every device in its mesh."""
-        return CommitteeTable(keys)
 
     def verify_batch_mask_committee(
         self,
@@ -1006,25 +978,9 @@ class Ed25519TpuVerifier:
             # failure of that program raises like any other device error:
             # nothing reruns the batch through the host-hash twin.
             device_hash = all(len(m) == 32 for m in messages)
-            return self._run_committee(
-                ct, messages, list(indices), signatures, device_hash
-            )
+            indices = list(indices)
 
-    def _run_committee(self, ct, messages, indices, signatures, device_hash: bool):
-        n = len(messages)
-        program = self.program_name(True, device_hash)
-        tl_batch = timeline.batch_id()
-        pool = self.pipeline.pool
-        defer = self._defer_readback
-        hists = _DEFER_HISTS if defer else _CHUNK_HISTS
-        tasks, oks = [], []
-
-        def make_task(ci: int, lo: int, hi: int) -> ChunkTask:
-            tlkey = (tl_batch, ci, hi - lo)
-            release: list = []
-
-            def stage():
-                _M_CHUNKS.inc()
+            def stage_chunk(lo: int, hi: int):
                 idx_chunk = indices[lo:hi]
                 if device_hash:
                     staged = prepare_batch_committee_dh(
@@ -1037,76 +993,17 @@ class Ed25519TpuVerifier:
                         idx_chunk,
                         signatures[lo:hi],
                     )
-                width = self._bucket(hi - lo)
-                _M_PAD_LANES.inc(width - (hi - lo))
-                oks.append((lo, hi, staged["s_ok"]))
-                if defer:
-                    # Deferred readback never blocks per chunk, so no
-                    # point marks a pooled buffer reusable — fresh
-                    # buffers, jax holds them through the async upload.
-                    return _pad(staged["packed"], width), _pad(staged["idx"], width)
-                packed = pool.pad(staged["packed"], width)
-                idx = pool.pad(staged["idx"], width)
-                release.extend((packed, idx))
-                return packed, idx
+                return (staged["idx"], staged["packed"]), staged["s_ok"]
 
-            def submit(payload):
-                packed, idx = payload
-                self._note_dispatch(program)
-                # `ct` stays PINNED through the closure — a concurrent
-                # epoch re-registration cannot swap tables under this
-                # in-flight chunk (the §5.5c contract).
-                return self._upload_dispatch_committee(
-                    ct, packed, idx, device_hash, tlkey
-                )
-
-            def readback(handle):
-                if defer:
-                    return handle
-                return self._materialize([handle])
-
-            return ChunkTask(
-                stage=stage, submit=submit, readback=readback, tlkey=tlkey,
-                release=release, hists=hists,
-            )
-
-        for ci, lo in enumerate(range(0, n, self.chunk)):
-            tasks.append(make_task(ci, lo, min(lo + self.chunk, n)))
-        hosts = self.pipeline.run(tasks)
-        if defer:
-            hosts = self._materialize_deferred(hosts, n, tl_batch)
-        out = np.empty(n, bool)
-        for (lo, hi, ok), host in zip(oks, hosts):
-            out[lo:hi] = host[: hi - lo] & ok
-        return out
-
-    def _upload_dispatch_committee(
-        self, ct, packed: np.ndarray, idx: np.ndarray, device_hash: bool,
-        tlkey: tuple,
-    ):
-        """Uploader-thread leg of the committee path: ship the (96, W) wire
-        array + (W,) index vector, dispatch against the RESIDENT tables of
-        `ct` (pinned by the caller — never re-read from self, a concurrent
-        re-registration must not swap tables under in-flight chunks)."""
-        import jax as _jax
-
-        put = self._put or _jax.device_put
-        with timeline.span("upload", *tlkey, hist=_M_UPLOAD):
-            dev_p = put(packed)
-            dev_i = put(idx)
-        with timeline.span("dispatch", *tlkey, hist=_M_DISPATCH):
+            # `ct`'s arrays stay PINNED through `_run`'s closure — a
+            # concurrent epoch re-registration cannot swap tables under an
+            # in-flight chunk (the §5.5c contract), they are never re-read
+            # from self.
+            resident = (ct.ta_ypx, ct.ta_ymx, ct.ta_xy2d, ct.valid)
             if device_hash:
-                return _verify_w4c96dh_jit(
-                    ct.ta_ypx,
-                    ct.ta_ymx,
-                    ct.ta_xy2d,
-                    ct.valid,
-                    ct.keys_u8,
-                    dev_i,
-                    dev_p,
-                )
-            return _verify_w4c96_jit(
-                ct.ta_ypx, ct.ta_ymx, ct.ta_xy2d, ct.valid, dev_i, dev_p
+                resident += (ct.keys_u8,)
+            return self._run(
+                n, self.program_name(True, device_hash), stage_chunk, resident
             )
 
     def close(self) -> None:
@@ -1139,20 +1036,6 @@ class Ed25519TpuVerifier:
             base = "pallas_p128" if self.kernel == "pallas" else "w4p128"
         return base + ("dh" if device_hash else "")
 
-    def _packed_fn(self):
-        if self.kernel == "pallas":
-            from . import pallas_ladder
-
-            return pallas_ladder._verify_pallas_p128_jit
-        return _verify_w4p128_jit
-
-    def _packed_dh_fn(self):
-        if self.kernel == "pallas":
-            from . import pallas_ladder
-
-            return pallas_ladder._verify_pallas_p128dh_jit
-        return _verify_w4p128dh_jit
-
     def verify_batch_mask(
         self,
         messages: Sequence[bytes],
@@ -1169,15 +1052,6 @@ class Ed25519TpuVerifier:
             return self._verify_batch_mask(messages, keys, signatures)
 
     def _verify_batch_mask(self, messages, keys, signatures) -> np.ndarray:
-        n = len(messages)
-        if not self.packed:
-            out = np.empty(n, bool)
-            for lo in range(0, n, self.max_bucket):
-                hi = min(lo + self.max_bucket, n)
-                out[lo:hi] = self._run_chunk(
-                    messages[lo:hi], keys[lo:hi], signatures[lo:hi]
-                )
-            return out
         # Device-hash path: when every message is a 32-byte digest (the
         # protocol hot path), h is computed on device and host staging is
         # pure byte concatenation. Other lengths ride the host-hash twin,
@@ -1185,54 +1059,75 @@ class Ed25519TpuVerifier:
         # raises like any other device error — no rerun with host hashing.
         # A columnar batch (three uint8 column arrays) is 32-byte messages
         # by its shape.
-        device_hash = isinstance(messages, np.ndarray) or all(
-            len(m) == 32 for m in messages
-        )
-        return self._run_packed(messages, keys, signatures, device_hash)
-
-    def _run_packed(self, messages, keys, signatures, device_hash: bool):
-        n = len(messages)
-        fn = self._packed_dh_fn() if device_hash else self._packed_fn()
         if isinstance(messages, np.ndarray):
-            stage_fn = prepare_rows_packed_dh
+            device_hash, stage_fn = True, prepare_rows_packed_dh
+        elif all(len(m) == 32 for m in messages):
+            device_hash, stage_fn = True, prepare_batch_packed_dh
         else:
-            stage_fn = prepare_batch_packed_dh if device_hash else prepare_batch_packed
-        program = self.program_name(False, device_hash)
+            device_hash, stage_fn = False, prepare_batch_packed
+
+        def stage_chunk(lo: int, hi: int):
+            # The generic kernel decompresses every lane's key and
+            # rebuilds its -A window table on device — the per-batch
+            # cost the committee path amortizes away.
+            _M_TABLE_BUILDS.inc()
+            _M_DECOMPRESSIONS.inc(hi - lo)
+            staged = stage_fn(messages[lo:hi], keys[lo:hi], signatures[lo:hi])
+            return (staged["packed"],), staged["s_ok"]
+
+        return self._run(
+            len(messages), self.program_name(False, device_hash), stage_chunk
+        )
+
+    def _run(self, n: int, program: str, stage_chunk, resident: tuple = ()):
+        """The chunk loop of both families: split `n` lanes at `chunk`,
+        and per chunk stage, pad into the pooled buffers, upload, dispatch
+        `programs[program]`, read back and apply the host's s < L mask.
+
+        `stage_chunk(lo, hi)` -> (the chunk's arrays to ship, lanes last,
+        in the program's operand order; its (hi - lo,) s_ok). `resident`
+        are device arrays that precede them in every call. The upload
+        worker runs `submit`: each verifier's DispatchPipeline has ONE, so
+        chunks of one verifier upload and dispatch in FIFO order while the
+        caller's thread stages the next chunk; sibling backends (cross-chip
+        work stealing, §5.5i) run their uploads in parallel on their own
+        workers."""
+        fn = self.programs[program]
         tl_batch = timeline.batch_id()
-        pool = self.pipeline.pool
         defer = self._defer_readback
         hists = _DEFER_HISTS if defer else _CHUNK_HISTS
-        tasks, oks = [], []
+        # Deferred readback never blocks per chunk, so no point marks a
+        # pooled buffer reusable — fresh buffers, jax holds them through
+        # the async upload.
+        pad = _pad if defer else self.pipeline.pool.pad
+        oks: list = []
 
         def make_task(ci: int, lo: int, hi: int) -> ChunkTask:
+            # the chunk's (batch, chunk, n) key of its four spans
+            # (ops/timeline.py: ring, histogram, profiler annotation)
             tlkey = (tl_batch, ci, hi - lo)
             release: list = []
 
             def stage():
                 _M_CHUNKS.inc()
-                # The generic kernel decompresses every lane's key and
-                # rebuilds its -A window table on device — the per-batch
-                # cost the committee path amortizes away.
-                _M_TABLE_BUILDS.inc()
-                _M_DECOMPRESSIONS.inc(hi - lo)
-                staged = stage_fn(
-                    messages[lo:hi], keys[lo:hi], signatures[lo:hi]
-                )
+                arrays, s_ok = stage_chunk(lo, hi)
                 width = self._bucket(hi - lo)
                 _M_PAD_LANES.inc(width - (hi - lo))
-                oks.append((lo, hi, staged["s_ok"]))
-                if defer:
-                    # Deferred readback never blocks per chunk, so no
-                    # point marks a pooled buffer reusable — fresh
-                    # buffers, jax holds them through the async upload.
-                    return _pad(staged["packed"], width)
-                packed = pool.pad(staged["packed"], width)
-                release.append(packed)
-                return packed
+                oks.append((lo, hi, s_ok))
+                padded = [pad(a, width) for a in arrays]
+                if not defer:
+                    release.extend(padded)
+                return padded
 
-            def submit(packed):
+            def submit(padded):
                 self._note_dispatch(program)
-                return _upload_dispatch(fn, packed, self._put, tlkey)
+                with timeline.span("upload", *tlkey, hist=_M_UPLOAD):
+                    dev = [
+                        (self._put_lanes if a.ndim == 1 else self._put)(a)
+                        for a in padded
+                    ]
+                with timeline.span("dispatch", *tlkey, hist=_M_DISPATCH):
+                    return fn(*resident, *dev)
 
             def readback(handle):
                 if defer:
@@ -1244,9 +1139,10 @@ class Ed25519TpuVerifier:
                 release=release, hists=hists,
             )
 
-        for ci, lo in enumerate(range(0, n, self.chunk)):
-            tasks.append(make_task(ci, lo, min(lo + self.chunk, n)))
-        hosts = self.pipeline.run(tasks)
+        hosts = self.pipeline.run(
+            make_task(ci, lo, min(lo + self.chunk, n))
+            for ci, lo in enumerate(range(0, n, self.chunk))
+        )
         if defer:
             hosts = self._materialize_deferred(hosts, n, tl_batch)
         out = np.empty(n, bool)
@@ -1281,48 +1177,13 @@ class Ed25519TpuVerifier:
             off += width
         return out
 
-    def _run_chunk(self, messages, keys, signatures) -> np.ndarray:
-        n = len(messages)
-        _M_CHUNKS.inc()
-        _M_TABLE_BUILDS.inc()
-        _M_DECOMPRESSIONS.inc(n)
-        # Legacy f32 path: no separate upload leg (args device_put inside
-        # the jit call), so the timeline records stage/dispatch/readback
-        # and the overlap-headroom pairing has nothing to pair — headroom
-        # honestly reads 0 for a path with no pipelined transfer.
-        tlkey = (timeline.batch_id(), 0, n)
-        with timeline.span("stage", *tlkey, hist=_M_STAGE):
-            staged = prepare_batch(
-                messages, keys, signatures, want_bits=self.kernel == "bits"
-            )
-        width = self._bucket(n)
-        _M_PAD_LANES.inc(width - n)
-        with timeline.span("dispatch", *tlkey):
-            mask = _verify_jit_args(staged, width, self.kernel)
-        with timeline.span("readback", *tlkey, hist=_M_READBACK):
-            host = np.asarray(mask)
-        return host[:n] & staged["s_ok"]
-
 
 def kernel_args(staged: dict, width: int, kernel: str = "w4") -> tuple:
-    """Padded device-call args for the chosen kernel flavour."""
-    scalar_keys = (
-        ("s_bits", "h_bits")
-        if kernel == "bits"
-        else ("s_digits", "h_digits")  # w4 and pallas take 4-bit digits
-    )
+    """`prepare_batch`'s staging as the padded f32 arguments of the
+    reference `_verify_kernel_w4` (the Pallas `_verify_kernel_pallas` takes
+    the same 4-bit digits)."""
+    _checked_kernel(kernel)
     return tuple(
         _pad(staged[k], width)
-        for k in ("a_y", "a_sign", "r_enc", *scalar_keys)
+        for k in ("a_y", "a_sign", "r_enc", "s_digits", "h_digits")
     )
-
-
-def _verify_jit_args(staged: dict, width: int, kernel: str):
-    if kernel == "pallas":
-        from . import pallas_ladder
-
-        return pallas_ladder._verify_pallas_jit(
-            *kernel_args(staged, width, "w4")
-        )
-    fn = _verify_w4_jit if kernel == "w4" else _verify_jit
-    return fn(*kernel_args(staged, width, kernel))

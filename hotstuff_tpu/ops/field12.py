@@ -12,8 +12,7 @@ so a multiply is a 22x22 convolution — 484 limb products vs the f32
 field's 1024 (2.1x fewer), with shorter carry chains (22 rows vs 32).
 
 Whether this BEATS the f32 field on a real TPU depends on the VPU's
-int32 multiply issue rate vs f32 fma (not public; measured by
-`tools/tune_device.py --vpu` / `--field`). This module exists to make
+int32 multiply issue rate vs f32 fma (not public; not measured). This module exists to make
 that decision a benchmark away: it implements the exact same contract as
 `ops.field` for the core ops (mul/sqr/add/sub/carry/canonical) with
 value-level tests against Python bigints (`tests/test_field12.py`). The

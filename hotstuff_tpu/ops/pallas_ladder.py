@@ -221,9 +221,6 @@ def _verify_kernel_pallas(a_y, a_sign, r_enc, s_digits, h_digits):
     return valid & jnp.all(enc == r_enc, axis=0)
 
 
-_verify_pallas_jit = jax.jit(_verify_kernel_pallas)
-
-
 def _verify_kernel_pallas_packed128(packed):
     """(128, B) u8 wire array (see ed.prepare_batch_packed) -> (B,) bool."""
     return _verify_kernel_pallas(
@@ -239,5 +236,10 @@ def _verify_kernel_pallas_packed128_dh(packed):
     return _verify_kernel_pallas(*inputs)
 
 
-_verify_pallas_p128_jit = jax.jit(_verify_kernel_pallas_packed128)
-_verify_pallas_p128dh_jit = jax.jit(_verify_kernel_pallas_packed128_dh)
+# The chip's generic pair, named and tabled as ops/ed25519.py's KERNELS /
+# PROGRAMS are.
+KERNELS = {
+    "pallas_p128": _verify_kernel_pallas_packed128,
+    "pallas_p128dh": _verify_kernel_pallas_packed128_dh,
+}
+PROGRAMS = {name: jax.jit(fn) for name, fn in KERNELS.items()}

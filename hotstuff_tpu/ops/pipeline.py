@@ -38,14 +38,13 @@ and (c) leaked its executor for the life of the process.
   * **depth=1 is the serial/inline mode**: stage, upload, dispatch and
     readback run synchronously on the caller thread with NO worker
     threads at all — the deterministic degeneration the chaos
-    virtual-time plane requires (COMPONENTS.md §5.5i), and the "serial"
-    leg of `bench.py --pipeline-ab`.
+    virtual-time plane requires (COMPONENTS.md §5.5i).
 
 The pipeline times the `stage` and `readback` phases of each task
 (`timeline.span`: the ring under the task's key, the task's histogram of
 that phase, the profiler annotation); the task's `submit` callable owns
-the `upload` and `dispatch` phases (the existing `_upload_dispatch` /
-`_upload_dispatch_committee` seams, which the mesh verifier overrides).
+the `upload` and `dispatch` phases (the `submit` of the verifier's chunk
+loop, `Ed25519TpuVerifier._run`).
 `TIMELINE_STAGES` is the full vocabulary — the graftlint `pipeline`
 pass asserts
 it stays inside `timeline.PHASES` so trace_report.py's device rows keep
@@ -115,8 +114,8 @@ class ChunkTask:
     `stage`    — pack the chunk's wire bytes (caller thread; CPU-only).
     `submit`   — ship the staged payload and dispatch the kernel, returning
                  the async device handle (upload worker; must stamp the
-                 `upload`/`dispatch` timeline phases itself — the
-                 `_upload_dispatch*` seams already do).
+                 `upload`/`dispatch` timeline phases itself, as
+                 `Ed25519TpuVerifier._run` does).
     `readback` — resolve the handle to a host result (readback worker).
     `tlkey`    — the chunk's (batch, chunk, n) DeviceTimeline key; the
                  pipeline times `stage` and `readback` under it. A task
@@ -348,7 +347,7 @@ class DispatchPipeline:
     def _run_serial(self, task: ChunkTask) -> Any:
         """The inline/serial leg: caller-thread stage -> submit ->
         readback, nothing overlapped — deterministic under the chaos
-        virtual-time loop, and the baseline of bench.py --pipeline-ab."""
+        virtual-time loop."""
         try:
             payload = self._staged(task)
             handle, dispatched_t = self._submitted(task, payload)

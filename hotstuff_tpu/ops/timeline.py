@@ -270,9 +270,9 @@ class DeviceTimeline:
             {
                 "batches": len(batches),
                 "chunks": len(chunks),
-                # 6 decimals: bench.py --pipeline-ab compares serial vs
-                # pipelined occupancy STRICTLY, and on fast hosts the gap
-                # can live below 1e-4 (4-digit rounding would tie).
+                # 6 decimals: serial vs pipelined occupancy compare
+                # STRICTLY, and on fast hosts the gap can live below 1e-4
+                # (4-digit rounding would tie).
                 "span_s": round(span_s, 6),
                 "occupancy": round(busy_s / span_s, 6),
                 "overlap_headroom": round(

@@ -6,9 +6,8 @@ from .mesh import (
     default_mesh,
     init_multihost,
     mesh_2d,
-    sharded_committee_fn,
+    sharded_program,
     sharded_qc_verify_fn,
-    sharded_verify_fn,
 )
 
 __all__ = [
@@ -16,7 +15,6 @@ __all__ = [
     "default_mesh",
     "init_multihost",
     "mesh_2d",
-    "sharded_committee_fn",
+    "sharded_program",
     "sharded_qc_verify_fn",
-    "sharded_verify_fn",
 ]

@@ -55,7 +55,7 @@ def init_multihost(
     additionally span hosts: each sidecar process calls this once, JAX's
     distributed runtime forms the global device set (ICI within a slice,
     DCN across slices), and the returned 1-D "dp" mesh shards verification
-    batches over every chip in the job (`sharded_verify_fn`). Consensus/
+    batches over every chip in the job (`sharded_program`). Consensus/
     mempool control traffic stays on host-side TCP (SURVEY §5.8) — only
     the batch-verification collectives ride the accelerator fabric.
 
@@ -76,40 +76,6 @@ def mesh_2d(n_qc: int, n_dp: int, devices=None) -> Mesh:
     devs = np.array(devices if devices is not None else jax.devices())
     assert devs.size >= n_qc * n_dp, "not enough devices for mesh"
     return Mesh(devs[: n_qc * n_dp].reshape(n_qc, n_dp), ("qc", "dp"))
-
-
-def _kernel_fn(kernel: str):
-    if kernel == "pallas":
-        from ..ops.pallas_ladder import _verify_kernel_pallas
-
-        return _verify_kernel_pallas
-    return ed._verify_kernel_w4 if kernel == "w4" else ed._verify_kernel
-
-
-def sharded_verify_fn(mesh: Mesh, dp_axis: str = "dp", kernel: str = "w4"):
-    """Jitted (a_y, a_sign, r_enc, s_scalars, h_scalars) -> (mask, n_valid).
-
-    Inputs are sharded over the batch (lane) dimension on `dp_axis`; each
-    device runs the full ladder on its shard; n_valid is an ICI psum.
-    """
-    batch_spec = P(None, dp_axis)
-    flat_spec = P(dp_axis)
-    base_kernel = _kernel_fn(kernel)
-
-    def local(a_y, a_sign, r_enc, s_scalars, h_scalars):
-        mask = base_kernel(a_y, a_sign, r_enc, s_scalars, h_scalars)
-        n_valid = jax.lax.psum(
-            jnp.sum(mask.astype(jnp.int32)), axis_name=dp_axis
-        )
-        return mask, n_valid
-
-    mapped = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(batch_spec, flat_spec, batch_spec, batch_spec, batch_spec),
-        out_specs=(flat_spec, P()),
-    )
-    return jax.jit(mapped)
 
 
 def sharded_qc_verify_fn(mesh: Mesh):
@@ -150,64 +116,45 @@ def sharded_qc_verify_fn(mesh: Mesh):
     return jax.jit(mapped)
 
 
-def sharded_packed_fn(
-    mesh: Mesh,
-    dp_axis: str = "dp",
-    kernel: str = "w4",
-    device_hash: bool = False,
-):
-    """Jitted (128, B) u8 packed wire array -> (B,) bool, batch sharded on
-    `dp_axis`. Each device unpacks and verifies its shard — the SAME 6x-
-    smaller wire format and unpack-on-device recipe as the single-chip
-    packed path (`ed._verify_kernel_w4_packed128`), so the pipelined
-    uploader and bucketing machinery work unchanged over a mesh. With
-    `device_hash`, rows 96-127 carry 32-byte messages and each device also
-    computes h = SHA-512(R||A||M) mod L for its shard (ops.sha512)."""
-    if kernel == "pallas":
-        from ..ops import pallas_ladder as pl_mod
+@functools.lru_cache(maxsize=None)
+def sharded_program(mesh: Mesh, name: str, dp_axis: str = "dp"):
+    """The verify program `name` (a key of `ed.KERNELS` or of
+    `pallas_ladder.KERNELS`) over the mesh, jitted: same operands, same
+    (B,) bool mask, the batch sharded on `dp_axis`.
 
-        base = (
-            pl_mod._verify_kernel_pallas_packed128_dh
-            if device_hash
-            else pl_mod._verify_kernel_pallas_packed128
-        )
+    Generic family: the (128, B) u8 wire array shards on its lane axis and
+    each device unpacks and verifies its shard — the SAME wire format and
+    unpack-on-device recipe as on one chip, so the chunk loop, the pooled
+    buffers and the bucketing work unchanged over a mesh; with device hash
+    each device also computes h = SHA-512(R||A||M) mod L for its shard
+    (ops.sha512).
+
+    Committee family (`w4c96*`): the `CommitteeTable` arrays ride as
+    REPLICATED operands (`P()` specs — one device-resident copy per chip,
+    pushed once at registration by `ShardedEd25519Verifier.set_committee`);
+    the (B,) i32 validator indices and the (96, B) u8 wire rows shard on
+    `dp_axis`. Each device gathers its lanes' precomputed -A window tables
+    from its local replica — the multi-chip steady state performs zero
+    per-batch decompressions or table builds, exactly like the single-chip
+    committee path. With device hash the replicated committee `keys_u8`
+    gather feeds the on-device SHA-512 (rows 64-95 carry 32-byte messages
+    instead of host-computed h).
+
+    One callable per (mesh, name): verifiers over one mesh share it, as
+    one-chip verifiers share the modules' PROGRAMS."""
+    if name in ed.KERNELS:
+        base = ed.KERNELS[name]
     else:
-        base = (
-            ed._verify_kernel_w4_packed128_dh
-            if device_hash
-            else ed._verify_kernel_w4_packed128
-        )
+        from ..ops import pallas_ladder
 
+        base = pallas_ladder.KERNELS[name]
+    in_specs = P(None, dp_axis)
+    if name.startswith("w4c96"):
+        # (ta_ypx, ta_ymx, ta_xy2d, valid[, keys_u8]) replicated, then idx + wire
+        tables = (P(),) * (5 if name.endswith("dh") else 4)
+        in_specs = (*tables, P(dp_axis), in_specs)
     mapped = shard_map(
-        base, mesh=mesh, in_specs=P(None, dp_axis), out_specs=P(dp_axis)
-    )
-    return jax.jit(mapped)
-
-
-def sharded_committee_fn(mesh: Mesh, dp_axis: str = "dp", device_hash: bool = False):
-    """Committee-resident verification over the mesh.
-
-    The `CommitteeTable` arrays ride as REPLICATED operands (`P()` specs —
-    one device-resident copy per chip, pushed once at registration by
-    `ShardedEd25519Verifier.set_committee`); the (96, B) u8 wire rows and
-    (B,) i32 validator indices shard on `dp_axis`. Each device gathers its
-    lanes' precomputed -A window tables from its local replica — the
-    multi-chip steady state performs zero per-batch decompressions or table
-    builds, exactly like the single-chip committee path. With `device_hash`
-    the replicated committee `keys_u8` gather feeds the on-device SHA-512
-    (rows 64-95 carry 32-byte messages instead of host-computed h)."""
-    base = (
-        ed._verify_kernel_w4_committee_packed96_dh
-        if device_hash
-        else ed._verify_kernel_w4_committee_packed96
-    )
-    # (ta_ypx, ta_ymx, ta_xy2d, valid[, keys_u8]) replicated, then idx + wire
-    table_specs = (P(),) * (5 if device_hash else 4)
-    mapped = shard_map(
-        base,
-        mesh=mesh,
-        in_specs=(*table_specs, P(dp_axis), P(None, dp_axis)),
-        out_specs=P(dp_axis),
+        base, mesh=mesh, in_specs=in_specs, out_specs=P(dp_axis)
     )
     return jax.jit(mapped)
 
@@ -215,27 +162,31 @@ def sharded_committee_fn(mesh: Mesh, dp_axis: str = "dp", device_hash: bool = Fa
 class ShardedEd25519Verifier(ed.Ed25519TpuVerifier):
     """Drop-in Ed25519TpuVerifier that shards batches over a mesh.
 
-    Uses the packed (128 B/signature) wire format and the base class's
+    It changes WHERE arrays land and WHICH callables the program table
+    holds, and nothing of dispatch: the base class's one chunk loop and
     owned DispatchPipeline (ops/pipeline.py: bounded in-flight window,
     pooled staging buffers, streamed per-chunk readback — single-process
     meshes only; a multi-process mesh forces the serial depth=1 window,
-    see __init__); chunks are device_put with an explicit batch-axis
-    NamedSharding so the transfer lands sharded (no device-0 staging +
-    reshard). `packed=False` restores the f32-argument
-    `sharded_verify_fn` path (used by the legacy bit-ladder kernel).
+    see __init__) run as on one chip. `programs` holds the same four names
+    (`program_name`; `kernel` is "w4" or "pallas") `shard_map`-wrapped
+    (`sharded_program`); the placement hooks device_put a chunk's wire
+    array and lane vector with an explicit batch-axis NamedSharding so the
+    transfer lands sharded (no device-0 staging + reshard).
 
     The committee-resident path (`set_committee` /
     `verify_batch_mask_committee`) is first-class: registration pushes one
-    replicated copy of the `CommitteeTable` arrays to every chip, and the
-    committee kernels are shard_map-wrapped with the tables as replicated
-    operands while the 96 B wire rows + 4 B indices shard on the dp axis —
-    multi-chip deployments inherit the single-chip zero-decompression
-    steady state, with the same snapshot-pinned reconfig-safety contract
-    (an epoch re-registration never swaps tables under in-flight chunks)."""
+    replicated copy of the `CommitteeTable` arrays to every chip
+    (`_replicate`), and the committee programs take the tables
+    as replicated operands while the 96 B wire rows + 4 B indices shard on
+    the dp axis — multi-chip deployments inherit the single-chip
+    zero-decompression steady state, with the same snapshot-pinned
+    reconfig-safety contract (an epoch re-registration never swaps tables
+    under in-flight chunks)."""
 
     def __init__(self, mesh: Mesh | None = None, **kw):
-        super().__init__(**kw)
+        # set first: the base class builds the program table over it
         self.mesh = mesh or default_mesh()
+        super().__init__(**kw)
         self._ndev = int(np.prod([self.mesh.shape[a] for a in self.mesh.axis_names]))
         me = jax.process_index()
         self._multiprocess = any(
@@ -292,57 +243,13 @@ class ShardedEd25519Verifier(ed.Ed25519TpuVerifier):
         self._replicate = functools.partial(
             jax.device_put, device=NamedSharding(self.mesh, P())
         )
-        self._sharded_committee = sharded_committee_fn(self.mesh, dp)
-        self._sharded_committee_dh = sharded_committee_fn(
-            self.mesh, dp, device_hash=True
-        )
-        if self.packed:
-            self._sharded_packed = sharded_packed_fn(self.mesh, dp, self.kernel)
-            self._sharded_packed_dh = sharded_packed_fn(
-                self.mesh, dp, self.kernel, device_hash=True
-            )
-        else:
-            self._fn = sharded_verify_fn(self.mesh, dp, self.kernel)
 
-    def _packed_fn(self):
-        return self._sharded_packed
-
-    def _packed_dh_fn(self):
-        return self._sharded_packed_dh
-
-    def _build_committee_table(self, keys):
-        """Registration-time replication: every chip in the mesh gets its
-        own device-resident copy of the window tables / validity mask /
-        key bytes, so the sharded committee kernels consume them as
-        replicated shard_map operands with zero per-batch movement."""
-        return ed.CommitteeTable(keys, put=self._replicate)
-
-    def _upload_dispatch_committee(self, ct, packed, idx, device_hash, tlkey):
-        """Uploader-thread leg of the committee path over the mesh: the
-        (96, W) wire rows and (W,) index vector land SHARDED on the dp axis
-        (no device-0 staging + reshard) and dispatch against the PINNED
-        replicated tables of `ct` — a concurrent epoch re-registration must
-        not swap replicas under in-flight sharded chunks. `tlkey` threads
-        the chunk's device-timeline key (ops/timeline.py) through, same as
-        the single-chip leg."""
-        tl = ed.timeline
-        with tl.span("upload", *tlkey, hist=ed._M_UPLOAD):
-            dev_p = self._put(packed)
-            dev_i = self._put_lanes(idx)
-        with tl.span("dispatch", *tlkey, hist=ed._M_DISPATCH):
-            if device_hash:
-                return self._sharded_committee_dh(
-                    ct.ta_ypx,
-                    ct.ta_ymx,
-                    ct.ta_xy2d,
-                    ct.valid,
-                    ct.keys_u8,
-                    dev_i,
-                    dev_p,
-                )
-            return self._sharded_committee(
-                ct.ta_ypx, ct.ta_ymx, ct.ta_xy2d, ct.valid, dev_i, dev_p
-            )
+    def _program_table(self) -> dict:
+        dp = self.mesh.axis_names[0]
+        return {
+            name: sharded_program(self.mesh, name, dp)
+            for name in super()._program_table()
+        }
 
     def _materialize(self, masks) -> np.ndarray:
         """Multi-host mesh: the mask is sharded across PROCESSES, so a
@@ -363,13 +270,3 @@ class ShardedEd25519Verifier(ed.Ed25519TpuVerifier):
                 multihost_utils.process_allgather(full, tiled=True)
             )
         return np.asarray(full)
-
-    def _run_chunk(self, messages, keys, signatures) -> np.ndarray:
-        n = len(messages)
-        staged = ed.prepare_batch(
-            messages, keys, signatures, want_bits=self.kernel == "bits"
-        )
-        width = self._bucket(n)
-        ed._M_PAD_LANES.inc(width - n)
-        mask, _ = self._fn(*ed.kernel_args(staged, width, self.kernel))
-        return self._materialize([mask])[:n] & staged["s_ok"]

@@ -14,7 +14,7 @@ this module makes per-stage breakdowns a permanent, machine-readable artifact:
   * `snapshot_json()` — one compact JSON object (no raw buckets) for the
     periodic `METRICS {json}` log line that `benchmark.logs.LogParser`
     scrapes; `dump()` / `write_json(path)` — the full structured artifact
-    (`bench.py --metrics-out`, `node run --metrics-out`).
+    (`node run --metrics-out`).
   * `start_periodic_emitter(interval_s)` — a daemon thread logging the
     snapshot line on `hotstuff.metrics` at INFO.
 

@@ -36,8 +36,8 @@ evaluates. This module closes both gaps:
     (`network/net.py` FrameReader), serving the plane's dump: snapshot
     ring, alert history, active alerts, cumulative lane stats, and the
     device-occupancy timeline summary (ops/timeline.py) when one is
-    attached. `node run --telemetry-port` and `bench.py
-    --telemetry-port` expose it; `tools/telemetry_dash.py` polls N nodes
+    attached. `node run --telemetry-port` exposes it;
+    `tools/telemetry_dash.py` polls N nodes
     live or reads the same shape out of a chaos report offline.
 
 Registered telemetry planes also feed the watchdog's CONTEXT hooks: every
@@ -1152,7 +1152,7 @@ def serve_in_thread(
     snapshot_interval_s: float | None = None,
 ) -> int:
     """Run a TelemetryServer on a daemon thread with its own event loop
-    (the seam for synchronous hosts like bench.py). Optionally ticks the
+    (the seam for synchronous hosts). Optionally ticks the
     plane's snapshot loop at `snapshot_interval_s`. Returns the bound
     port; the thread dies with the process."""
     import threading

@@ -20,7 +20,7 @@ def keys():
 
 def test_single_requests_batched(keys, run_async):
     async def body():
-        svc = BatchVerificationService(CpuBackend(), max_delay=0.01)
+        svc = BatchVerificationService(CpuBackend())
         digest = Digest.of(b"vote")
         results = await asyncio.gather(
             *[
@@ -37,7 +37,7 @@ def test_single_requests_batched(keys, run_async):
 
 def test_invalid_items_isolated(keys, run_async):
     async def body():
-        svc = BatchVerificationService(CpuBackend(), max_delay=0.01)
+        svc = BatchVerificationService(CpuBackend())
         digest = Digest.of(b"vote")
         pk0, sk0 = keys[0]
         pk1, sk1 = keys[1]
@@ -51,7 +51,7 @@ def test_invalid_items_isolated(keys, run_async):
 def test_size_flush_before_deadline(keys, run_async):
     async def body():
         svc = BatchVerificationService(
-            CpuBackend(), max_batch=8, max_delay=10.0
+            CpuBackend(), max_batch=8
         )
         digest = Digest.of(b"vote")
         pk, sk = keys[0]
@@ -71,7 +71,7 @@ def test_size_flush_before_deadline(keys, run_async):
 def test_group_larger_than_max_batch(keys, run_async):
     async def body():
         svc = BatchVerificationService(
-            CpuBackend(), max_batch=3, max_delay=0.005
+            CpuBackend(), max_batch=3
         )
         digest = Digest.of(b"qc")
         pairs = [(pk, Signature.new(digest, sk)) for pk, sk in keys]
@@ -108,7 +108,7 @@ def test_urgent_group_dispatches_separately(keys, run_async):
 
     async def body():
         backend = _RecordingBackend()
-        svc = BatchVerificationService(backend, max_batch=1000, max_delay=5.0)
+        svc = BatchVerificationService(backend, max_batch=1000)
         digest = Digest.of(b"vote")
         sigs = {pk: Signature.new(digest, sk) for pk, sk in keys}
         pk0, sk0 = keys[0]
@@ -151,7 +151,7 @@ def test_urgent_flush_not_blocked_by_full_dispatch_slots(keys, run_async):
 
         backend = GatedBackend()
         svc = BatchVerificationService(
-            backend, max_batch=50, max_delay=0.001, max_concurrent_dispatches=2
+            backend, max_batch=50, max_concurrent_dispatches=2
         )
         digest = Digest.of(b"vote")
         pk0, sk0 = keys[0]

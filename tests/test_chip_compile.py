@@ -208,19 +208,14 @@ def test_whole_verify_program_compiles_for_v5e(one_chip, program):
     from hotstuff_tpu.ops import pallas_ladder
 
     u8 = jnp.uint8
+    fn = {**ed.PROGRAMS, **pallas_ladder.PROGRAMS}[program]
     if program == "w4c96dh":
-        fn = ed._verify_w4c96dh_jit
         shapes = (
             *_committee_table_shapes(one_chip),
             jax.ShapeDtypeStruct((SERVED_WIDTH,), jnp.int32, sharding=one_chip),
             jax.ShapeDtypeStruct((96, SERVED_WIDTH), u8, sharding=one_chip),
         )
     else:
-        fn = (
-            pallas_ladder._verify_pallas_p128dh_jit
-            if program == "pallas_p128dh"
-            else pallas_ladder._verify_pallas_p128_jit
-        )
         shapes = (
             jax.ShapeDtypeStruct((128, SERVED_WIDTH), u8, sharding=one_chip),
         )
@@ -239,15 +234,14 @@ def test_sharded_verify_program_compiles_for_2x2(topo, program):
     mesh = Mesh(np.array(topo.devices[:4]), ("dp",))
     lanes = NamedSharding(mesh, P("dp"))
     wire = NamedSharding(mesh, P(None, "dp"))
+    fn = pm.sharded_program(mesh, program, "dp")
     if program == "w4c96dh":
-        fn = pm.sharded_committee_fn(mesh, "dp", device_hash=True)
         shapes = (
             *_committee_table_shapes(NamedSharding(mesh, P())),
             jax.ShapeDtypeStruct((SERVED_WIDTH,), jnp.int32, sharding=lanes),
             jax.ShapeDtypeStruct((96, SERVED_WIDTH), jnp.uint8, sharding=wire),
         )
     else:
-        fn = pm.sharded_packed_fn(mesh, "dp", "pallas", device_hash=True)
         shapes = (
             jax.ShapeDtypeStruct((128, SERVED_WIDTH), jnp.uint8, sharding=wire),
         )

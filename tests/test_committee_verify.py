@@ -168,6 +168,53 @@ class TestCommitteeKernel:
         assert got2.tolist() == [w for _m, _k, _s, w in live]
 
 
+@pytest.mark.parametrize("device_hash", [False, True], ids=["host_hash", "device_hash"])
+def test_two_chunks_ragged_tail_match_generic_family(device_hash):
+    """The families share one chunk loop (`Ed25519TpuVerifier._run`): a
+    committee batch split at `chunk` into a full chunk and a ragged one,
+    a forged lane in each, gives the generic family's mask lane for lane,
+    with both staged arrays (indices, wire rows) padded per chunk and the
+    tables of ONE snapshot ahead of them in every dispatch. Width 128 and
+    `chunk=64`, as the rest of tier-1 compiles."""
+    n = 101  # chunks of 64 and 37 lanes
+    if device_hash:
+        from tests.common import rfc8032_keypair, rfc8032_sign
+
+        kps = [rfc8032_keypair(bytes([i + 1]) * 32) for i in range(4)]
+        pool = []
+        for i, kp in enumerate(kps):
+            m = (b"chunks-%d" % i).ljust(32, b"\0")  # a 32-byte digest
+            pool.append((m, kp[0], rfc8032_sign(kp, m)))
+    else:
+        pool = [_unhex(v) for v in VECTORS]  # messages of 0, 1, 2, 1023 bytes
+    msgs = [pool[i % 4][0] for i in range(n)]
+    pks = [pool[i % 4][1] for i in range(n)]
+    sigs = [pool[i % 4][2] for i in range(n)]
+    for lane in (5, 100):  # chunk 0, chunk 1
+        sigs[lane] = sigs[lane][:33] + bytes([sigs[lane][33] ^ 1]) + sigs[lane][34:]
+    want = [lane not in (5, 100) for lane in range(n)]
+
+    v = ed.Ed25519TpuVerifier(
+        min_bucket=128, max_bucket=128, kernel="w4", chunk=64
+    )
+    try:
+        table = v.set_committee(sorted({k for _, k, _ in pool}))
+        idx = [table.index[k] for k in pks]
+        p0 = metrics.counter("verifier.pad_lanes").value
+        b0, d0 = _M_BUILDS.value, _M_DECOMP.value
+        committee = v.verify_batch_mask_committee(msgs, idx, sigs)
+        assert metrics.counter("verifier.pad_lanes").value == p0 + 2 * 128 - n
+        assert (_M_BUILDS.value, _M_DECOMP.value) == (b0, d0)
+        generic = v.verify_batch_mask(msgs, pks, sigs)
+        assert (_M_BUILDS.value, _M_DECOMP.value) == (b0 + 2, d0 + n)
+    finally:
+        v.close()
+    assert committee.tolist() == want
+    assert committee.tolist() == generic.tolist()
+    dh = "dh" if device_hash else ""
+    assert dict(v.dispatched) == {"w4c96" + dh: 2, "w4p128" + dh: 2}
+
+
 class TestBackendRouting:
     def test_tagged_batches_ride_committee_kernel(self):
         """TpuBackend: committee-tagged batches whose keys all resolve ride

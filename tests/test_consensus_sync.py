@@ -53,7 +53,18 @@ def test_missing_parent_requests_then_loops_back(run_async, base_port):
         req = decode_consensus_message(msg.data)
         assert isinstance(req, SyncRequest)
         assert req.digest == b1.digest() and req.requester == me
-        assert set(msg.addresses) == set(cmt.broadcast_addresses(me))
+        # Attempt 0 goes to ONE peer, a pure function of (digest, own key):
+        # always broadcasting made every missing digest n-1 frames per
+        # retry tick across the committee (Synchronizer._peers).
+        everyone = set(cmt.broadcast_addresses(me))
+        assert list(msg.addresses) == sync._peers(b1.digest(), 0)
+        assert len(msg.addresses) == 1 and set(msg.addresses) <= everyone
+
+        # A retry past sync_retry_delay escalates the same request to all.
+        await sync._retry_pass(asyncio.get_running_loop().time() + 10.001)
+        again = await asyncio.wait_for(network_tx.get(), 5)
+        assert decode_consensus_message(again.data) == req
+        assert set(again.addresses) == everyone
 
         # The parent arrives (e.g. via a peer's re-send) -> LoopBack fires.
         await store.write(b1.digest().data, encode_stored_block(b1))

@@ -87,7 +87,7 @@ class TestServiceDedup:
     def test_repeat_verification_skips_backend(self, run_async):
         async def body():
             backend = _CountingBackend()
-            svc = BatchVerificationService(backend, max_delay=0.001)
+            svc = BatchVerificationService(backend)
             m, pk, sig = _triple(1)
             assert await svc.verify(m, pk, sig)
             assert backend.verified == 1
@@ -101,7 +101,7 @@ class TestServiceDedup:
     def test_seed_verified_short_circuits_first_check(self, run_async):
         async def body():
             backend = _CountingBackend()
-            svc = BatchVerificationService(backend, max_delay=0.001)
+            svc = BatchVerificationService(backend)
             m, pk, sig = _triple(2)
             svc.seed_verified(m, pk, sig)  # the aggregator's seam
             assert await svc.verify(m, pk, sig)
@@ -113,7 +113,7 @@ class TestServiceDedup:
         async def body():
             backend = _CountingBackend()
             svc = BatchVerificationService(
-                backend, max_delay=0.001, dedup_cache_size=0
+                backend, dedup_cache_size=0
             )
             assert svc.dedup is None
             m, pk, sig = _triple(3)
@@ -126,7 +126,7 @@ class TestServiceDedup:
     def test_mixed_group_only_misses_dispatch(self, run_async):
         async def body():
             backend = _CountingBackend()
-            svc = BatchVerificationService(backend, max_delay=0.001)
+            svc = BatchVerificationService(backend)
             triples = [_triple(i) for i in range(4)]
             for m, pk, sig in triples[:2]:
                 svc.seed_verified(m, pk, sig)
@@ -146,7 +146,7 @@ class TestServiceDedup:
 
         async def body():
             backend = _CountingBackend()
-            svc = BatchVerificationService(backend, max_delay=0.001)
+            svc = BatchVerificationService(backend)
             m, pk, sig = _triple(9)
             for _ in range(2):
                 mask = await svc.verify_group(
@@ -162,7 +162,7 @@ class TestServiceDedup:
     def test_committee_tag_reaches_backend(self, run_async):
         async def body():
             backend = _CountingBackend(committee_routing=True)
-            svc = BatchVerificationService(backend, max_delay=0.001)
+            svc = BatchVerificationService(backend)
             m, pk, sig = _triple(5)
             await svc.verify(m, pk, sig, committee=True)
             m2, pk2, sig2 = _triple(6)
@@ -184,7 +184,7 @@ class TestServiceDedup:
                 return [True] * len(messages)
 
         async def body():
-            svc = BatchVerificationService(StrictBackend(), max_delay=0.001)
+            svc = BatchVerificationService(StrictBackend())
             m, pk, sig = _triple(7)
             assert await svc.verify(m, pk, sig, committee=True)
             assert StrictBackend.verified == 1
@@ -248,7 +248,7 @@ class TestColumnarGroups:
         async def drive(as_rows):
             backend = _OddIdsBackend()
             svc = BatchVerificationService(
-                backend, max_delay=0.001, dedup_cache_size=4
+                backend, dedup_cache_size=4
             )
             before = _dedup_counters()
             masks = []
@@ -280,7 +280,7 @@ class TestColumnarGroups:
     def test_triple_cached_by_one_path_hits_by_the_other(self, run_async):
         async def body():
             backend = _OddIdsBackend()
-            svc = BatchVerificationService(backend, max_delay=0.001)
+            svc = BatchVerificationService(backend)
             # list path and the aggregator's seam in, rows out
             m, pk, sig = _triple(21)
             assert await svc.verify(m, pk, sig)
@@ -303,7 +303,7 @@ class TestColumnarGroups:
 
         async def body():
             backend = _OddIdsBackend()
-            svc = BatchVerificationService(backend, max_delay=0.05)
+            svc = BatchVerificationService(backend)
             svc.seed_verified(*_triple(31))
             t = [_triple(i) for i in (31, 32, 33)]
             got = await asyncio.gather(
@@ -337,7 +337,7 @@ class TestColumnarGroups:
                 return messages[:, 0] % 2 == 1
 
         async def body():
-            svc = BatchVerificationService(ColumnBackend(), max_delay=0.05)
+            svc = BatchVerificationService(ColumnBackend())
             svc.seed_verified(*_triple(43))
             got = await asyncio.gather(
                 svc.verify_rows(_rows([41, 42])), svc.verify_rows(_rows([43, 44, 45]))

@@ -546,107 +546,6 @@ def test_loadgen_cli_selftest_smoke(tmp_path):
     assert summary["latency_ms"]["p99"] >= summary["latency_ms"]["p50"]
 
 
-def test_bench_ingress_mode_emits_artifact(tmp_path):
-    """`bench.py --ingress --ingress-backend pure` exits rc 0 with the
-    INGRESS_rN.json-shaped line: arrival curve, offered vs committed
-    tx/s, latency percentiles, backend field."""
-    import json
-    import subprocess
-    import sys
-
-    metrics_path = tmp_path / "ingress-metrics.json"
-    proc = subprocess.run(
-        [
-            sys.executable,
-            os.path.join(os.path.dirname(__file__), "..", "bench.py"),
-            "--ingress",
-            "--ingress-backend", "pure",
-            "--ingress-rate", "20",
-            "--ingress-duration", "3",
-            "--ingress-clients", "3",
-            "--metrics-out", str(metrics_path),
-        ],
-        capture_output=True,
-        text=True,
-        timeout=300,
-        cwd=os.path.join(os.path.dirname(__file__), ".."),
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    body = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert body["metric"] == "ingress_committed_tx_per_sec"
-    assert body["backend"] == "pure-python"
-    for key in ("curve", "offered_tps", "committed_tps", "shed", "latency_ms"):
-        assert key in body, key
-    assert body["committed_tps"] > 0
-    # the metrics artifact carries the ingress namespace with real counts
-    dump = json.loads(metrics_path.read_text())
-    assert dump["counters"]["ingress.received"] == body["offered"]
-    assert dump["counters"]["ingress.forwarded"] > 0
-
-
-def test_bench_scheduler_ab_emits_artifact(tmp_path):
-    """`bench.py --scheduler-ab --sched-backend pure` exits rc 0 with the
-    SCHED_rN.json-shaped line: a legacy and a scheduler leg (critical/bulk
-    lane queue-delay percentiles, verified/sec), the improvement ratios,
-    and the backend field."""
-    import json
-    import subprocess
-    import sys
-
-    metrics_path = tmp_path / "sched-metrics.json"
-    proc = subprocess.run(
-        [
-            sys.executable,
-            os.path.join(os.path.dirname(__file__), "..", "bench.py"),
-            "--scheduler-ab",
-            "--sched-backend", "pure",
-            "--sched-duration", "2",
-            "--metrics-out", str(metrics_path),
-        ],
-        capture_output=True,
-        text=True,
-        timeout=300,
-        cwd=os.path.join(os.path.dirname(__file__), ".."),
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    body = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert body["metric"] == "critical_lane_p99_queue_ms"
-    assert body["backend"] == "pure-python"
-    for leg in ("legacy", "scheduler"):
-        assert body[leg]["critical_groups"] > 0, leg
-        assert body[leg]["bulk_groups"] > 0, leg
-        assert body[leg]["critical_queue_ms"]["count"] > 0, leg
-        assert body[leg]["verified_per_sec"] > 0, leg
-    assert body["p99_improvement"] is not None
-    assert body["verified_ratio"] is not None
-    # the metrics artifact carries the scheduler namespace with real counts
-    dump = json.loads(metrics_path.read_text())
-    assert dump["counters"]["scheduler.submitted"] > 0
-    assert dump["counters"]["scheduler.critical_dispatches"] > 0
-
-
-def test_bench_refuses_to_measure_the_cpu_unasked(monkeypatch, capsys):
-    """The kernel bench on a machine with no chip exits non-zero unless the
-    process was told JAX_PLATFORMS=cpu: a CPU number may never be printed
-    under a device metric's name. (In-process: `jax` is already on the CPU
-    here; only what the process was TOLD is patched.)"""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    import bench
-    from hotstuff_tpu import ops
-
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--pipeline-ab"])
-    monkeypatch.setattr(ops, "cpu_requested", lambda: False)
-    monkeypatch.setattr(
-        bench, "bench_pipeline_ab", lambda *a: pytest.fail("bench ran on an unasked CPU")
-    )
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code == 1
-    assert "no accelerator found" in capsys.readouterr().err
-
-
 # ---------------------------------------------------------------------------
 # tools/lint_metrics.py: the metric/trace namespace lint
 

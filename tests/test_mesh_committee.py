@@ -160,6 +160,45 @@ class TestShardedCommitteeKernel:
             msgs, sidx, sigs
         ).tolist()
 
+    @pytest.mark.parametrize(
+        "device_hash", [False, True], ids=["host_hash", "device_hash"]
+    )
+    def test_two_chunks_ragged_tail_on_the_mesh(
+        self, committee, digest_batch, sharded, device_hash
+    ):
+        """The mesh verifier overrides placement and the program table and
+        nothing of dispatch: the base class's one chunk loop splits a
+        committee batch at `chunk` (one full mesh bucket of 512 lanes and
+        a ragged one of 40), puts each chunk's index vector and wire rows
+        sharded, and the replicated tables of one snapshot ride ahead of
+        them. A forged lane in each chunk; the mask is the expected one."""
+        n = 552
+        if device_hash:
+            _, pks = committee
+            msgs, _, idx, sigs, _ = digest_batch
+            pool = list(zip(msgs[:8], idx[:8], sigs[:8]))
+            table = sharded.set_committee(pks)
+        else:
+            vmsgs, vpks, vsigs = _vector_batch()
+            table = sharded.set_committee(sorted(set(vpks)))
+            pool = [
+                (m, table.index[k], s)
+                for m, k, s in zip(vmsgs[:4], vpks[:4], vsigs[:4])
+            ]
+        k = len(pool)
+        msgs = [pool[i % k][0] for i in range(n)]
+        idx = [pool[i % k][1] for i in range(n)]
+        sigs = [pool[i % k][2] for i in range(n)]
+        for lane in (7, 530):  # chunk 0, chunk 1
+            sigs[lane] = bytes([sigs[lane][0] ^ 1]) + sigs[lane][1:]
+        assert sharded.chunk == 512
+        name = sharded.program_name(True, device_hash)
+        c0, p0 = sharded.dispatched[name], _M_PAD.value
+        got = sharded.verify_batch_mask_committee(msgs, idx, sigs, table=table)
+        assert got.tolist() == [lane not in (7, 530) for lane in range(n)]
+        assert sharded.dispatched[name] == c0 + 2
+        assert _M_PAD.value == p0 + 2 * 512 - n
+
     def test_zero_decompressions_in_steady_state(
         self, committee, digest_batch, sharded
     ):

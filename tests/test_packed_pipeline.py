@@ -115,11 +115,6 @@ class TestPipelinedVerifier:
         v = ed.Ed25519TpuVerifier(max_bucket=128, kernel="w4", chunk=128)
         assert v.verify_batch_mask(msgs, pks, sigs).all()
 
-    def test_packed_false_legacy_path(self):
-        msgs, pks, sigs = _signed(20)
-        v = ed.Ed25519TpuVerifier(max_bucket=128, kernel="w4", packed=False)
-        assert v.verify_batch_mask(msgs, pks, sigs).all()
-
 
 class TestUrgentBypass:
     def test_urgent_flush_bypasses_busy_dispatch_slots(self, run_async):
@@ -146,7 +141,7 @@ class TestUrgentBypass:
 
             release = threading.Event()
             svc = BatchVerificationService(
-                SlowBackend(release), max_delay=0.001, max_concurrent_dispatches=1
+                SlowBackend(release), max_concurrent_dispatches=1
             )
             rng = random.Random(1)
             pk, sk = generate_keypair(rng)

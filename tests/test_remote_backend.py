@@ -27,7 +27,7 @@ def triples():
 def test_round_trip_and_mask(triples, run_async, base_port):
     async def body():
         server = asyncio.create_task(
-            serve(("127.0.0.1", base_port), CpuBackend(), max_delay=0.001)
+            serve(("127.0.0.1", base_port), CpuBackend())
         )
         await asyncio.sleep(0.2)
         backend = RemoteBackend(("127.0.0.1", base_port), crossover=1)
@@ -95,7 +95,7 @@ def test_oversized_request_dropped_server_survives(triples, run_async, base_port
 
     async def body():
         server = asyncio.create_task(
-            serve(("127.0.0.1", base_port), CpuBackend(), max_delay=0.001)
+            serve(("127.0.0.1", base_port), CpuBackend())
         )
         await asyncio.sleep(0.2)
         try:
@@ -320,7 +320,7 @@ def test_live_serve_same_mask_whichever_parse(mlen, run_async, base_port):
 
     async def body():
         server = asyncio.create_task(
-            serve(("127.0.0.1", base_port), CpuBackend(), max_delay=0.001)
+            serve(("127.0.0.1", base_port), CpuBackend())
         )
         await asyncio.sleep(0.2)
         c0, a0 = columnar.value, arrived.value

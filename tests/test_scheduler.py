@@ -1,6 +1,5 @@
 """DeviceScheduler: typed source lanes, preemptive critical dispatch,
-alignment-grid bucket sizing, continuous refill, and the legacy-loop
-parity surface `bench.py --scheduler-ab` compares against.
+alignment-grid bucket sizing, continuous refill.
 
 Dependency-free by design (stub backend, no `cryptography`, no jax): the
 scheduler never looks at message bytes, so these tests exercise the real
@@ -173,33 +172,6 @@ def test_sync_lane_flushes_before_mempool_deadline(run_async):
     run_async(body())
 
 
-def test_legacy_mode_records_same_lane_attribution(run_async):
-    """use_scheduler=False (the --scheduler-ab baseline) still resolves
-    source classes and feeds the same per-lane queue-delay reservoir, so
-    the A/B compares like with like."""
-
-    async def body():
-        backend = StubBackend()
-        svc = BatchVerificationService(
-            backend, use_scheduler=False, max_delay=0.002, inline=True
-        )
-        assert svc.scheduler is None
-        bm, bp = _group(8, b"b")
-        cm, cp = _group(2, b"c")
-        bulk = asyncio.ensure_future(
-            svc.verify_group(bm, bp, source="mempool", dedup=False)
-        )
-        crit = asyncio.ensure_future(
-            svc.verify_group(cm, cp, source="consensus", dedup=False)
-        )
-        assert all(await crit) and all(await bulk)
-        summary = svc.lane_stats.summary()
-        assert summary["consensus"]["count"] == 1
-        assert summary["mempool"]["count"] == 1
-
-    run_async(body())
-
-
 def test_scheduler_summary_shape(run_async):
     async def body():
         svc = BatchVerificationService(StubBackend(), inline=True)
@@ -262,7 +234,6 @@ def test_bulk_bucket_steals_to_free_sibling_backend(run_async):
         sibling = StubBackend()
         svc = BatchVerificationService(
             home,
-            use_scheduler=True,
             scheduler_config=sched.SchedulerConfig(bulk_concurrency=1),
             steal_backends=[sibling],
         )
@@ -301,7 +272,7 @@ def test_critical_never_steals_even_with_siblings(run_async):
         home = StubBackend()
         sibling = StubBackend()
         svc = BatchVerificationService(
-            home, use_scheduler=True, steal_backends=[sibling]
+            home, steal_backends=[sibling]
         )
         m, p = _group(3, b"q")
         assert all(await svc.verify_group(m, p, source="consensus", dedup=False))

@@ -100,8 +100,8 @@ def test_packed_dh_kernel_matches_packed():
     staged_h = ed.prepare_batch_packed(msgs, pks, sigs, allow_native=False)
     staged_m = ed.prepare_batch_packed_dh(msgs, pks, sigs)
     np.testing.assert_array_equal(staged_h["s_ok"], staged_m["s_ok"])
-    want = np.asarray(ed._verify_w4p128_jit(jnp.asarray(staged_h["packed"])))
-    got = np.asarray(ed._verify_w4p128dh_jit(jnp.asarray(staged_m["packed"])))
+    want = np.asarray(ed.PROGRAMS["w4p128"](jnp.asarray(staged_h["packed"])))
+    got = np.asarray(ed.PROGRAMS["w4p128dh"](jnp.asarray(staged_m["packed"])))
     np.testing.assert_array_equal(got, want)
     assert want[0] and not want[2] and not want[5] and not want[6]
 
@@ -153,13 +153,10 @@ def test_device_hash_failure_raises_and_nothing_reruns_on_host(monkeypatch):
     v = ed.Ed25519TpuVerifier(kernel="w4", max_bucket=256)
     msgs, pks, sigs = _signed_batch(5, seed=21)
 
-    def boom():
-        def fail(*a, **k):
-            raise RuntimeError("injected lowering failure")
+    def fail(*a, **k):
+        raise RuntimeError("injected lowering failure")
 
-        return fail
-
-    monkeypatch.setattr(v, "_packed_dh_fn", boom)
+    monkeypatch.setitem(v.programs, "w4p128dh", fail)
     with pytest.raises(RuntimeError, match="injected lowering failure"):
         v.verify_batch_mask(msgs, pks, sigs)
     assert "w4p128" not in v.dispatched  # the host-hash twin never ran
@@ -180,3 +177,66 @@ def test_dispatched_names_the_program_per_message_length():
     assert ed.Ed25519TpuVerifier(kernel="pallas").program_name(False, True) == (
         "pallas_p128dh"
     )
+
+
+_TABLE_CASES = [
+    (kernel, committee, device_hash)
+    for kernel in ("w4", "pallas")
+    for committee in (False, True)
+    for device_hash in (False, True)
+]
+
+
+@pytest.mark.parametrize("kernel,committee,device_hash", _TABLE_CASES)
+def test_program_name_resolves_to_one_callable(kernel, committee, device_hash):
+    """Every name `program_name` can return is a key of the verifier's
+    table, for the one-chip and the mesh verifier alike; the one-chip
+    verifier holds the module's jitted singleton (no second compile of one
+    program in a process), the mesh verifier its own wrapper of the same
+    traceable kernel under the same name."""
+    from hotstuff_tpu.ops import pallas_ladder
+    from hotstuff_tpu.parallel import ShardedEd25519Verifier, default_mesh
+
+    singletons = {**ed.PROGRAMS, **pallas_ladder.PROGRAMS}
+    base = "w4c96" if committee else {"w4": "w4p128", "pallas": "pallas_p128"}[kernel]
+    want = base + ("dh" if device_hash else "")
+    single = ed.Ed25519TpuVerifier(kernel=kernel)
+    sharded = ShardedEd25519Verifier(mesh=default_mesh(4), kernel=kernel)
+    for v in (single, sharded):
+        name = v.program_name(committee, device_hash)
+        assert name == want
+        assert len(v.programs) == 4 and callable(v.programs[name])
+        # one callable per name: no other name of the table aliases it
+        assert [k for k, fn in v.programs.items() if fn is v.programs[name]] == [name]
+    assert single.programs[want] is singletons[want]
+    assert sharded.programs[want] is not singletons[want]
+    assert set(sharded.programs) == set(single.programs)
+
+
+def test_no_jitted_verify_program_outside_the_table():
+    """The two ops modules hold six jitted verify programs, all in their
+    PROGRAMS tables under the names `program_name` returns, plus the one
+    f32-argument reference the tests and __graft_entry__ compare against."""
+    from hotstuff_tpu.ops import pallas_ladder
+
+    jitted = type(jax.jit(lambda x: x))
+
+    def loose(mod):
+        return {
+            name
+            for name, obj in vars(mod).items()
+            if isinstance(obj, jitted) and "verify" in name
+        }
+
+    assert loose(ed) == {"_verify_w4_jit"}
+    assert loose(pallas_ladder) == set()
+    assert set(ed.PROGRAMS) == {"w4p128", "w4p128dh", "w4c96", "w4c96dh"}
+    assert set(pallas_ladder.PROGRAMS) == {"pallas_p128", "pallas_p128dh"}
+    for table, kernels in (
+        (ed.PROGRAMS, ed.KERNELS),
+        (pallas_ladder.PROGRAMS, pallas_ladder.KERNELS),
+    ):
+        assert set(table) == set(kernels)
+        assert all(isinstance(fn, jitted) for fn in table.values())
+    with pytest.raises(ValueError, match="kernel"):
+        ed.Ed25519TpuVerifier(kernel="bits")

@@ -195,7 +195,7 @@ def test_collect_and_verifier_spans_share_one_batch(run_async, no_annotator):
         timeline.TIMELINE.reset()
         hists = ("service.collect_s", "service.backend_s", "service.scatter_s")
         before = [_hist(h).count for h in hists]
-        svc = BatchVerificationService(Backend(), max_delay=0.001)
+        svc = BatchVerificationService(Backend())
         pairs = [(PublicKey(bytes(32)), Signature(bytes(64)))] * 5
         assert await svc.verify_group([b"m"] * 5, pairs, dedup=False, rid=17) == [True] * 5
         ring = timeline.TIMELINE.intervals()
@@ -279,14 +279,14 @@ def test_loopback_sidecar_counts_requests_and_slot_holds(run_async, base_port):
 
     async def body():
         server = asyncio.create_task(
-            serve(("127.0.0.1", base_port), CpuBackend(), max_delay=0.001)
+            serve(("127.0.0.1", base_port), CpuBackend())
         )
         await asyncio.sleep(0.2)
         c0, h0 = read()
         timeline.TIMELINE.reset()
         try:
             service = BatchVerificationService(
-                RemoteBackend(("127.0.0.1", base_port), crossover=1), max_delay=0.001
+                RemoteBackend(("127.0.0.1", base_port), crossover=1)
             )
             core = Core(
                 keys(4)[0][0], mempool_committee(base_port + 1, 4),

@@ -190,7 +190,7 @@ def test_the_cache_answers_a_shared_pool_and_nothing_of_per_node_pools(run_async
 
     async def body():
         backend = _CountingCpu()
-        service = BatchVerificationService(backend, max_delay=0.001)
+        service = BatchVerificationService(backend)
         assert service.dedup is not None
         for k in payloads:
             for pool in pools:

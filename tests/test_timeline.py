@@ -154,27 +154,32 @@ def test_verifier_chunk_loop_records_intervals():
     assert 0.0 <= s["overlap_headroom"] <= 1.0
 
 
-def test_deferred_readback_masks_bit_identical():
+@pytest.mark.parametrize("family", ["generic", "committee"])
+def test_deferred_readback_masks_bit_identical(family):
     """`_defer_readback` (the multi-process mesh mode, parallel/mesh.py):
     per-chunk readbacks return raw device handles and ONE end-of-batch
     `_materialize` call splits the concatenated mask back on bucket
     widths. Masks must match the streamed per-chunk path bit-for-bit —
-    valid AND forged lanes. Single-chip here (multihost needs the
+    valid AND forged lanes — in BOTH families: they share the one chunk
+    loop, so the committee family's two staged arrays (index vector and
+    wire rows) take the fresh-buffer path too, and its mask equals the
+    generic family's. Single-chip here (multihost needs the
     `cryptography` wheel this box lacks); the defer/concat/split
     machinery is what's under test, at the same cache-shared w4/128
-    2-chunk shapes as the wiring test above."""
+    2-chunk shapes as the wiring test above, the second chunk ragged."""
     pytest.importorskip("jax")
     from hotstuff_tpu.crypto import pysigner
     from hotstuff_tpu.ops.ed25519 import Ed25519TpuVerifier
 
+    n = 101  # chunks of 64 and 37 lanes
     pool = []
     for i in range(8):
         pk, seed = pysigner.keypair_from_seed(bytes([i + 1]) * 32)
         m = (b"defer-%d" % i).ljust(32, b"\0")
         pool.append((m, pk, pysigner.sign(seed, m)))
-    msgs = [pool[i % 8][0] for i in range(128)]
-    pks = [pool[i % 8][1] for i in range(128)]
-    sigs = [pool[i % 8][2] for i in range(128)]
+    msgs = [pool[i % 8][0] for i in range(n)]
+    pks = [pool[i % 8][1] for i in range(n)]
+    sigs = [pool[i % 8][2] for i in range(n)]
     sigs[5] = os.urandom(64)  # forged lane in chunk 0
     sigs[100] = os.urandom(64)  # forged lane in chunk 1
 
@@ -183,13 +188,24 @@ def test_deferred_readback_masks_bit_identical():
     vd = Ed25519TpuVerifier(**kw)
     vd._defer_readback = True
     try:
-        want = vn.verify_batch_mask(msgs, pks, sigs)
-        got = vd.verify_batch_mask(msgs, pks, sigs)
+        generic = vn.verify_batch_mask(msgs, pks, sigs)
+        if family == "generic":
+            want = generic
+            got = vd.verify_batch_mask(msgs, pks, sigs)
+        else:
+            for v in (vn, vd):
+                v.set_committee([pk for _, pk, _ in pool])
+            idx = [i % 8 for i in range(n)]
+            want = vn.verify_batch_mask_committee(msgs, idx, sigs)
+            got = vd.verify_batch_mask_committee(msgs, idx, sigs)
+            assert want.tolist() == generic.tolist()
+            assert dict(vd.dispatched) == {"w4c96dh": 2}
     finally:
         vn.close()
         vd.close()
     assert got.tolist() == want.tolist()
     assert bool(want[0]) and not bool(want[5]) and not bool(want[100])
+    assert want.sum() == n - 2
 
 
 @pytest.mark.slow

@@ -72,8 +72,7 @@ def main() -> None:
         help="add serial-vs-pipelined A/B phase rows (ops/pipeline.py): "
         "the same e2e workload through DispatchPipeline depth=1 then "
         "depth=2, each with its own device occupancy / overlap headroom "
-        "/ stall line — the per-leg attribution behind "
-        "bench.py --pipeline-ab",
+        "/ stall line",
     )
     ap.add_argument(
         "--metrics-out",
@@ -116,7 +115,7 @@ def main() -> None:
     # Phase rows must time the SAME kernel the e2e row rides: 32-byte
     # messages auto-select the device-hash variant in verify_batch_mask.
     device_hash = all(len(m) == 32 for m in msgs)
-    fn = verifier._packed_dh_fn() if device_hash else verifier._packed_fn()
+    fn = verifier.programs[verifier.program_name(False, device_hash)]
     stage = (
         ed.prepare_batch_packed_dh if device_hash else ed.prepare_batch_packed
     )

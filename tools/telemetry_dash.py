@@ -2,8 +2,8 @@
 """Live/offline telemetry dashboard: per-node commit rate, lane queueing,
 device occupancy, and SLO burn alerts — one renderer for both sources.
 
-    # live: scrape N running nodes (node run --telemetry-port / bench.py
-    # --telemetry-port expose the framed-JSON endpoint)
+    # live: scrape N running nodes (node run --telemetry-port exposes
+    # the framed-JSON endpoint)
     python tools/telemetry_dash.py --poll 127.0.0.1:9090,127.0.0.1:9091
 
     # offline: the same dashboard out of a chaos report's embedded
