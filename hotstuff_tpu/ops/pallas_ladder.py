@@ -3,16 +3,20 @@
 The jnp ladder (ops.ed25519._verify_kernel_w4) leaves XLA to schedule ~3.5k
 field mults as separate HBM-roundtripping fusions per fori iteration. This
 kernel runs the whole 64-group ladder VMEM-resident: one grid program per
-256-lane batch block holds the accumulator point, both digit arrays and the
-16-entry tables (shared k*B and per-item k*(-A)) on-chip for all 256
-doubling steps — the only HBM traffic is the initial block load and the
-final point store.
+128-lane batch block (`LADDER_BLOCK`) holds the accumulator point, both
+digit arrays and the 16-entry tables (shared k*B and per-item k*(-A))
+on-chip for all 256 doubling steps — the only HBM traffic is the initial
+block load and the final point store. The per-item table is built there
+too, in the kernel's prologue, from the decompressed key: in plain jnp its
+~115 field multiplications cost 33 us each (3.9 ms a 4096-lane program) and
+its four (16, 32, B) tables went out to HBM for the kernel to read back
+(PERF.md §6, PR 32).
 
-All arithmetic is ops.field on (32, BLOCK) f32 limb vectors (exact-integer
+All arithmetic is ops.field on (32, block) f32 limb vectors (exact-integer
 f32, see field.py). Table lookups are unrolled masked sums over the 16
 entries (VPU fma chains — no gathers, which TPUs do poorly). Digit rows are
 selected by an iota-mask reduction instead of dynamic slicing (supported +
-cheap: 64xBLOCK fma per group).
+cheap: 64 x block fma per group).
 
 The two fixed-exponent chains around the ladder (decompress's square root,
 compress's 1/Z: ~265 field multiplications each) run in a second kernel,
@@ -21,8 +25,9 @@ ladder step, and were first left in plain jnp as "~15% of total work";
 the device trace said otherwise: of 39.9 ms a 4096-lane program the ladder
 took 17.4 ms and the chains' sixteen jnp `while` loops 16.5 ms, 33 us an
 iteration against 6.3 us a multiplication in here (PERF.md §6, PR 30, has
-the program by device op before and after). Table construction, SHA-512
-and the canonical reductions stay in plain jnp around the two kernels.
+the program by device op before and after). Unpacking, SHA-512, the few
+single field operations of decompress and compress and the canonical
+reductions stay in plain jnp around the two kernels.
 """
 
 from __future__ import annotations
@@ -39,11 +44,17 @@ from jax.experimental.pallas import tpu as pltpu
 from . import field as f
 from . import ed25519 as ed
 
-# Lanes per grid program (multiple of 128), of both kernels. One chain over
-# 4096 lanes, alone on a v5e with the host's dispatch: 1.9 / 1.9 / 2.2 /
-# 2.7 ms at 128 / 256 / 512 / 1024 lanes a program (a (66, B) product
-# outgrows the 64 vregs past 256), VMEM exhausted at 2048 (PR 30).
+# Lanes per grid program (multiple of 128) of the chain kernel, and what a
+# batch is aligned to. One chain over 4096 lanes, alone on a v5e with the
+# host's dispatch: 1.9 / 1.9 / 2.2 / 2.7 ms at 128 / 256 / 512 / 1024 lanes
+# a program (a (66, B) product outgrows the 64 vregs past 256), VMEM
+# exhausted at 2048 (PR 30).
 BLOCK = 256
+# The ladder's own: it keeps an accumulator point, the looked-up operands
+# and a product live at once, and measured the same way reads 17.2 / 19.3 /
+# 23.6 ms at 128 / 256 / 512 lanes a program, VMEM exhausted at 1024
+# (PR 32, with the table prologue).
+LADDER_BLOCK = 128
 
 
 def _digit_row(digits: jnp.ndarray, row) -> jnp.ndarray:
@@ -61,13 +72,49 @@ def _lookup_shared(table: jnp.ndarray, digit: jnp.ndarray) -> jnp.ndarray:
     return acc
 
 
-def _lookup_item(table: jnp.ndarray, digit: jnp.ndarray) -> jnp.ndarray:
-    """table (16, 32, B) per-item, digit (B,) -> (32, B)."""
-    acc = jnp.zeros(table.shape[1:], jnp.float32)
+def _lookup_item(table_ref, digit: jnp.ndarray) -> jnp.ndarray:
+    """table ref (16, 32, B) per-item, digit (B,) -> (32, B)."""
+    acc = jnp.zeros(table_ref.shape[1:], jnp.float32)
     for e in range(16):
         m = (digit == e).astype(jnp.float32)
-        acc = acc + table[e] * m[None, :]
+        acc = acc + table_ref[e] * m[None, :]
     return acc
+
+
+def _neg_a_table_into(x_neg, a_y, d2, ypx_ref, ymx_ref, z_ref, t2d_ref):
+    """The arithmetic of `ed._build_neg_a_table`, entry by entry into four
+    (16, 32, B) refs: the cached multiples k*(-A), k = 0..15, as
+    (Y+X, Y-X, Z, 2d*T). Every limb equals the jnp build's (exact integers
+    in f32, the same operations on the same operands), so the ladder's
+    bound on table limbs (~590) stands. The jnp build stays what it is, the
+    `w4*` programs' and the tests' reference; this one differs where a
+    Mosaic body must: `d2` is `ed.D2`, (32, 1), as an operand (a Pallas
+    body captures no array constant) and always `mul`'s second factor
+    (Mosaic broadcasts a column over lanes, not one limb of it over a whole
+    tile); the fourteen additions are one rolled loop; entries 0 and 1 take
+    2d*T from what is at hand (0, and the madd operand 2d*x*y). A lane
+    whose key did not decompress carries some canonical x and flows on
+    like any other."""
+    zero, one, _, _ = ed.point_identity(a_y.shape[1])
+    xy = f.mul(x_neg, a_y)
+    na = (f.add(a_y, x_neg), f.sub(a_y, x_neg), f.mul(xy, d2))
+
+    def store(k, p, t2d):
+        ypx_ref[k] = f.add(p[1], p[0])
+        ymx_ref[k] = f.sub(p[1], p[0])
+        z_ref[k] = p[2]
+        t2d_ref[k] = t2d
+
+    store(0, (zero, one, one, zero), zero)
+    first = (x_neg, a_y, one, xy)
+    store(1, first, na[2])
+
+    def entry(k, cur):
+        cur = ed.point_madd(cur, *na)
+        store(k, cur, f.mul(cur[3], d2))
+        return cur
+
+    lax.fori_loop(2, 16, entry, first)
 
 
 def _ladder_kernel(
@@ -76,24 +123,18 @@ def _ladder_kernel(
     bypx_ref,
     bymx_ref,
     bxy2d_ref,
-    ta_ypx_ref,
-    ta_ymx_ref,
-    ta_z_ref,
-    ta_t2d_ref,
+    d2_ref,
+    xneg_ref,
+    ay_ref,
     x_out,
     y_out,
     z_out,
     t_out,
+    *ta_refs,
 ):
     sd = sd_ref[:]
     hd = hd_ref[:]
     b_ypx, b_ymx, b_xy2d = bypx_ref[:], bymx_ref[:], bxy2d_ref[:]
-    ta_ypx, ta_ymx, ta_z, ta_t2d = (
-        ta_ypx_ref[:],
-        ta_ymx_ref[:],
-        ta_z_ref[:],
-        ta_t2d_ref[:],
-    )
 
     def group(g, acc):
         # T-skip schedule: see ed._verify_kernel_w4.body — only the last
@@ -110,16 +151,12 @@ def _ladder_kernel(
             _lookup_shared(b_xy2d, sdg),
         )
         acc = ed.point_add_cached(
-            acc,
-            _lookup_item(ta_ypx, hdg),
-            _lookup_item(ta_ymx, hdg),
-            _lookup_item(ta_z, hdg),
-            _lookup_item(ta_t2d, hdg),
-            with_t=False,
+            acc, *(_lookup_item(ref, hdg) for ref in ta_refs), with_t=False
         )
         return acc
 
     with f.mosaic_safe():
+        _neg_a_table_into(xneg_ref[:], ay_ref[:], d2_ref[:], *ta_refs)
         X, Y, Z, T = lax.fori_loop(
             0, ed.NGROUPS, group, ed.point_identity(sd.shape[1])
         )
@@ -130,27 +167,29 @@ def _ladder_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ladder_pallas(
-    s_digits, h_digits, ta_ypx, ta_ymx, ta_z, ta_t2d, interpret: bool = False
-):
-    """(64,B) digits + per-item tables (16,32,B) -> ladder result Point.
+def ladder_pallas(s_digits, h_digits, x_neg, a_y, interpret: bool = False):
+    """(64,B) digits + the decompressed key's -x and y, (32,B) canonical
+    limbs each -> ladder result Point [s]B + [h](-A). The kernel builds
+    the per-item table of k*(-A) in VMEM scratch before its first group
+    (`_neg_a_table_into`); nothing of it touches HBM.
     `interpret` runs the kernel body in the Pallas interpreter (the only
     way it executes without a TPU; the tests pass it, nothing else does)."""
     batch = s_digits.shape[1]
-    assert batch % BLOCK == 0, f"batch {batch} must be a multiple of {BLOCK}"
-    grid = (batch // BLOCK,)
+    block = LADDER_BLOCK
+    assert batch % block == 0, f"batch {batch} must be a multiple of {block}"
+    grid = (batch // block,)
 
     digit_spec = pl.BlockSpec(
-        (ed.NGROUPS, BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM
+        (ed.NGROUPS, block), lambda i: (0, i), memory_space=pltpu.VMEM
     )
     shared_spec = pl.BlockSpec(
         (16, f.NLIMB), lambda i: (0, 0), memory_space=pltpu.VMEM
     )
-    item_spec = pl.BlockSpec(
-        (16, f.NLIMB, BLOCK), lambda i: (0, 0, i), memory_space=pltpu.VMEM
+    const_spec = pl.BlockSpec(
+        (f.NLIMB, 1), lambda i: (0, 0), memory_space=pltpu.VMEM
     )
-    out_spec = pl.BlockSpec(
-        (f.NLIMB, BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM
+    elem_spec = pl.BlockSpec(
+        (f.NLIMB, block), lambda i: (0, i), memory_space=pltpu.VMEM
     )
     out_shape = jax.ShapeDtypeStruct((f.NLIMB, batch), jnp.float32)
 
@@ -158,11 +197,15 @@ def ladder_pallas(
     x, y, z, t = pl.pallas_call(
         _ladder_kernel,
         grid=grid,
-        in_specs=[digit_spec, digit_spec] + [shared_spec] * 3 + [item_spec] * 4,
-        out_specs=[out_spec] * 4,
+        in_specs=[digit_spec] * 2
+        + [shared_spec] * 3
+        + [const_spec]
+        + [elem_spec] * 2,
+        out_specs=[elem_spec] * 4,
         out_shape=[out_shape] * 4,
+        scratch_shapes=[pltpu.VMEM((16, f.NLIMB, block), jnp.float32)] * 4,
         interpret=interpret,
-    )(s_digits, h_digits, *base, ta_ypx, ta_ymx, ta_z, ta_t2d)
+    )(s_digits, h_digits, *base, ed.D2, x_neg, a_y)
     return x, y, z, t
 
 
@@ -212,10 +255,8 @@ def _verify_kernel_pallas(a_y, a_sign, r_enc, s_digits, h_digits):
     the program's stages in a device trace, whatever the HLO ops are called."""
     with jax.named_scope("decompress"):
         x_a, xneg_a, valid = ed.decompress(a_y, a_sign, pow2523=pow2523_pallas)
-    with jax.named_scope("table"):
-        ta = ed._build_neg_a_table(xneg_a, a_y)
-    with jax.named_scope("ladder"):
-        result = ladder_pallas(s_digits, h_digits, *ta)
+    with jax.named_scope("ladder"):  # the -A table is the kernel's prologue
+        result = ladder_pallas(s_digits, h_digits, xneg_a, a_y)
     with jax.named_scope("compress"):
         enc = ed.compress(result, invert=invert_pallas)
     return valid & jnp.all(enc == r_enc, axis=0)

@@ -66,13 +66,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _ladder_shapes(width: int, digit_sharding, table_sharding=None):
+def _ladder_shapes(width: int, sharding):
+    """`ladder_pallas`'s arguments: both digit arrays, then -x and y of the
+    decompressed key (its -A table is built inside the kernel)."""
     f32 = jnp.float32
-    digits = jax.ShapeDtypeStruct((64, width), f32, sharding=digit_sharding)
-    table = jax.ShapeDtypeStruct(
-        (16, 32, width), f32, sharding=table_sharding or digit_sharding
-    )
-    return (digits, digits, table, table, table, table)
+    digits = jax.ShapeDtypeStruct((64, width), f32, sharding=sharding)
+    element = jax.ShapeDtypeStruct((32, width), f32, sharding=sharding)
+    return (digits, digits, element, element)
 
 
 def _compile(name: str, fn, *shapes):
@@ -100,7 +100,7 @@ def test_ladder_pallas_compiles_for_v5e(one_chip, width):
     from hotstuff_tpu.ops import pallas_ladder
 
     if width == "BLOCK":
-        width = pallas_ladder.BLOCK
+        width = pallas_ladder.LADDER_BLOCK
     compiled = _compile(
         f"ladder_pallas@{width}",
         pallas_ladder.ladder_pallas,
@@ -139,25 +139,22 @@ def test_ladder_pallas_compiles_under_shard_map_on_2x2(topo, mesh_shape, axes):
     from hotstuff_tpu.parallel.mesh import shard_map
 
     mesh = Mesh(np.array(topo.devices[:4]).reshape(mesh_shape), axes)
-    lanes = P(None, axes)  # (64, B): batch axis over every mesh axis
-    tables = P(None, None, axes)
+    lanes = P(None, axes)  # (64 | 32, B): batch axis over every mesh axis
     fn = jax.jit(
         shard_map(
             pallas_ladder.ladder_pallas,
             mesh=mesh,
-            in_specs=(lanes, lanes, tables, tables, tables, tables),
+            in_specs=(lanes,) * 4,
             out_specs=(lanes,) * 4,
         )
     )
-    width = 4 * pallas_ladder.BLOCK  # one grid program per chip
-    shapes = _ladder_shapes(
-        width, NamedSharding(mesh, lanes), NamedSharding(mesh, tables)
-    )
+    width = 4 * pallas_ladder.LADDER_BLOCK  # one grid program per chip
+    shapes = _ladder_shapes(width, NamedSharding(mesh, lanes))
     compiled = _compile(f"ladder_pallas shard_map {mesh_shape}", fn, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
     # each chip holds a quarter of the lanes, not all of them
     per_dev = compiled.memory_analysis().argument_size_in_bytes
-    full = (2 * 64 + 4 * 16 * 32) * width * 4
+    full = (2 * 64 + 2 * 32) * width * 4
     assert per_dev == full // 4
 
 

@@ -18,6 +18,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+
 from hotstuff_tpu.ops import ed25519 as ed
 from hotstuff_tpu.ops import field as f
 from hotstuff_tpu.ops import pallas_ladder
@@ -25,14 +27,35 @@ from hotstuff_tpu.ops import pallas_ladder
 pytest.importorskip("cryptography")
 
 BAD_S, BAD_R, WRONG_KEY, WRONG_MSG = 3, 17, 101, 200
+NONCANONICAL_R, NO_SQRT_KEY, ZERO_X_KEY = 60, 150, 151
+TABLE_PARTS = ("ypx", "ymx", "z", "t2d")
+
+
+def _neg_a_table_pallas(x_neg, a_y):
+    """`_neg_a_table_into` alone in an interpreted `pallas_call`: the four
+    tables as outputs, where the ladder kernel keeps them in VMEM scratch."""
+
+    def kernel(d2_ref, x_ref, y_ref, *table_refs):
+        with f.mosaic_safe():
+            pallas_ladder._neg_a_table_into(
+                x_ref[:], y_ref[:], d2_ref[:], *table_refs
+            )
+
+    table = jax.ShapeDtypeStruct((16,) + a_y.shape, jnp.float32)
+    return pl.pallas_call(kernel, out_shape=[table] * 4, interpret=True)(
+        ed.D2, x_neg, a_y
+    )
 
 
 @pytest.fixture(scope="module")
 def block():
-    """One BLOCK of signed lanes, four of them corrupted, pushed through
-    decompress -> table build -> `ladder_pallas(interpret=True)` ->
-    compress; plus the w4 kernel's mask on the same staged inputs."""
+    """One BLOCK of signed lanes, six of them corrupted (one key has no
+    square root, one is the identity: x = 0), pushed through decompress ->
+    `ladder_pallas(interpret=True)`, table prologue and all -> compress;
+    plus the w4 kernel's mask on the same staged inputs, and the -A table
+    of the same keys from the kernel's prologue and from the jnp build."""
     from __graft_entry__ import _signed_batch
+    from chip_smoke import _off_curve_key
 
     n = pallas_ladder.BLOCK
     msgs, pks, sigs = _signed_batch(n, seed=22)
@@ -42,6 +65,8 @@ def block():
     sigs[BAD_S] = bytes(s_bad)
     sigs[BAD_R] = sigs[BAD_R + 1][:32] + sigs[BAD_R][32:]
     pks[WRONG_KEY] = pks[WRONG_KEY + 1]
+    pks[NO_SQRT_KEY] = _off_curve_key()
+    pks[ZERO_X_KEY] = (1).to_bytes(32, "little")  # y = 1: the identity
     msgs[WRONG_MSG] = rng.randbytes(32)
     staged = ed.prepare_batch(msgs, pks, sigs)
     a_y, a_sign, r_enc, s_digits, h_digits = ed.kernel_args(staged, n, "w4")
@@ -49,14 +74,16 @@ def block():
     @jax.jit
     def via_pallas(a_y, a_sign, r_enc, s_digits, h_digits):
         _x, xneg, valid = ed.decompress(a_y, a_sign)
-        table = ed._build_neg_a_table(xneg, a_y)
         point = pallas_ladder.ladder_pallas(
-            s_digits, h_digits, *table, interpret=True
+            s_digits, h_digits, xneg, a_y, interpret=True
         )
         enc = ed.compress(point)
-        return enc, valid & (enc == r_enc).all(axis=0)
+        tables = _neg_a_table_pallas(xneg, a_y), ed._build_neg_a_table(xneg, a_y)
+        return enc, valid & (enc == r_enc).all(axis=0), valid, xneg, tables
 
-    enc, mask = via_pallas(a_y, a_sign, r_enc, s_digits, h_digits)
+    enc, mask, valid, xneg, (table, jnp_table) = via_pallas(
+        a_y, a_sign, r_enc, s_digits, h_digits
+    )
     w4_mask = ed._verify_w4_jit(a_y, a_sign, r_enc, s_digits, h_digits)
     return {
         "msgs": msgs,
@@ -65,14 +92,46 @@ def block():
         "enc": np.asarray(enc),
         "mask": np.asarray(mask) & staged["s_ok"],
         "w4_mask": np.asarray(w4_mask) & staged["s_ok"],
+        "valid": np.asarray(valid),
+        "xneg": np.asarray(xneg),
+        "table": dict(zip(TABLE_PARTS, map(np.asarray, table))),
+        "jnp_table": dict(zip(TABLE_PARTS, map(np.asarray, jnp_table))),
     }
 
 
 def test_interpreted_pallas_ladder_masks_match_w4(block):
     want = np.ones(pallas_ladder.BLOCK, bool)
-    want[[BAD_S, BAD_R, WRONG_KEY, WRONG_MSG]] = False
+    want[[BAD_S, BAD_R, WRONG_KEY, NO_SQRT_KEY, ZERO_X_KEY, WRONG_MSG]] = False
     assert block["mask"].tolist() == want.tolist()
     assert block["mask"].tolist() == block["w4_mask"].tolist()
+
+
+@pytest.mark.parametrize("part", TABLE_PARTS)
+def test_kernel_prologue_table_equals_jnp_build_limb_for_limb(block, part):
+    """All 16 cached multiples of -A in every lane, not only mod p: the
+    values are exact integers in f32 and the prologue does the jnp build's
+    operations on its operands, so the ladder's bound on a table limb
+    (a lazy sum of two normalized elements) holds for it unchanged."""
+    got, want = block["table"][part], block["jnp_table"][part]
+    assert got.shape == want.shape == (16, f.NLIMB, pallas_ladder.BLOCK)
+    assert np.array_equal(got, want)
+    assert got.min() >= 0.0 and got.max() <= 590.0
+
+
+def test_kernel_prologue_table_of_keys_that_are_no_curve_point_or_have_x_zero(block):
+    """A key without a square root is not valid and its lane's table is
+    still the jnp build's; the identity key (x = 0, valid) gives sixteen
+    projective forms of the identity: Y+X = Y-X = Z != 0, 2dT = 0 mod p."""
+    assert not block["valid"][NO_SQRT_KEY] and block["valid"][ZERO_X_KEY]
+    assert not block["xneg"][:, ZERO_X_KEY].any()
+    entries = {}
+    for part in TABLE_PARTS:
+        got, want = block["table"][part], block["jnp_table"][part]
+        assert np.array_equal(got[:, :, NO_SQRT_KEY], want[:, :, NO_SQRT_KEY])
+        lane = jnp.asarray(got[:, :, ZERO_X_KEY].T)  # (32 limbs, 16 entries)
+        entries[part] = f.int_of_limbs(np.asarray(f.canonical(lane)))
+    assert entries["ypx"] == entries["ymx"] == entries["z"]
+    assert all(entries["z"]) and not any(entries["t2d"])
 
 
 @pytest.mark.parametrize("lane", [0, BAD_S, WRONG_MSG])
@@ -160,14 +219,12 @@ def test_sqr_n_squares_truly_only_inside_a_pallas_body():
     assert inside == want != chain()
 
 
-NONCANONICAL_R, NO_SQRT_KEY = 60, 150
-
-
 def test_whole_pallas_program_with_interpreted_chains_matches_w4(monkeypatch):
     """`_verify_kernel_pallas` as the chip runs it (decompress with the
-    Pallas square root, table, Pallas ladder, compress with the Pallas
-    inversion), its three kernels interpreted: every lane's verdict equals
-    the jnp w4 program's, whose chains are the jnp `while` loops."""
+    Pallas square root, Pallas ladder with its table prologue, compress with
+    the Pallas inversion), its three kernels interpreted: every lane's
+    verdict equals the jnp w4 program's, whose chains are the jnp `while`
+    loops and whose table is the jnp build."""
     from __graft_entry__ import _signed_batch
     from chip_smoke import _off_curve_key
 
