@@ -63,6 +63,11 @@ log = logging.getLogger("hotstuff.crypto")
 _M_REMOTE_BATCHES = metrics.counter("crypto.remote_batches")
 _M_REMOTE_SIGS = metrics.counter("crypto.remote_sigs")
 _M_REMOTE_FALLBACKS = metrics.counter("crypto.remote_fallback_batches")
+# What stayed on the node's own CPU because it was under the crossover: every
+# caller's small batch (a payload's one signature, a QC's votes, a coalesced
+# workload group of under 64), never a fallback.
+_M_REMOTE_CPU_BATCHES = metrics.counter("crypto.remote_cpu_batches")
+_M_REMOTE_CPU_SIGS = metrics.counter("crypto.remote_cpu_sigs")
 # One successful round trip of a node's request: `sendall` to mask received.
 _M_REMOTE_RTT = metrics.histogram("crypto.remote_rtt_s")
 # The sidecar's side of the same request. parse and reply are synchronous
@@ -247,6 +252,8 @@ class RemoteBackend(CryptoBackend):
         if n < self.crossover:
             self.stats["cpu_batches"] += 1
             self.stats["cpu_sigs"] += n
+            _M_REMOTE_CPU_BATCHES.inc()
+            _M_REMOTE_CPU_SIGS.inc(n)
             return self._cpu.verify_batch_mask(messages, keys, signatures)
         payload = _encode_request(messages, keys, signatures)
         urgent = n < self.URGENT_BELOW

@@ -53,6 +53,8 @@ _M_PAYLOAD_BYTES = metrics.counter("mempool.payload_bytes")
 _M_REQUESTS_SERVED = metrics.counter("mempool.payload_requests_served")
 _M_GOSSIP_DROPPED = metrics.counter("mempool.gossip_dropped")
 _M_SYNTHETIC_SKIPPED = metrics.counter("mempool.synthetic_skipped")
+# the same in batches: `_verify_sem` counts batches, whatever their size
+_M_SYNTHETIC_SKIPPED_BATCHES = metrics.counter("mempool.synthetic_skipped_batches")
 _M_REQUESTS_CLAMPED = metrics.counter("mempool.requests_clamped")
 _M_VERIFY_BATCH = metrics.histogram(
     "mempool.verify_batch_size", metrics.SIZE_BUCKETS
@@ -223,6 +225,7 @@ class Core:
             before = self._synthetic_skipped
             self._synthetic_skipped += n
             _M_SYNTHETIC_SKIPPED.inc(n)
+            _M_SYNTHETIC_SKIPPED_BATCHES.inc()
             if before == 0 or before // 25_000 != self._synthetic_skipped // 25_000:
                 log.warning(
                     "verification pipeline saturated: %s synthetic workload "

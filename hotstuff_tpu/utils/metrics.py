@@ -583,6 +583,8 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("crypto.remote_batches", "counter", None),
     ("crypto.remote_sigs", "counter", None),
     ("crypto.remote_fallback_batches", "counter", None),
+    ("crypto.remote_cpu_batches", "counter", None),
+    ("crypto.remote_cpu_sigs", "counter", None),
     ("crypto.remote_rtt_s", "histogram", None),
     # crypto/remote.py — the sidecar's side of a request: counts at parse,
     # the two synchronous event-loop sections, the whole request
@@ -702,6 +704,7 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("mempool.payload_requests_served", "counter", None),
     ("mempool.gossip_dropped", "counter", None),
     ("mempool.synthetic_skipped", "counter", None),
+    ("mempool.synthetic_skipped_batches", "counter", None),
     ("mempool.requests_clamped", "counter", None),
     ("mempool.front_dropped", "counter", None),
     ("mempool.ingress_lane_txs", "counter", None),

@@ -475,6 +475,108 @@ def test_pool_build_s_is_the_slowest_nodes_one_sample():
     assert read(src) is None
 
 
+# -- PR 33: how large the verify plane's batches and requests were ------------
+
+SMALL_BATCH_METRICS = (
+    "node.batch_sigs", "node.request_sigs", "node.cpu_verified_share",
+    "sidecar.sigs_per_request",
+)
+
+
+def _small_batch_src():
+    """`_src()` with two nodes' and the sidecar's counts as `fab local`
+    payloads make them: in the window node 0 admitted 1,000 batches of 29
+    and node 1 500 of 20; they sent 100 + 60 requests of 15,000 + 5,000
+    signatures and kept 14,000 + 6,000 on their CPUs; the sidecar parsed
+    180 requests (the probe's 20 among them) of 26,400."""
+    src = _src()
+    adds = [
+        ({"crypto.remote_sigs": 50_000, "crypto.remote_batches": 900,
+          "crypto.remote_cpu_sigs": 7_000},
+         {"crypto.remote_sigs": 65_000, "crypto.remote_batches": 1_000,
+          "crypto.remote_cpu_sigs": 21_000},
+         ((976_000.0, 1_000), (1_005_000.0, 2_000))),
+        ({"crypto.remote_sigs": 1_000, "crypto.remote_batches": 10,
+          "crypto.remote_cpu_sigs": 0},
+         {"crypto.remote_sigs": 6_000, "crypto.remote_batches": 70,
+          "crypto.remote_cpu_sigs": 6_000},
+         ((0.0, 0), (10_000.0, 500))),
+    ]
+    for node, (c_first, c_last, (h_first, h_last)) in zip(src["nodes"], adds):
+        (_t, first), (_t, last) = node["snapshots"][:2]
+        first["counters"].update(c_first)
+        last["counters"].update(c_last)
+        first["histograms"]["mempool.verify_batch_size"] = dict(zip(("sum", "count"), h_first))
+        last["histograms"]["mempool.verify_batch_size"] = dict(zip(("sum", "count"), h_last))
+    (_t, first), (_t, last) = src["sidecar"]["snapshots"][:2]
+    first["counters"]["sidecar.requests"] = 300
+    last["counters"]["sidecar.requests"] = 480
+    last["counters"]["sidecar.request_sigs"] = first["counters"]["sidecar.request_sigs"] + 26_400
+    return src
+
+
+# by hand: (29,000 + 10,000) / 1,500; (15,000 + 5,000) / 160;
+# 20,000 / (20,000 + 20,000); 26,400 / 180
+SMALL_BATCH_EXPECTED = dict(zip(SMALL_BATCH_METRICS, (26.0, 125.0, 50.0, 146.0 + 2.0 / 3.0)))
+# the names each reader divides by: absent (a program older than them, or a
+# window in which they stood still), there is nothing to read
+DIVIDES_BY = {
+    "node.batch_sigs": ("histograms", "mempool.verify_batch_size"),
+    "node.request_sigs": ("counters", "crypto.remote_batches"),
+    "node.cpu_verified_share": ("counters", "crypto.remote_sigs", "crypto.remote_cpu_sigs"),
+    "sidecar.sigs_per_request": ("counters", "sidecar.requests"),
+}
+
+
+@pytest.mark.parametrize("name", SMALL_BATCH_METRICS)
+def test_small_batch_reader_divides_the_windows_counts(name):
+    from chipbench import run
+
+    read = run.load_reader("per_layer", name)
+    assert read(_small_batch_src()) == pytest.approx(SMALL_BATCH_EXPECTED[name])
+    # the counter absent from every snapshot: None, never a made-up number
+    bare = _small_batch_src()
+    kind, *names = DIVIDES_BY[name]
+    for log in [bare["sidecar"], *bare["nodes"]]:
+        for _t, snap in log["snapshots"]:
+            for n in names:
+                snap[kind].pop(n, None)
+    assert read(bare) is None
+    # no snapshots around the window: nothing to read
+    empty = _small_batch_src()
+    for log in [empty["sidecar"], *empty["nodes"]]:
+        log["snapshots"] = []
+    assert read(empty) is None
+
+
+def test_cpu_share_of_a_program_older_than_its_counter_is_zero():
+    """The parent commit counts what went over the wire and not what stayed:
+    its share reads 0 (as `sidecar.columnar_share` reads such a program), so
+    that its traced run still prints a line (`run.py` prints none where a
+    metric the cell is due has no reading)."""
+    from chipbench import run
+
+    read = run.load_reader("per_layer", "node.cpu_verified_share")
+    src = _small_batch_src()
+    for node in src["nodes"]:
+        for _t, snap in node["snapshots"]:
+            snap["counters"].pop("crypto.remote_cpu_sigs", None)
+    assert read(src) == 0.0
+
+
+def test_every_cell_reports_the_small_batch_metrics():
+    from chipbench import run
+
+    bench = run.load_benchmark()
+    for cell in bench["workloads"]:
+        names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
+        assert set(SMALL_BATCH_METRICS) <= set(names), cell["name"]
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(SMALL_BATCH_METRICS[0])
+    assert tuple(names[first:first + 4]) == SMALL_BATCH_METRICS
+    assert all("workloads" not in m for m in bench["per_layer"][first:first + 4])
+
+
 def test_every_new_name_is_in_the_namespace():
     declared = {name for name, _kind, _b in metrics._DEFAULT_NAMESPACE}
     assert {
@@ -483,6 +585,8 @@ def test_every_new_name_is_in_the_namespace():
         "crypto.remote_rtt_s", "mempool.verify_rtt_s", "runtime.loop_cpu_s",
         "sidecar.columnar_sigs", "service.scatter_s",
         "mempool.pool_build_s", "mempool.pool_triples",
+        "crypto.remote_cpu_sigs", "crypto.remote_cpu_batches",
+        "mempool.synthetic_skipped_batches",
     } <= declared
     # a phase's histogram is its profiler name plus `_s`
     for name in timeline.PHASES.values():
