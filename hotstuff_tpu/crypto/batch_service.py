@@ -247,10 +247,14 @@ class BatchVerificationService:
         )
         # Flushes dispatch CONCURRENTLY (bounded): an urgent 3-signature QC
         # check must not wait out a multi-thousand-signature workload batch
-        # already in flight on the device (backends route small batches to
-        # the CPU fast path, so the urgent flush completes in microseconds
-        # while the big dispatch is still on the wire; urgent dispatches
-        # never acquire this semaphore). With steal backends configured
+        # already in flight on the device, so urgent dispatches never
+        # acquire this semaphore. In a node the backend routes such a batch
+        # to its CPU fast path and the urgent flush completes in
+        # microseconds; in the sidecar every urgent flush is a device
+        # program of its own, and what bounds those is the scheduler's
+        # critical window (crypto/scheduler.py: `bulk_concurrency` in
+        # flight on a gridded backend, later arrivals share the next
+        # one), never this semaphore. With steal backends configured
         # the bound must cover every backend window the scheduler can
         # legitimately fill (bulk_concurrency per backend) — otherwise
         # the service-global semaphore silently caps stealing below the

@@ -356,7 +356,11 @@ async def _handle_connection(reader, writer, service, urgent_below: int):
             _M_REQUEST_SIGS.inc(n)
             del body  # the list parse copied it; rows keep it alive themselves
             # Small requests are consensus-critical (QC/TC checks above the
-            # client's crossover but still latency-bound): flush immediately.
+            # client's crossover but still latency-bound): they ride the
+            # service's preemptive lane, which flushes at once while fewer
+            # than two critical device programs are in flight and else
+            # with the next one, together with whatever else waits there
+            # (crypto/scheduler.py, the critical lane's dispatch window).
             if isinstance(parsed, np.ndarray):
                 _M_COLUMNAR_SIGS.inc(n)
                 mask = await service.verify_rows(

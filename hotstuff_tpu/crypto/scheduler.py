@@ -15,11 +15,27 @@ class and latency SLO, feed one admission → bucket → dispatch loop:
   * **Preemptive critical lane.** Consensus-critical groups never wait out
     a lower-class flush timer: any pending critical work is drained and
     dispatched FIRST on every loop pass, bypassing the bulk dispatch bound
-    entirely (small quorum batches ride the backend's CPU fast path, so
-    unbounded critical dispatches are bounded in practice by the consensus
-    message rate). A critical arrival also CLOSES the forming bulk bucket
-    early — the formed groups ship right behind it instead of restarting
-    their deadline, so preemption never re-delays bulk.
+    and the pace model entirely. A critical arrival also CLOSES the
+    forming bulk bucket early — the formed groups ship right behind it
+    instead of restarting their deadline, so preemption never re-delays
+    bulk.
+  * **The critical lane's dispatch window.** On a gridless backend (the
+    nodes' RemoteBackend, CpuBackend, the chaos services) a critical
+    dispatch is a sub-millisecond host call and any number may be in
+    flight. On a backend with a device grid (`alignment_fn() > 0`: the
+    sidecar's TpuBackend) a dispatch is a whole device program that costs
+    the same full or empty, and the device runs them one after another: a
+    third program in flight only queues behind the other two. So there
+    the lane keeps `bulk_concurrency` critical dispatches in flight (the
+    DispatchPipeline window: one program on the device, one staged behind
+    it) on an account of its OWN, which bulk work never touches. Groups
+    that arrive while it is full stay in the lane and ride the next
+    dispatch TOGETHER, started the moment the older in-flight one ends:
+    no timer, nothing held that could have run, and the wait is bounded
+    by one dispatch. An in-process TpuBackend node has a grid too: its
+    quorum-sized checks take the backend's CPU fast path in well under a
+    millisecond, and a third simultaneous critical group there waits for
+    the first of two to end and joins the next.
   * **Alignment-grid bucket sizing.** Bulk buckets are sized dynamically
     against the backend's bucket alignment (`TpuBackend.bucket_alignment`:
     `lane × ndev` on a mesh — parallel/mesh.py's `mesh_alignment` — or the
@@ -171,6 +187,11 @@ _M_STEALS = metrics.counter("pipeline.steals")
 _M_DISPATCHED = metrics.counter("scheduler.dispatched_groups")
 _M_BUCKETS = metrics.counter("scheduler.buckets")
 _M_CRITICAL = metrics.counter("scheduler.critical_dispatches")
+# Groups shipped on critical dispatches, and those of them that found the
+# critical lane's dispatch window full and waited for a slot (gridded
+# backends only): groups over dispatches is how far the window coalesces.
+_M_CRITICAL_GROUPS = metrics.counter("scheduler.critical_groups")
+_M_CRITICAL_HELD = metrics.counter("scheduler.critical_held")
 _M_SIZE_FLUSHES = metrics.counter("scheduler.size_flushes")
 _M_GRID_FLUSHES = metrics.counter("scheduler.grid_flushes")
 _M_DEADLINE_FLUSHES = metrics.counter("scheduler.deadline_flushes")
@@ -276,7 +297,10 @@ class SchedulerConfig:
 
     `bulk_concurrency` bounds in-flight NON-critical buckets (2 = double
     buffering: stage the next bucket while one is on the device; more
-    slots only add host-thread contention against the critical lane).
+    slots only add host-thread contention against the critical lane) and,
+    on a backend with a device grid, in-flight critical dispatches on
+    their own account (the same window: a program costs the same there
+    whichever lane filled it).
     `pace_s_per_sig` is the virtual device-occupancy model for chaos runs
     (0 = backend-bound, production)."""
 
@@ -299,7 +323,8 @@ class DeviceScheduler:
 
     `dispatch(groups, total, critical)` is the owning service's executor
     hook (BatchVerificationService._spawn_dispatch): it must return the
-    spawned task, whose completion frees a bulk slot. Groups only need
+    spawned task, whose completion frees a bulk slot (or, on a gridded
+    backend, a slot of the critical lane's window). Groups only need
     `.source`, `.t_submit`, `.t_dequeue` and `__len__` — the scheduler
     never looks at messages or futures, which is what keeps the lint's
     drain-order simulation (and unit tests) dependency-free."""
@@ -337,6 +362,9 @@ class DeviceScheduler:
         # inline/virtual-time determinism contract, §5.5i).
         self.n_backends = max(1, n_backends)
         self._inflight = [0] * self.n_backends
+        # The critical lane's own window (module docstring): its
+        # dispatches in flight, counted on gridded backends only.
+        self._inflight_critical = 0
         self._wake: asyncio.Event | None = None  # bound lazily to the loop
         self.stats = {
             "submitted": 0,
@@ -365,10 +393,16 @@ class DeviceScheduler:
         """Admit one group into its lane (synchronous — lanes are
         unbounded; backpressure stays with the callers, e.g.
         ingress admission and the mempool's verify semaphores)."""
-        self.lanes[group.source].queue.append(group)
-        self.lanes[group.source].enqueued += 1
+        lane = self.lanes[group.source]
+        lane.queue.append(group)
+        lane.enqueued += 1
         self.stats["submitted"] += 1
         _M_SUBMITTED.inc()
+        if lane.cls.preemptive and self._critical_window_full():
+            # It waits for a slot. (A critical group that finds one free
+            # ships on the loop's next pass: the window fills only by a
+            # dispatch, and a dispatch takes everything the lane holds.)
+            _M_CRITICAL_HELD.inc()
         _M_DEPTH.set(self.depth())
         if self._wake is not None:
             self._wake.set()
@@ -471,18 +505,43 @@ class DeviceScheduler:
         if self._wake is not None:
             self._wake.set()
 
+    def note_critical_done(self, _task=None) -> None:
+        """Done-callback for a gridded backend's critical dispatch tasks
+        (however they ended): frees the slot of the critical lane's window
+        and wakes the loop, which ships everything the lane holds."""
+        self._inflight_critical -= 1
+        if self._wake is not None:
+            self._wake.set()
+
+    def _critical_window_full(self) -> bool:
+        """True while a backend with a device grid has `bulk_concurrency`
+        critical dispatches in flight (the account stays 0 without a grid)."""
+        return (
+            self._inflight_critical >= self.config.bulk_concurrency
+            and self._alignment_fn() > 0
+        )
+
     def _ship_critical(self, now: float) -> bool:
+        # Bypasses the bulk bound AND the pace model: critical work is
+        # never delayed by a lower-class flush timer or a busy bulk
+        # pipeline. Where a dispatch is a device program (a backend with a
+        # grid) the lane has a window of its own: with it full the groups
+        # stay in the lane and share the dispatch that starts when the
+        # older one in flight ends (module docstring).
+        if self._critical_window_full():
+            return False
         hot = self.drain_critical(now)
         if not hot:
             return False
         self.stats["critical_dispatches"] += 1
         _M_CRITICAL.inc()
+        _M_CRITICAL_GROUPS.inc(len(hot))
         _M_DISPATCHED.inc(len(hot))
         _M_DEPTH.set(self.depth())
-        # Bypasses the bulk bound AND the pace model: critical work is
-        # never delayed by a lower-class flush timer or a busy bulk
-        # pipeline (small quorum batches ride the backend's CPU fast path).
-        self._dispatch(hot, sum(len(g) for g in hot), True)
+        task = self._dispatch(hot, sum(len(g) for g in hot), True)
+        if self._alignment_fn() > 0:
+            self._inflight_critical += 1
+            task.add_done_callback(self.note_critical_done)
         return True
 
     async def _pace_busy(self, dur: float, loop) -> None:

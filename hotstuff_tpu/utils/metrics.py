@@ -608,6 +608,8 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("scheduler.dispatched_groups", "counter", None),
     ("scheduler.buckets", "counter", None),
     ("scheduler.critical_dispatches", "counter", None),
+    ("scheduler.critical_groups", "counter", None),
+    ("scheduler.critical_held", "counter", None),
     ("scheduler.size_flushes", "counter", None),
     ("scheduler.grid_flushes", "counter", None),
     ("scheduler.deadline_flushes", "counter", None),

@@ -179,3 +179,103 @@ def test_urgent_flush_not_blocked_by_full_dispatch_slots(keys, run_async):
         assert all(all(m) for m in await asyncio.gather(*workers))
 
     run_async(body())
+
+
+# ---------------------------------------------------------------------------
+# The critical lane's dispatch window, through the real service with real
+# verdicts (ISSUE 35; the policy's own cases are in tests/test_scheduler.py).
+
+
+class _GriddedBackend(_RecordingBackend):
+    """OpenSSL verdicts behind a device grid, as the sidecar's TpuBackend
+    presents one: the k-th call parks on its own gate."""
+
+    bucket_alignment = 4096
+
+    def __init__(self):
+        import threading
+
+        super().__init__()
+        self.gates = [threading.Event() for _ in range(4)]
+        self._lock = threading.Lock()
+
+    def verify_batch_mask(self, messages, keys, signatures):
+        with self._lock:
+            k = len(self.calls)
+            self.calls.append(len(messages))
+        assert self.gates[k].wait(timeout=20), f"call {k} never released"
+        return CpuBackend.verify_batch_mask(self, messages, keys, signatures)
+
+
+@pytest.mark.parametrize(
+    "kinds", ["rows+list", "list+rows", "rows+rows", "list+list"]
+)
+def test_held_critical_groups_share_one_dispatch_and_keep_their_masks(
+    kinds, run_async
+):
+    """Urgent requests as `fab local` payloads make them (64-255 rows, a
+    fifth of the triples corrupted in the probe's seven ways): the third
+    and fourth wait while two critical programs are in flight, ride ONE
+    dispatch when the first ends, columnar and list groups mixed, and
+    every future gets exactly its own slice of the mask, as OpenSSL judges
+    each triple."""
+    import numpy as np
+
+    from chipbench import reference as ref
+
+    sizes = (70, 130, 200, 255)
+    corpus = [
+        tuple(col[:n] for col in request)
+        for n, request in zip(sizes, ref.probe_corpus(35, 4, 255, 5))
+    ]
+    expected = [
+        [ref.verify_strict(m, k, s) for m, k, s in zip(*request)]
+        for request in corpus
+    ]
+    assert all(0.15 < 1 - sum(e) / len(e) < 0.25 for e in expected)
+
+    def submit(svc, request, kind):
+        msgs, pks, sigs = request
+        if kind == "rows":
+            rows = np.frombuffer(
+                b"".join(m + k + s for m, k, s in zip(msgs, pks, sigs)), np.uint8
+            ).reshape(len(msgs), 128)
+            return asyncio.ensure_future(svc.verify_rows(rows, urgent=True))
+        from hotstuff_tpu.crypto.primitives import PublicKey
+
+        pairs = [(PublicKey(k), Signature(s)) for k, s in zip(pks, sigs)]
+        return asyncio.ensure_future(svc.verify_group(msgs, pairs, urgent=True))
+
+    async def until(cond):
+        for _ in range(2000):
+            if cond():
+                return
+            await asyncio.sleep(0.002)
+        raise AssertionError(f"timed out: calls {backend.calls}")
+
+    async def body():
+        svc = BatchVerificationService(backend)
+        third, fourth = kinds.split("+")
+        futs = [submit(svc, corpus[0], "rows")]
+        await until(lambda: len(backend.calls) == 1)
+        futs.append(submit(svc, corpus[1], "list"))
+        await until(lambda: len(backend.calls) == 2)
+        futs.append(submit(svc, corpus[2], third))
+        futs.append(submit(svc, corpus[3], fourth))
+        await asyncio.sleep(0.05)
+        assert backend.calls == [70, 130]  # both held, nothing on a timer
+        backend.gates[0].set()
+        await until(lambda: len(backend.calls) == 3)
+        assert backend.calls == [70, 130, 455]  # one dispatch for both
+        backend.gates[1].set()
+        backend.gates[2].set()
+        masks = await asyncio.wait_for(asyncio.gather(*futs), 30.0)
+        assert [len(m) for m in masks] == list(sizes)
+        for mask, want, kind in zip(masks, expected, ("rows", "list", third, fourth)):
+            assert isinstance(mask, np.ndarray if kind == "rows" else list)
+            assert [bool(b) for b in mask] == want
+        assert svc.stats["flushes"] == 3 and svc.stats["urgent_flushes"] == 3
+        assert svc.scheduler.stats["critical_dispatches"] == 3
+
+    backend = _GriddedBackend()
+    run_async(body())

@@ -277,6 +277,29 @@ def test_bulk_flood_priority_lane_isolation():
         assert s["buckets"] > 0
 
 
+def test_bulk_flood_priority_replays_as_before_the_critical_window():
+    """The critical lane's dispatch window (ISSUE 35) engages only on a
+    backend with a device grid; the chaos services (inline, pure-python
+    backend, no grid) must schedule decision for decision as they did. The
+    digest is the one commit 2d4447c, the last before the window, gives
+    for the same seed and duration: fault trace, commits, events, commit
+    times, flood counters and every node's scheduler summary (queue-delay
+    percentiles included). A change that means to move any of those pins
+    its own."""
+    import hashlib
+    import json
+
+    report = run_scenario("bulk_flood_priority", seed=11, duration=3.5)
+    assert report["ok"], report
+    sections = ("fault_trace", "commits", "events", "commit_times", "scheduler", "flood")
+    blob = json.dumps(
+        {k: report[k] for k in sections}, sort_keys=True, default=str
+    ).encode()
+    assert hashlib.sha256(blob).hexdigest() == (
+        "4de84eef843d482110fb1f8d533e3847171a10c7b224095086894389a6eaf68a"
+    )
+
+
 @pytest.mark.slow
 def test_bulk_flood_priority_deterministic():
     """Tier-1 diet (ISSUE 16): demoted to slow — generic same-seed

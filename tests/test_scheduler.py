@@ -299,3 +299,176 @@ def test_inline_chaos_mode_forces_stealing_off(run_async):
         assert svc.scheduler.stats["steals"] == 0
 
     run_async(body())
+
+
+# ---------------------------------------------------------------------------
+# The critical lane's dispatch window (ISSUE 35): on a backend with a device
+# grid a critical dispatch is a whole device program, so the lane keeps
+# `bulk_concurrency` of them in flight on an account of its own and groups
+# that find it full ride the next one together. Gridless backends never
+# consult it.
+
+
+class GatedBackend(CryptoBackend):
+    """Backend whose k-th call parks on its own gate, then raises if told
+    to; `hold_below` lets calls of fewer signatures through ungated (the
+    critical group among blocked bulk buckets)."""
+
+    name = "gated"
+
+    def __init__(self, alignment: int = 0, raises=(), hold_below: int = 0):
+        import threading
+
+        self.calls: list[int] = []
+        self.gates = [threading.Event() for _ in range(8)]
+        self._raises = set(raises)
+        self._hold_below = hold_below
+        self._lock = threading.Lock()
+        if alignment:
+            self.bucket_alignment = alignment
+
+    def verify_batch_mask(self, messages, keys, signatures, **_kw):
+        with self._lock:
+            k = len(self.calls)
+            self.calls.append(len(messages))
+        if len(messages) >= self._hold_below:
+            assert self.gates[k].wait(timeout=10), f"call {k} never released"
+        if k in self._raises:
+            raise RuntimeError(f"device refused call {k}")
+        return [True] * len(messages)
+
+
+async def _until(cond, what: str):
+    for _ in range(1000):
+        if cond():
+            return
+        await asyncio.sleep(0.002)
+    raise AssertionError(f"timed out waiting for {what}")
+
+
+def _critical_counts():
+    return (
+        sched._M_CRITICAL.value,
+        sched._M_CRITICAL_GROUPS.value,
+        sched._M_CRITICAL_HELD.value,
+    )
+
+
+def _submit_critical(svc, n: int, tag: bytes):
+    m, p = _group(n, tag)
+    return asyncio.ensure_future(
+        svc.verify_group(m, p, source="consensus", dedup=False)
+    )
+
+
+def test_critical_window_holds_then_ships_the_held_as_one(run_async):
+    """Two critical device programs in flight: the third and fourth groups
+    stay in the lane (no third program queues on the device) and ship as
+    ONE dispatch the moment the first ends — no timer, and only they count
+    as held."""
+
+    async def body():
+        backend = GatedBackend(alignment=64)
+        svc = BatchVerificationService(backend)
+        before = _critical_counts()
+        a = _submit_critical(svc, 70, b"a")
+        await _until(lambda: backend.calls == [70], "the first program")
+        b = _submit_critical(svc, 80, b"b")
+        await _until(lambda: backend.calls == [70, 80], "the second program")
+        c = _submit_critical(svc, 90, b"c")
+        d = _submit_critical(svc, 100, b"d")
+        await asyncio.sleep(0.05)  # long past any flush deadline (4 ms at most)
+        assert backend.calls == [70, 80], backend.calls
+        assert svc.scheduler.depth() == 2 and not c.done() and not d.done()
+        assert svc.scheduler._inflight_critical == 2
+        backend.gates[0].set()  # the older program ends ...
+        await _until(lambda: len(backend.calls) == 3, "the shared program")
+        assert backend.calls == [70, 80, 190]  # ... and both ride the next
+        assert len(await a) == 70 and not b.done()
+        backend.gates[1].set()
+        backend.gates[2].set()
+        assert [len(await f) for f in (b, c, d)] == [80, 90, 100]
+        await _until(lambda: svc.scheduler._inflight_critical == 0, "the slots")
+        after = _critical_counts()
+        assert [x - y for x, y in zip(after, before)] == [3, 4, 2]
+        assert svc.scheduler.stats["critical_dispatches"] == 3
+
+    run_async(body())
+
+
+def test_bulk_dispatches_in_flight_never_hold_a_critical_group(run_async):
+    """The window is the critical lane's own: with both bulk slots of a
+    gridded backend taken, a critical group still ships at once."""
+
+    async def body():
+        backend = GatedBackend(alignment=64, hold_below=64)
+        svc = BatchVerificationService(backend)
+        before = _critical_counts()
+        bulk = []
+        for i in range(2):  # a full grid row each: two grid flushes in flight
+            m, p = _group(64, b"w%d" % i)
+            bulk.append(asyncio.ensure_future(
+                svc.verify_group(m, p, source="mempool", dedup=False)
+            ))
+            await _until(lambda: len(backend.calls) == i + 1, "a bulk program")
+        assert svc.scheduler._inflight == [2]
+        q = _submit_critical(svc, 3, b"q")
+        assert await asyncio.wait_for(q, 5.0) == [True] * 3
+        assert backend.calls == [64, 64, 3] and not any(f.done() for f in bulk)
+        for gate in backend.gates:
+            gate.set()
+        assert all(all(m) for m in await asyncio.gather(*bulk))
+        after = _critical_counts()
+        assert [x - y for x, y in zip(after, before)] == [1, 1, 0]
+
+    run_async(body())
+
+
+def test_gridless_backend_dispatches_every_critical_group_as_before(run_async):
+    """No grid (every node's RemoteBackend, CpuBackend): four critical
+    groups arriving one after another make four dispatches in flight at
+    once, and the window's account is never touched."""
+
+    async def body():
+        backend = GatedBackend()
+        svc = BatchVerificationService(backend)
+        before = _critical_counts()
+        futs = []
+        for i in range(4):
+            futs.append(_submit_critical(svc, 70 + i, b"g%d" % i))
+            await _until(lambda: len(backend.calls) == i + 1, f"dispatch {i}")
+        assert backend.calls == [70, 71, 72, 73]
+        assert svc.scheduler._inflight_critical == 0
+        for gate in backend.gates:
+            gate.set()
+        assert [len(await f) for f in futs] == [70, 71, 72, 73]
+        after = _critical_counts()
+        assert [x - y for x, y in zip(after, before)] == [4, 4, 0]
+
+    run_async(body())
+
+
+def test_critical_dispatch_that_raises_frees_its_slot(run_async):
+    """A device program that raises fails its own groups alone and gives
+    its slot back: the group held behind it ships and verifies."""
+
+    async def body():
+        backend = GatedBackend(alignment=64, raises={0})
+        svc = BatchVerificationService(backend)
+        a = _submit_critical(svc, 70, b"a")
+        await _until(lambda: len(backend.calls) == 1, "the first program")
+        b = _submit_critical(svc, 80, b"b")
+        await _until(lambda: len(backend.calls) == 2, "the second program")
+        c = _submit_critical(svc, 90, b"c")
+        await asyncio.sleep(0.02)
+        assert backend.calls == [70, 80]
+        backend.gates[0].set()
+        with pytest.raises(RuntimeError, match="device refused call 0"):
+            await a
+        await _until(lambda: backend.calls == [70, 80, 90], "the held group")
+        backend.gates[1].set()
+        backend.gates[2].set()
+        assert len(await b) == 80 and len(await c) == 90
+        await _until(lambda: svc.scheduler._inflight_critical == 0, "the slots")
+
+    run_async(body())

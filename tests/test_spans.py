@@ -587,7 +587,81 @@ def test_every_new_name_is_in_the_namespace():
         "mempool.pool_build_s", "mempool.pool_triples",
         "crypto.remote_cpu_sigs", "crypto.remote_cpu_batches",
         "mempool.synthetic_skipped_batches",
+        "scheduler.critical_groups", "scheduler.critical_held",
     } <= declared
     # a phase's histogram is its profiler name plus `_s`
     for name in timeline.PHASES.values():
         assert name + "_s" in declared, name
+
+
+# -- the critical lane's dispatch window (ISSUE 35) ---------------------------
+
+# (counters and the consensus lane's histogram count at the window's first
+# snapshot, the same at its last, the reading)
+CRITICAL_WINDOW_CASES = {
+    # 900 groups on 400 dispatches in the window, since boot 1,000 on 1,000
+    "shared": ({"scheduler.critical_groups": 1_000, "scheduler.critical_dispatches": 1_000},
+               {"scheduler.critical_groups": 1_900, "scheduler.critical_dispatches": 1_400},
+               2.25),
+    # the parent's one program a request
+    "alone": ({"scheduler.critical_groups": 50, "scheduler.critical_dispatches": 50},
+              {"scheduler.critical_groups": 2_050, "scheduler.critical_dispatches": 2_050},
+              1.0),
+    # a program older than the counter: the lane's histogram counted the groups
+    "older_program": ({"scheduler.critical_dispatches": 10, "queue_count": 12},
+                      {"scheduler.critical_dispatches": 30, "queue_count": 72},
+                      3.0),
+    # the counter wins where a program has both
+    "both": ({"scheduler.critical_groups": 0, "scheduler.critical_dispatches": 0, "queue_count": 5},
+             {"scheduler.critical_groups": 30, "scheduler.critical_dispatches": 20, "queue_count": 999},
+             1.5),
+    "no_critical_dispatch_in_the_window": (
+        {"scheduler.critical_groups": 7, "scheduler.critical_dispatches": 7},
+        {"scheduler.critical_groups": 7, "scheduler.critical_dispatches": 7}, None),
+    "dispatches_uncounted": ({"scheduler.critical_groups": 1}, {"scheduler.critical_groups": 9}, None),
+    "groups_uncounted": ({"scheduler.critical_dispatches": 1}, {"scheduler.critical_dispatches": 9}, None),
+    "neither": ({}, {}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CRITICAL_WINDOW_CASES))
+def test_critical_groups_per_dispatch(case):
+    from chipbench import run
+
+    read = run.load_reader("per_layer", "sidecar.critical_groups_per_dispatch")
+    at_first, at_last, expected = CRITICAL_WINDOW_CASES[case]
+    src = _src()
+    (_t, first), (_t, last), (_t, after) = src["sidecar"]["snapshots"]
+    for snap, add in ((first, at_first), (last, at_last), (after, at_last)):
+        add = dict(add)
+        if "queue_count" in add:
+            snap["histograms"]["scheduler.queue_consensus_s"] = {
+                "sum": 0.1, "count": add.pop("queue_count")}
+        snap["counters"].update(add)
+    assert read(src) == (expected if expected is None else pytest.approx(expected))
+    # no snapshots around the window: nothing to read
+    src["sidecar"]["snapshots"] = []
+    assert read(src) is None
+
+
+def test_the_critical_windows_metric_is_due_where_critical_dispatches_are():
+    """No critical dispatch in the window means no reading, and `run.py`
+    prints no line where a due metric has none: the entry lists the cells
+    whose windows hold tens to thousands of critical dispatches, and leaves
+    out the two whose requests are nearly all over 256 signatures (0 to 13
+    a window on the chip, PERF.md section 6, PR 35)."""
+    from chipbench import run
+
+    bench = run.load_benchmark()
+    name = "sidecar.critical_groups_per_dispatch"
+    (entry,) = (m for m in bench["per_layer"] if m["name"] == name)
+    listed = ["fork-n4-fablocal.flood", "fork-n4.steady", "fork-n10.flood",
+              "fork-n10-ownpool.flood"]
+    assert entry == {
+        "name": name, "unit": "groups",
+        "better": "higher", "source": "program_counter", "layer": "sidecar",
+        "moves": "verified_tx_per_s", "workloads": listed,
+    }
+    for cell in bench["workloads"]:
+        names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
+        assert (name in names) == (cell["name"] in listed), cell["name"]
