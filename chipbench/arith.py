@@ -14,6 +14,18 @@ import math
 from . import collect, traffic
 
 
+def live_nodes(config) -> int:
+    """The nodes that boot: a configuration's `faults` f are the last f
+    members of its committee, which never start (upstream's
+    `benchmark/local.py`: `range(nodes - faults)`). The committee, and with
+    it the quorum, stays that of all `nodes`; f may be at most (n - 1) // 3,
+    the most a committee of n survives."""
+    n, f = int(config["nodes"]), int(config.get("faults", 0))
+    if not 0 <= f <= (n - 1) // 3:
+        raise ValueError(f"faults {f} in a committee of {n}: at most {(n - 1) // 3}")
+    return n - f
+
+
 def percentile(values, q: float):
     """Nearest-rank percentile over ALL values given; None for none."""
     if not values:
@@ -124,6 +136,23 @@ def verified_tx_per_s(src):
     if share is None:
         return None
     return committed_tx_in_window(src) / src["window"]["seconds"] * share
+
+
+def outages(src) -> list[tuple[float, float, list[float]]]:
+    """Per live node, every stretch between two consecutive block commits of
+    its log that is longer than `timeout_delay` and overlaps the window:
+    (last commit before, first commit after, the node's `Timeout reached`
+    instants in between). Time without service, as the node's own clock
+    saw it; a stretch the log never closed is not one."""
+    w = src["window"]
+    least = src["config"]["parameters"]["consensus"]["timeout_delay"] / 1000.0
+    out = []
+    for node in src["nodes"]:
+        times = sorted(t for t, _r, _d in node["blocks"])
+        for a, b in zip(times, times[1:]):
+            if b - a > least and a < w["t1"] and b > w["t0"]:
+                out.append((a, b, [t for t, _r in node["timeouts"] if a < t < b]))
+    return out
 
 
 def front_dropped(src) -> list:

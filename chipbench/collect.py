@@ -18,6 +18,7 @@ _PAYLOAD = re.compile(r"^Payload (\S+) contains (\d+) B$")
 _SAMPLE = re.compile(r"^Payload (\S+) contains sample tx (\d+)$")
 _SHED = re.compile(r"^payload maker shedding: (\d+) transactions dropped")
 _VERIFY = re.compile(r"^Verifying (OWN|OTHER) transaction batch\. Size: (\d+)$")
+_TIMEOUT = re.compile(r"^Timeout reached for round (\d+)$")
 
 
 def _stamp(m) -> float:
@@ -30,6 +31,7 @@ def parse_log(path: str) -> dict:
         "blocks": [],  # (t, round, block digest)
         "payload_commits": [],  # (t, round, payload digest)
         "created": [],  # (t, round, block digest)
+        "timeouts": [],  # (t, round): the pacemaker fired (`Timeout reached`)
         "own_payloads": {},  # payload digest -> bytes
         "samples": {},  # sample id -> payload digest
         "verify": [],  # (t, kind, sigs)
@@ -54,6 +56,9 @@ def parse_log(path: str) -> dict:
                 k = _SHED.match(msg)
                 if k:
                     out["maker_shed"] = int(k.group(1))
+                k = _TIMEOUT.match(msg)
+                if k:
+                    out["timeouts"].append((_stamp(m), int(k.group(1))))
             elif level in ("ERROR", "CRITICAL"):
                 out["errors"].append(msg[:200])
                 if "synthetic batch verification failed" in msg:

@@ -2,8 +2,13 @@
 the plain reference (`reference.py`), number by number, each with its limit.
 Every comparison here is exact, so every limit is 0.
 
-  consensus   every node commits in the window; no round that two nodes both
-              committed carries two digests
+Every node here is a LIVE node: `src["nodes"]` holds the n - f that booted
+(`arith.live_nodes`, `collect.gather`), one client each. A member that is
+dead by design has no log and is judged on nothing; a live one that falls
+silent fails the run.
+
+  consensus   every live node commits in the window; no round that two nodes
+              both committed carries two digests
   mempool     every payload a node committed for its own client is in that
               node's store, hashes to its digest by the reference's own
               hashing, and holds only transactions that client sent, byte
@@ -47,9 +52,9 @@ def _mempool(src, work: str, out) -> tuple[list, list, list, int]:
     of the run (those due in the window; all), the transactions its client
     sent, and the number of transactions checked."""
     cfg, tr = src["config"], src["traffic"]
-    size, n = cfg["tx_size"], cfg["nodes"]
+    size, n = cfg["tx_size"], len(src["nodes"])
     seed8 = ref.seed_bytes(src["seed"])
-    rate_c = tr["rate"] / n
+    rate_c = tr["rate"] / n  # the offer is split over the live nodes' clients
     ticks = arith.window_ticks(src)
     sent_ticks = [0] * n  # ticks each client got through
     sent_txs = [0] * n
@@ -175,6 +180,9 @@ def _verify_plane(src, out) -> None:
 
 def judge(src, work: str) -> dict:
     """name -> [number, limit]; adds `attempted`, `failed` to src."""
+    live = arith.live_nodes(src["config"])
+    if len(src["nodes"]) != live:
+        raise ValueError(f"{len(src['nodes'])} node logs for {live} live nodes")
     out: dict = {}
     _consensus(src, out)
     from_window, from_all, sent_txs, checked = _mempool(src, work, out)

@@ -4,7 +4,9 @@ chip_smoke.py `phase_served`, with three things changed: the sidecar and the
 nodes start at the same moment (the nodes' synthetic pools are made while the
 sidecar warms up), every process logs at INFO (the program's argparse
 default; `CommandMaker` adds `-vv` to it and lands on DEBUG), and the
-committee's keys come from the seed. This module never imports JAX.
+committee's keys come from the seed, and a configuration's `faults` f are
+its last f members, which never boot (upstream's `local.py`: the committee
+file names all n, `range(n - f)` start). This module never imports JAX.
 """
 
 from __future__ import annotations
@@ -17,6 +19,7 @@ import subprocess
 import sys
 import time
 
+from . import arith
 from . import reference as ref
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -84,6 +87,10 @@ class Deployment:
         self.work = work
         self.config = config
         self.n = int(config["nodes"])
+        try:
+            self.live = arith.live_nodes(config)  # nodes 0 .. live-1 boot
+        except ValueError as e:
+            raise LaunchError(str(e)) from None
         self.committee = Committee(seed, self.n)
         self.fault = fault
         self.procs: dict[str, subprocess.Popen] = {}
@@ -126,7 +133,7 @@ class Deployment:
             "--committee", ".committee.json",
         ]
         self._spawn("sidecar", cmd)
-        for i in range(self.n):
+        for i in range(self.live):
             module = "hotstuff_tpu.node.main"
             pre: list[str] = []
             if self.fault == "alter_tx" and i == 0:
@@ -142,11 +149,11 @@ class Deployment:
             )
 
     def await_ready(self) -> dict[str, float]:
-        """Wait for every process to say it has booted. Returns the seconds
-        each took, from now."""
+        """Wait for every process started to say it has booted. Returns the
+        seconds each took, from now."""
         t0 = time.time()
         waits = {"sidecar": SIDECAR_BOOT_TIMEOUT}
-        waits.update({f"node-{i}": NODE_BOOT_TIMEOUT for i in range(self.n)})
+        waits.update({f"node-{i}": NODE_BOOT_TIMEOUT for i in range(self.live)})
         took: dict[str, float] = {}
         while waits:
             time.sleep(0.25)
@@ -168,7 +175,7 @@ class Deployment:
 
     def start_client(self, rate: float, tick_ms: float, seed: int, start: float,
                      stop: float, out: str, name: str = "client") -> subprocess.Popen:
-        targets = ",".join(self.committee.front(i) for i in range(self.n))
+        targets = ",".join(self.committee.front(i) for i in range(self.live))
         return self._spawn(
             name,
             [

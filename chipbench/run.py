@@ -151,8 +151,9 @@ def window_run(dep, cfg, tr: Traffic, seed: int, seconds: float, trace: bool,
     pr.join(timeout=60)
     records = collect.read_records(records_path)
     waited = drain.wait_committed(
-        [dep.log(f"node-{i}") for i in range(dep.n)], cfg["tx_size"],
-        drain.sent_by_client(records, dep.n), t1, tr.drain_s, tr.drain_quiet_s, tr.drain_most_s,
+        [dep.log(f"node-{i}") for i in range(dep.live)], cfg["tx_size"],
+        drain.sent_by_client(records, dep.live), t1, tr.drain_s, tr.drain_quiet_s,
+        tr.drain_most_s,
     )
     say(f"chipbench: drain {json.dumps(waited)}")
     end = time.time()
@@ -171,6 +172,13 @@ def window_run(dep, cfg, tr: Traffic, seed: int, seconds: float, trace: bool,
             len(c[0]) for c, a in zip(corpus, pr.answers) if a is not None
         ),
     }
+
+
+def printable(metrics: dict, missing: list, traced: bool) -> bool:
+    """Whether a result line is printed. A per-layer metric whose reader
+    finds nothing to read is left out of the line; an end-to-end metric
+    without a reading, or a line without any metric, prints no line."""
+    return bool(metrics) and (traced or not missing)
 
 
 def build_result(bench, cell, traced: bool, src, device, took, compared):
@@ -214,6 +222,11 @@ def build_result(bench, cell, traced: bool, src, device, took, compared):
         "committed_tx": arith.committed_tx_in_window(src),
         "verified_shares": arith.verified_shares(src),
         "verified_share": arith.verified_share(src),
+        "timeouts_in_window": sum(
+            1 for n in src["nodes"] for t, _r in n["timeouts"]
+            if src["window"]["t0"] <= t < src["window"]["t1"]
+        ),
+        "outages_s": sorted(round(b - a, 3) for a, b, _t in arith.outages(src)),
     }
     result["compared"] = compared  # last, beside the limits
     return result, missing
@@ -246,10 +259,15 @@ def main(argv=None) -> int:
     say(
         f"chipbench: cell {cell['name']} seed {args.seed} seconds {args.seconds} "
         f"trace {args.trace} rate {tr.rate} tx/s nodes {cfg['nodes']} "
-        f"cpus {os.cpu_count()} JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}"
+        f"faults {cfg.get('faults', 0)} cpus {os.cpu_count()} "
+        f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}"
     )
 
-    dep = launch.Deployment(work, cfg, args.seed, fault=args.fault)
+    try:
+        dep = launch.Deployment(work, cfg, args.seed, fault=args.fault)
+    except launch.LaunchError as e:
+        say(f"chipbench: FAILED: {e}")
+        return 1
     code = 1
     try:
         dep.start(trace_seconds=TRACE_SECONDS if args.trace else None)
@@ -279,7 +297,7 @@ def main(argv=None) -> int:
 
         if args.trace:
             reduce_trace(dep, work)
-        src.update(collect.gather(work, cfg["nodes"]))
+        src.update(collect.gather(work, dep.live))
         if args.trace:
             tr = src.get("trace") or {}
             if device["platform"] == "tpu" and not tr.get("on_tpu"):
@@ -293,19 +311,21 @@ def main(argv=None) -> int:
         result, missing = build_result(bench, cell, bool(args.trace), src, device, took, compared)
         for name, (value, limit) in compared.items():
             say(f"compared {name}: {value} (limit {limit})")
-        if missing or not result["metrics"]:
-            say(f"chipbench: no reading for {missing}; no result")
-            code = 3
-        else:
+        if missing:
+            say(f"chipbench: no reading for {missing}")
+        if printable(result["metrics"], missing, bool(args.trace)):
             print(json.dumps(result), flush=True)
             code = 0
+        else:
+            say("chipbench: no result")
+            code = 3
     except launch.LaunchError as e:
         say(f"chipbench: FAILED: {e}")
         code = 1
     finally:
         dep.stop()
         shutil.rmtree(os.path.join(work, "trace"), ignore_errors=True)
-        for i in range(cfg["nodes"]):
+        for i in range(dep.live):
             shutil.rmtree(os.path.join(work, f".db-{i}"), ignore_errors=True)
             tidy_log(dep.log(f"node-{i}"), keep=args.keep_logs or code != 0)
     return code

@@ -14,6 +14,9 @@ rehearsal (`python3 -m chipbench.selfcheck`); exits non-zero on a mismatch.
   7. `attempted` and `failed` where a front port shed and a node lost
   8. the all-nodes verified share beside the worst node's skipped share, and
      a span's window mean where the histogram holds a warm-up from before it
+  9. a crash fault: the outages of the live nodes' logs and the timeouts in
+     them; a per-layer metric with no reading is left out of a line that is
+     still printed, an end-to-end one prints no line
 """
 
 from __future__ import annotations
@@ -263,6 +266,63 @@ def share_case() -> int:
     return bad
 
 
+def fault_case() -> int:
+    """The window [100, 140), `timeout_delay` 5 s. Node 0 commits at 95, 99,
+    110.4, 111, 121.8, 122 and 145; node 1 at 99, 110.5, 122.1 and 150. Its
+    timeouts fire at 104.9 and 109.9 and at 116.5 and 121.5. Outages of node
+    0: 99-110.4 (timeouts 104.9, 109.9), 111-121.8 (116.5, 121.5), 122-145
+    (none: a stall the logs do not explain, counted all the same); node 1:
+    99-110.5, 110.5-122.1 and 122.1-150, with the same timeouts. 95-99 is
+    under 5 s. The six outages sorted: 10.8, 11.4, 11.5, 11.6, 23.0, 27.9 s,
+    and the nearest-rank median is the third, 11.5; recovery, over the four
+    that hold a timeout: 0.5, 0.3, 0.6, 0.6 s -> the second sorted, 0.5."""
+    from .run import load_reader, printable, read_metrics
+
+    def node(commits, timeouts):
+        return {"blocks": [(t, 0, "B") for t in commits],
+                "timeouts": [(t, 0) for t in timeouts], "snapshots": []}
+
+    timeouts = [104.9, 109.9, 116.5, 121.5]
+    src = {
+        "window": {"t0": 100.0, "t1": 140.0, "seconds": 40.0},
+        "config": {"parameters": {"consensus": {"timeout_delay": 5000}}},
+        "nodes": [node([95.0, 99.0, 110.4, 111.0, 121.8, 122.0, 145.0], timeouts),
+                  node([99.0, 110.5, 122.1, 150.0], timeouts)],
+    }
+    bad = check("outages of the live nodes", len(arith.outages(src)), 6)
+    bad += check("service.outage_ms", load_reader("per_layer", "service.outage_ms")(src),
+                 11500.0, 1e-6)
+    bad += check("consensus.recovery_ms",
+                 load_reader("per_layer", "consensus.recovery_ms")(src), 500.0, 1e-6)
+    calm = dict(src, nodes=[node([99.0 + k for k in range(45)], [])])
+    bad += check("no outage: no reading", load_reader("per_layer", "service.outage_ms")(calm),
+                 None)
+    bench = {
+        "end_to_end": [{"name": "commit_p95_ms", "workloads": ["c"]}],
+        "per_layer": [
+            {"name": name, "unit": "ms", "moves": "commit_p95_ms", "workloads": ["c"]}
+            for name in ("service.outage_ms", "consensus.recovery_ms")
+        ],
+    }
+    got = read_metrics(bench, "c", "per_layer", calm)
+    missing = [m["name"] for m in bench["per_layer"] if m["name"] not in got]
+    half = read_metrics(bench, "c", "per_layer", dict(
+        src, nodes=[node([99.0, 110.4, 150.0], [])]))
+    bad += check("a traced line without the metric that reads nothing is printed",
+                 (sorted(half), printable(half, ["consensus.recovery_ms"], True)),
+                 (["service.outage_ms"], True))
+    bad += check("no per-layer reading at all: no line", printable(got, missing, True), False)
+    bad += check("an end-to-end metric without a reading: no line",
+                 printable({"setup_s": {}}, ["commit_p95_ms"], False), False)
+    try:
+        arith.live_nodes({"nodes": 10, "faults": 4})
+        bad += check("faults over (n - 1) // 3 refused", False, True)
+    except ValueError:
+        bad += check("faults over (n - 1) // 3 refused", True, True)
+    bad += check("live nodes of ten, one dead", arith.live_nodes({"nodes": 10, "faults": 1}), 9)
+    return bad
+
+
 def record(out_dir: str) -> int:
     """Record the small trace kept beside this file: five runs of one small
     jitted program on whatever device JAX has (meant for the chip), traced,
@@ -308,7 +368,7 @@ def main() -> int:
     if len(sys.argv) > 2 and sys.argv[1] == "--record":
         return record(sys.argv[2])
     bad = (latency_case() + schedule_case() + trace_case() + roofline_case()
-           + device_count_case() + drain_case() + shed_case() + share_case())
+           + device_count_case() + drain_case() + shed_case() + share_case() + fault_case())
     print("selfcheck:", "all ok" if not bad else f"{bad} FAILED")
     return 1 if bad else 0
 

@@ -76,3 +76,14 @@ def loop_cpu_share(src, where: str):
         100.0 * _counter_delta(first, last, "runtime.loop_cpu_s") / seconds
         for first, last, seconds in pairs
     )
+
+
+def counter_rate(src, where: str, name: str):
+    """Advances of counter `name` a second in the window, pooled over
+    `where`: the sum of every process's advance over the sum of the seconds
+    its snapshots span (of the nodes, one node's mean rate)."""
+    pairs = _brackets(src, where)
+    if pairs is None:
+        return None
+    advance = sum(_counter_delta(first, last, name) for first, last, _s in pairs)
+    return advance / sum(seconds for _f, _l, seconds in pairs)

@@ -37,6 +37,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", default="0")
     ap.add_argument("--sets", type=int, default=1)
     ap.add_argument("--fault")
+    ap.add_argument("--keep-logs", action="store_true",
+                    help="keep each run's nodes' logs, in a directory of its own")
     args = ap.parse_args(argv)
     out_path = os.path.join(launch.ROOT, "chiprun_out", "chipbench", "results.jsonl")
     os.makedirs(os.path.dirname(out_path), exist_ok=True)
@@ -47,6 +49,9 @@ def main(argv=None) -> int:
                    "--seed", seed, "--seconds", args.seconds, "--trace", args.trace]
             if args.fault:
                 cmd += ["--fault", args.fault]
+            if args.keep_logs:
+                cmd += ["--keep-logs", "--out", os.path.join(
+                    launch.ROOT, "chiprun_out", "chipbench", f"{args.workload}-{seed}-{s}")]
             t = time.time()
             p = subprocess.run(cmd, cwd=launch.ROOT, capture_output=True, text=True)
             wall = time.time() - t

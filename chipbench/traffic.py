@@ -13,7 +13,8 @@ A traffic mix is a JSON file of parameters under `chipbench/traffic/`:
                                 the key it is attempted and failed
      "probe": {"every_s": 2, "sigs": 320, "bad_every": 5}}
 
-The offer is split evenly over one client per node. Every client sends, at
+The offer is split evenly over one client per LIVE node (a configuration's
+`faults` never boot: `arith.live_nodes`). Every client sends, at
 tick k (due instant start + k * tick), the transactions that bring its total
 to floor(rate_c * k * tick): the same schedule for every seed. The seed
 changes the transactions' bytes, the client that carries a remainder first,
