@@ -33,6 +33,7 @@ from ..consensus import Consensus
 from ..consensus.config import Committee, Parameters
 from ..consensus.mempool_driver import (
     MempoolCleanup,
+    MempoolCommit,
     MempoolGet,
     MempoolVerify,
     PayloadStatus,
@@ -43,6 +44,8 @@ from ..crypto.backend import set_backend
 from ..crypto.batch_service import BatchVerificationService
 from ..crypto.primitives import Digest, PublicKey, Signature
 from ..crypto.scheduler import SchedulerConfig
+from ..mempool.errors import QueueFullError
+from ..mempool.payload_queue import PayloadQueue
 from ..network import net
 from ..ops import timeline
 from ..store import Store
@@ -136,6 +139,50 @@ class BulkFlood:
     targets: tuple[int, ...] | None = None  # node indices; None = all honest
 
 
+@dataclass(slots=True)
+class PayloadLoad:
+    """Payload dissemination for chaos scenarios: every `1 / rate` virtual
+    seconds for `duration` seconds each running honest node makes one
+    payload digest (a per-node seeded stream) and it is queued on every
+    running node at once, as gossip would queue it. With a load wired the
+    nodes run `QueueMempool`, the real mempool's digest bookkeeping, so a
+    scenario can hold the committee to "every payload made commits once"."""
+
+    rate: float  # payloads per virtual second per node
+    duration: float
+
+
+class QueueMempool:
+    """The mempool's digest queue (mempool/payload_queue.py) behind a chaos
+    node: Get pops the front for the round's proposal, Cleanup and Commit
+    settle what was proposed, an orphan's digests come back to the front;
+    Verify accepts (payload bytes are not modelled)."""
+
+    def __init__(self, capacity: int = 10_000) -> None:
+        self.channel = channel()
+        self.payloads = PayloadQueue(capacity)
+        self.requeued = 0  # orphans' digests put back at the front
+
+    def start(self) -> None:
+        spawn(self._run(), name="chaos-mempool")
+
+    async def _run(self) -> None:
+        while True:
+            msg = await self.channel.get()
+            if isinstance(msg, MempoolGet):
+                limit = max(1, msg.max_size // Digest.SIZE)
+                msg.reply.set_result(self.payloads.take(limit, msg.round))
+            elif isinstance(msg, MempoolVerify):
+                msg.reply.set_result(PayloadStatus.ACCEPT)
+            elif isinstance(msg, MempoolCleanup):
+                for block in (msg.b0, msg.b1, msg.block):
+                    self.payloads.note_block(block.round, block.payload)
+            elif isinstance(msg, MempoolCommit):
+                self.requeued += len(
+                    self.payloads.note_commit(msg.round, msg.digests)
+                )
+
+
 class DeterministicMempool:
     """MockMempool with a per-node seeded stream: answers Get with one
     deterministic payload digest, Verify with ACCEPT (the consensus plane
@@ -174,7 +221,7 @@ class _NodeHandle:
     __slots__ = (
         "index", "pk", "seed", "store_path", "scope", "store", "service",
         "policy", "running", "core", "epochs", "proof_registry",
-        "proof_service",
+        "proof_service", "mempool",
     )
 
     def __init__(self, index: int, pk: PublicKey, seed: bytes, store_path: str | None):
@@ -191,6 +238,7 @@ class _NodeHandle:
         self.epochs: EpochManager | None = None  # this incarnation's view
         self.proof_registry = None  # proofs.ProofRegistry (proofs runs)
         self.proof_service = None  # proofs.ProofService over the registry
+        self.mempool = None  # this incarnation's mempool mock
 
 
 class ChaosOrchestrator:
@@ -213,6 +261,7 @@ class ChaosOrchestrator:
         proofs: bool = False,
         proof_squat_rate: float = 0.0,
         burn_budget: dict[str, float] | None = None,
+        payload_load: PayloadLoad | None = None,
     ) -> None:
         self.rng = SeededRng(seed)
         self.seed = seed
@@ -334,6 +383,10 @@ class ChaosOrchestrator:
         self.ingress_drivers: list[tuple[int, object]] = []  # (node, loadgen)
         self.flood = flood
         self.flood_stats: dict[int, dict] = {}  # node -> driver counters
+        self.payload_load = payload_load
+        # node -> payload digests it made / committed, in order (hex)
+        self.payloads_made: dict[int, list[str]] = {}
+        self.payload_commits: dict[int, list[str]] = {}
         # Commit-proof serving plane (§5.5q): with proofs=True every node
         # boots a ProofRegistry wired into its Core, admitted ingress tx
         # digests feed the target's DeterministicMempool (so accepted
@@ -447,13 +500,19 @@ class ChaosOrchestrator:
             with scope:
                 node.store = Store(node.store_path)
                 sig_service = pysigner.PySignatureService(node.seed)
-                mempool = DeterministicMempool(
-                    self.rng.stream(f"mempool:{i}"),
-                    pending=(
-                        self._proof_pending[i] if self.proofs_enabled else None
-                    ),
-                )
+                if self.payload_load is not None:
+                    mempool = QueueMempool()
+                else:
+                    mempool = DeterministicMempool(
+                        self.rng.stream(f"mempool:{i}"),
+                        pending=(
+                            self._proof_pending[i]
+                            if self.proofs_enabled
+                            else None
+                        ),
+                    )
                 mempool.start()
+                node.mempool = mempool
                 if self.proofs_enabled:
                     # Fresh registry per incarnation against the node's
                     # persisted store: a restart reloads the newest proof
@@ -855,6 +914,29 @@ class ChaosOrchestrator:
             stats["completed"] += 1
             stats["verified"] += sum(bool(ok) for ok in mask)
 
+    async def _make_payloads(self) -> None:
+        """PayloadLoad: each running honest node makes a digest every
+        `1 / rate` seconds, queued at once on every running node."""
+        load = self.payload_load
+        loop = asyncio.get_running_loop()
+        end = loop.time() + load.duration
+        streams = {i: self.rng.stream(f"payloads:{i}") for i in self.honest}
+        while True:
+            await asyncio.sleep(1.0 / load.rate)
+            if loop.time() > end:
+                return
+            for i in self.honest:
+                if not self.nodes[i].running:
+                    continue
+                digest = Digest(streams[i].randbytes(32))
+                self.payloads_made.setdefault(i, []).append(digest.data.hex())
+                for node in self.nodes:
+                    if node.running and isinstance(node.mempool, QueueMempool):
+                        try:
+                            node.mempool.payloads.insert(digest)
+                        except QueueFullError:
+                            pass
+
     def _peer_view(self, i: int) -> dict:
         """Node i's per-peer observatory snapshot (network/net.py ledger)
         re-keyed from transport addresses to node indices — the chaos
@@ -872,6 +954,10 @@ class ChaosOrchestrator:
             block = await commit_channel.get()
             self.safety.on_commit(i, block)
             self.liveness.on_commit(i, block, loop.time())
+            if self.payload_load is not None:
+                self.payload_commits.setdefault(i, []).extend(
+                    d.data.hex() for d in block.payload
+                )
 
     async def crash(self, i: int) -> None:
         node = self.nodes[i]
@@ -1139,6 +1225,8 @@ class ChaosOrchestrator:
                     self._boot_proof_squatters()
                 if self.flood is not None:
                     self._boot_flood()
+                if self.payload_load is not None:
+                    spawn(self._make_payloads(), name="chaos-payloads")
                 if self.telemetry_config is not None:
                     self._boot_telemetry(loop)
                 if self.plan.crashes or self.plan.boots:
@@ -1259,6 +1347,21 @@ class ChaosOrchestrator:
             # Per-node bulk-flood driver counters (BulkFlood scenarios).
             "flood": {
                 str(i): dict(stats) for i, stats in self.flood_stats.items()
+            },
+            # PayloadLoad runs: per node, the payload digests it made, the
+            # payload digests it committed in commit order, and how many
+            # digests of blocks that could no longer commit its (last)
+            # mempool queued again.
+            "payloads": {
+                "made": {str(i): v for i, v in sorted(self.payloads_made.items())},
+                "committed": {
+                    str(i): v for i, v in sorted(self.payload_commits.items())
+                },
+                "requeued": {
+                    str(i): node.mempool.requeued
+                    for i, node in enumerate(self.nodes)
+                    if isinstance(node.mempool, QueueMempool)
+                },
             },
             # Commit-proof serving plane (§5.5q): per-target tracking-
             # client outcomes — served/verified counts, submit→proof-in-

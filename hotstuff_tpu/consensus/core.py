@@ -101,10 +101,16 @@ _M_PROPOSALS = metrics.counter("consensus.proposals")
 _M_VOTES = metrics.counter("consensus.votes")
 _M_COMMITS = metrics.counter("consensus.commits")
 _M_TIMEOUTS = metrics.counter("consensus.timeouts")
+# One block a round: proposals a leader did not make again in a round it had
+# proposed in (a later QC or TC of the round before, whichever path brought it).
+_M_PROPOSALS_SUPPRESSED = metrics.counter("consensus.proposals_suppressed")
 _M_SYNC_SERVED = metrics.counter("consensus.sync_requests_served")
 _M_ROUND = metrics.gauge("consensus.round")
 _M_PROPOSAL_TO_VOTE = metrics.histogram("consensus.proposal_to_vote_s")
 _M_COMMIT_LATENCY = metrics.histogram("consensus.commit_latency_s")
+# A view change: a node's first local timeout of a stall to its next
+# QC-driven round advance, on the loop's clock.
+_M_VIEW_CHANGE = metrics.histogram("consensus.view_change_s")
 _M_RECONFIG_PROPOSED = metrics.counter("reconfig.proposed")
 _M_HANDOFF_COMMITS = metrics.counter("reconfig.handoff_commits")
 _M_RANGE_SERVED = metrics.counter("sync.range_served")
@@ -252,6 +258,11 @@ class Core:
         # Pacemaker backoff state: consecutive local timeouts without an
         # intervening QC-driven round advance (see Parameters.timeout_backoff).
         self._consecutive_timeouts = 0
+        # Loop time of the first local timeout of the current stall, until a
+        # QC advances the round again (consensus.view_change_s).
+        self._stall_since: float | None = None
+        # The round this node last proposed in: one block a round.
+        self._proposed_round: Round = 0
         # block digest -> first-seen monotonic time, for commit_latency_s
         # (insertion-ordered; bounded by _SEEN_CAP, oldest evicted).
         self._block_seen: dict[Digest, float] = {}
@@ -450,6 +461,9 @@ class Core:
                 break
             to_commit.append(parent)
         self.last_committed_round = block.round
+        # Only now do the payloads settle: those of processed blocks at or
+        # below this round that did not commit are proposed again.
+        await self.mempool_driver.commit(block.round, to_commit)
         # Persist the floor BEFORE announcing the commit: the epoch-
         # boundary crash scenarios land a crash inside the commit path
         # (the switch hook fires here), and a floor that only becomes
@@ -604,6 +618,11 @@ class Core:
             tracing.event(
                 "qc", tracing.trace_id(qc.round, qc.hash.data), adopted=True
             )
+        if qc.round >= self.round and self._stall_since is not None:
+            _M_VIEW_CHANGE.record(
+                asyncio.get_running_loop().time() - self._stall_since
+            )
+            self._stall_since = None
         if qc.round >= self.round and self._consecutive_timeouts:
             # A QC advancing the round is real progress: restore the base
             # pacemaker delay. (TC-driven advances deliberately keep the
@@ -680,6 +699,8 @@ class Core:
     async def _local_timeout_round(self) -> None:
         """Pacemaker fired (core.rs:175-197)."""
         _M_TIMEOUTS.inc()
+        if self._stall_since is None:
+            self._stall_since = asyncio.get_running_loop().time()
         tracing.event(
             "timeout", round=self.round,
             consecutive=self._consecutive_timeouts + 1,
@@ -754,7 +775,12 @@ class Core:
     # -- proposals -----------------------------------------------------------
 
     async def _generate_proposal(self, tc: TC | AggTC | None) -> None:
-        """Leader path (core.rs:278-318)."""
+        """Leader path (core.rs:278-318). One block a round: a leader that
+        signed two blocks in one round would split the round's votes between
+        them, and with f members dead neither would be certified."""
+        if self._proposed_round == self.round:
+            _M_PROPOSALS_SUPPRESSED.inc()
+            return
         if self.epochs.handoff_blocks(self.round):
             # Epoch-final wall, proposer side: nothing the old committee
             # proposes at or past a pending boundary may be certified, so
@@ -763,8 +789,11 @@ class Core:
             # owns these rounds).
             self.epochs.note_hold(self.round, "proposal")
             return
+        self._proposed_round = self.round
         t0 = time.perf_counter()
-        payload = await self.mempool_driver.get(self.parameters.max_payload_size)
+        payload = await self.mempool_driver.get(
+            self.parameters.max_payload_size, self.round
+        )
         payload_dur = time.perf_counter() - t0
         reconfig = self._take_reconfig()
         digest = Block.make_digest(
@@ -1437,7 +1466,11 @@ class Core:
         await self.overlay.after_merge(key)
 
     async def _handle_tc(self, tc: TC | AggTC) -> None:
-        """A TC received directly (core.rs:438-444)."""
+        """A TC received directly (core.rs:438-444). Every node that
+        assembles a round's TC broadcasts it, so the next leader receives the
+        same round's TC up to n - f times after the first moved it on: the
+        advance then does nothing, and `_generate_proposal`'s one-block-a-
+        round guard refuses the block again."""
         await tc.verify_async(self.epochs, self.verification_service)
         self._note_tc(tc)
         await self._advance_round(tc.round)
@@ -1612,6 +1645,11 @@ class Core:
         await self.epochs.load(self.store)
         self.epochs.note_round(self.round)
         self.synchronizer.note_committed(self.last_committed_round)
+        if self.last_committed_round:
+            # A restarted node's mempool starts empty: what it processes at
+            # or below the persisted floor committed before the crash, and
+            # must settle as committed, never come back as an orphan.
+            await self.mempool_driver.commit(self.last_committed_round, [])
         self.timer = Timer(self.parameters.timeout_delay)
         if self.parameters.probe_interval_ms > 0:
             spawn(self._probe_loop(), name="consensus-probe")

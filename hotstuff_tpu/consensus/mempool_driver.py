@@ -1,9 +1,13 @@
 """Consensus-side proxy to the mempool (reference consensus/src/mempool.rs).
 
 ConsensusMempoolMessage variants (mempool.rs:16-20):
-  * Get(max, reply)        -> payload digests for a new block
-  * Verify(block, reply)   -> payload availability: Accept / Reject / Wait
-  * Cleanup(b0, b1, block) -> drop state for committed/ordered payloads
+  * Get(max, reply, round) -> payload digests for the block of `round`
+  * Verify(block, reply)   -> payload availability: Accept / Reject / Wait;
+                              the block's payloads leave the queue
+  * Cleanup(b0, b1, block) -> the blocks' payloads leave the queue (processed)
+  * Commit(round, digests) -> blocks up to `round` committed these payloads;
+                              those of blocks that can no longer commit are
+                              queued again (not in the reference)
 
 On Wait the mempool synchronizer fetches missing payloads and loops the block
 back to the consensus core when they arrive, so `verify` simply returns False
@@ -30,6 +34,7 @@ class PayloadStatus(Enum):
 class MempoolGet:
     max_size: int
     reply: asyncio.Future
+    round: int = 0
 
 
 @dataclass(slots=True)
@@ -45,13 +50,19 @@ class MempoolCleanup:
     block: Block
 
 
+@dataclass(slots=True)
+class MempoolCommit:
+    round: int
+    digests: tuple
+
+
 class MempoolDriver:
     def __init__(self, mempool_channel: asyncio.Queue) -> None:
         self._tx = mempool_channel
 
-    async def get(self, max_size: int) -> list:
+    async def get(self, max_size: int, round_: int) -> list:
         fut = asyncio.get_running_loop().create_future()
-        await self._tx.put(MempoolGet(max_size, fut))
+        await self._tx.put(MempoolGet(max_size, fut, round_))
         return await fut
 
     async def verify(self, block: Block) -> bool:
@@ -70,3 +81,8 @@ class MempoolDriver:
 
     async def cleanup(self, b0: Block, b1: Block, block: Block) -> None:
         await self._tx.put(MempoolCleanup(b0, b1, block))
+
+    async def commit(self, round_: int, blocks: list[Block]) -> None:
+        await self._tx.put(
+            MempoolCommit(round_, tuple(d for b in blocks for d in b.payload))
+        )

@@ -25,6 +25,7 @@ from ..utils.actors import Selector, spawn
 from ..utils.serde import Reader, Writer
 from ..consensus.mempool_driver import (
     MempoolCleanup,
+    MempoolCommit,
     MempoolGet,
     MempoolVerify,
     PayloadStatus,
@@ -34,13 +35,13 @@ from .errors import (
     InvalidPayloadSignatureError,
     MempoolError,
     PayloadTooBigError,
-    QueueFullError,
     UnknownAuthorityError,
     ensure,
 )
 from .messages import OwnPayload, Payload, PayloadRequest
 from .messages import encode_mempool_message
 from .payload_maker import PayloadMaker
+from .payload_queue import PayloadQueue
 from .synchronizer import Synchronizer
 
 log = logging.getLogger("hotstuff.mempool")
@@ -66,6 +67,9 @@ _M_VERIFY_RTT = metrics.histogram("mempool.verify_rtt_s")
 # one sample a node, and the triples it holds.
 _M_POOL_BUILD = metrics.histogram("mempool.pool_build_s")
 _M_POOL_TRIPLES = metrics.counter("mempool.pool_triples")
+# Digests of a block that can no longer commit whose payload this node holds,
+# put back at the queue's front.
+_M_ORPHANS_REQUEUED = metrics.counter("mempool.orphans_requeued")
 
 
 class SyntheticPool:
@@ -171,14 +175,9 @@ class Core:
         self._gossip_dropped = 0  # payloads shed at full acceptance bound
         self._synthetic_skipped = 0  # workload sigs skipped at a full pipeline
         self._requests_clamped = 0  # oversized payload requests clamped
-        # Undelivered payload digests, insertion-ordered (core.rs:50 queue).
-        self.queue: dict[Digest, None] = {}
-        # Digests already consumed by consensus cleanup. Background payload
-        # verification may finish AFTER the block containing the payload
-        # committed; inserting then would re-propose a committed payload.
-        # Bounded insertion-ordered set (evicts oldest).
-        self._cleaned: dict[Digest, None] = {}
-        self._cleaned_cap = 4 * parameters.queue_capacity
+        # Undelivered payload digests (core.rs:50 queue), those in blocks
+        # that may still commit, and those that committed.
+        self.payloads = PayloadQueue(parameters.queue_capacity)
         self.pool: SyntheticPool | None = None
         if parameters.benchmark_mode:
             size, per_node = synthetic_pool(parameters.synthetic_pool_size)
@@ -373,13 +372,10 @@ class Core:
         await self._submit_synthetic_batch("OTHER", len(payload.transactions))
 
     def _queue_insert(self, digest: Digest) -> None:
-        if digest in self._cleaned:
-            return  # already ordered and cleaned up; do not re-propose
-        ensure(
-            len(self.queue) < self.parameters.queue_capacity,
-            QueueFullError(self.parameters.queue_capacity),
-        )
-        self.queue[digest] = None
+        # A digest in a block that may still commit, or that committed, is
+        # not queued again: background verification can finish after the
+        # block holding the payload was processed.
+        self.payloads.insert(digest)
 
     async def _handle_request(self, request: PayloadRequest) -> None:
         """Serve stored payloads to a lagging peer (core.rs:236-249).
@@ -429,38 +425,38 @@ class Core:
 
     # -- consensus driver ----------------------------------------------------
 
-    async def _get_payload(self, max_size: int) -> list[Digest]:
-        """Pop up to max_size/32 digests; if the queue is dry, force the
-        PayloadMaker to flush (core.rs:251-268)."""
+    async def _get_payload(self, max_size: int, round_: int) -> list[Digest]:
+        """Pop up to max_size/32 digests for the proposal of `round_`; if the
+        queue is dry, force the PayloadMaker to flush (core.rs:251-268)."""
         limit = max(1, max_size // Digest.SIZE)
-        if self.queue:
-            out = []
-            for digest in list(self.queue):
-                if len(out) >= limit:
-                    break
-                out.append(digest)
-                del self.queue[digest]
-            return out
+        if self.payloads.queue:
+            return self.payloads.take(limit, round_)
         payload = await self.payload_maker.request_make()
         if not payload.transactions:
             return []
-        digest = await self._handle_own_payload(payload)
-        # A freshly-made payload can collide with an already-committed digest
-        # (identical tx content re-made after a cleanup): _queue_insert skips
-        # cleaned digests, and re-proposing one would double-include it.
-        if digest not in self.queue:
-            return []
-        del self.queue[digest]  # it is being delivered right now
-        return [digest]
+        await self._handle_own_payload(payload)
+        # A freshly-made payload can collide with a pending or committed
+        # digest (identical tx content re-made): it was not queued, and
+        # proposing it again would double-include it.
+        return self.payloads.take(limit, round_)
 
     async def _cleanup(self, msg: MempoolCleanup) -> None:
         for block in (msg.b0, msg.b1, msg.block):
-            for digest in block.payload:
-                self.queue.pop(digest, None)
-                self._cleaned[digest] = None
-        while len(self._cleaned) > self._cleaned_cap:
-            self._cleaned.pop(next(iter(self._cleaned)))
+            self.payloads.note_block(block.round, block.payload)
         self.synchronizer.cleanup(msg.b0.round)
+
+    def _commit(self, msg: MempoolCommit) -> None:
+        orphans = self.payloads.note_commit(msg.round, msg.digests)
+        if orphans:
+            _M_ORPHANS_REQUEUED.inc(len(orphans))
+            # the digests of blocks at or below the committed round that did
+            # not commit and whose payload is here, back at the front of the
+            # queue
+            log.info(
+                "Queued again at commit of B%s: %s",
+                msg.round,
+                " ".join(str(d) for d in orphans),
+            )
 
     # -- main loop -----------------------------------------------------------
 
@@ -475,7 +471,7 @@ class Core:
             # its single select loop, so a dropped future deadlocks the node.
             if isinstance(msg, MempoolGet):
                 try:
-                    result = await self._get_payload(msg.max_size)
+                    result = await self._get_payload(msg.max_size, msg.round)
                 except Exception as e:
                     log.error("get_payload failed: %r", e)
                     result = []
@@ -483,6 +479,11 @@ class Core:
                     msg.reply.set_result(result)
                 continue
             if isinstance(msg, MempoolVerify):
+                # The digests of a verified proposal leave the queue now, not
+                # once its payloads are all here and it is processed: a leader
+                # that assembles the next QC from others' votes meanwhile must
+                # not propose them again in a block that extends this one.
+                self.payloads.note_block(msg.block.round, msg.block.payload)
                 try:
                     status = await self.synchronizer.verify_payload(msg.block)
                 except Exception as e:
@@ -500,6 +501,8 @@ class Core:
                     await self._handle_request(msg)
                 elif isinstance(msg, MempoolCleanup):
                     await self._cleanup(msg)
+                elif isinstance(msg, MempoolCommit):
+                    self._commit(msg)
                 else:
                     log.warning("unexpected mempool message: %r", msg)
             except MempoolError as e:  # typed Byzantine-input rejection

@@ -135,7 +135,7 @@ class Mempool:
         # the committee can't fetch without sync round-trips (admission
         # shedding at the Front is where overload is supposed to land).
         payload_maker.backlog_fn = lambda: (
-            len(core.queue) >= parameters.queue_capacity
+            len(core.payloads) >= parameters.queue_capacity
             or sender.egress_backlogged()
         )
         if parameters.ingress_enabled:

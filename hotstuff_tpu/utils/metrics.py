@@ -637,6 +637,7 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("consensus.votes", "counter", None),
     ("consensus.commits", "counter", None),
     ("consensus.timeouts", "counter", None),
+    ("consensus.proposals_suppressed", "counter", None),
     ("consensus.qcs", "counter", None),
     ("consensus.tcs", "counter", None),
     ("consensus.sync_requests", "counter", None),
@@ -699,6 +700,7 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("consensus.qc_form_s", "histogram", None),
     ("consensus.tc_form_s", "histogram", None),
     ("consensus.commit_latency_s", "histogram", None),
+    ("consensus.view_change_s", "histogram", None),
     # mempool/core.py
     ("mempool.payloads_own", "counter", None),
     ("mempool.payloads_other", "counter", None),
@@ -714,6 +716,7 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("mempool.verify_rtt_s", "histogram", None),
     ("mempool.pool_build_s", "histogram", None),
     ("mempool.pool_triples", "counter", None),
+    ("mempool.orphans_requeued", "counter", None),
     # ingress/ — authenticated client plane with admission control
     ("ingress.received", "counter", None),
     ("ingress.admitted", "counter", None),

@@ -587,3 +587,51 @@ def test_restart_store_file_grows(tmp_path):
         ("crash", 2),
         ("restart", 2),
     ]
+
+
+# --- one member dead from the start: every payload commits once -------------
+
+
+@pytest.mark.parametrize("seed", [11, 23])
+def test_dead_member_every_payload_commits_once(seed):
+    """n = 4 with node 3 dead from the start (upstream's crash-fault runs:
+    the last f members never boot). The live nodes make payloads for 4
+    virtual seconds, faster than a rotation of the leader can commit them,
+    so the block proposed just before the dead member's round (whose votes
+    go to it and which never gets a QC) carries payloads. Every payload the
+    live nodes made must commit exactly once, on every live node, in one
+    agreed order: the leader proposes once a round, and an orphaned block's
+    payloads are proposed again."""
+    from hotstuff_tpu.chaos import ChaosOrchestrator
+    from hotstuff_tpu.chaos import vtime
+    from hotstuff_tpu.chaos.orchestrator import PayloadLoad
+    from hotstuff_tpu.consensus.config import Parameters
+
+    plan = FaultPlan(
+        default_link=LinkFaults(delay=0.01),
+        crashes=[CrashWindow(node=3, at=0.0, restart=None)],
+    )
+
+    async def body():
+        orch = ChaosOrchestrator(
+            seed=seed,
+            n=4,
+            plan=plan,
+            parameters=Parameters(timeout_delay=1_000, sync_retry_delay=1_000),
+            trusted_crypto=True,
+            payload_load=PayloadLoad(rate=10.0, duration=4.0),
+        )
+        return await orch.run(40.0)
+
+    report = vtime.run(body(), timeout=60, wall_timeout=120)
+    assert report["ok"], report["safety_violations"] + report["liveness_violations"]
+    made = [d for i in ("0", "1", "2") for d in report["payloads"]["made"][i]]
+    assert len(made) == len(set(made)) > 100
+    committed = [report["payloads"]["committed"][i] for i in ("0", "1", "2")]
+    assert not report["payloads"]["committed"].get("3")
+    for seq in committed:
+        assert len(seq) == len(set(seq))  # once
+        assert set(seq) == set(made)  # every one, nothing else
+    assert committed[0] == committed[1] == committed[2]  # one order
+    # the run did orphan payload-carrying blocks, and put them back
+    assert all(report["payloads"]["requeued"][i] > 0 for i in ("0", "1", "2"))

@@ -104,6 +104,51 @@ def test_generate_proposal_on_qc(run_async, base_port):
     run_async(body())
 
 
+@pytest.mark.parametrize("k", [1, 3])
+def test_one_block_a_round_whatever_moves_the_leader(run_async, base_port, k):
+    """Every node that assembles a round's TC broadcasts it, so the next
+    leader meets that round's QC or TC again and again after the first moved
+    it on. It proposes once: the block the QC made, and for each of the k
+    TCs that followed, nothing but `consensus.proposals_suppressed`."""
+    from itertools import combinations
+
+    from hotstuff_tpu.consensus.messages import TC
+    from hotstuff_tpu.utils import metrics
+
+    suppressed = metrics.counter("consensus.proposals_suppressed")
+
+    async def body():
+        cmt = committee(base_port)
+        elector = LeaderElector(cmt)
+        b1, b2 = chain(2, cmt)
+        idx = next(i for i, (pk, _) in enumerate(keys()) if pk == elector.get_leader(3))
+        core, core_channel, network_tx, _ = make_core(idx, cmt, timeout_ms=60_000)
+        spawn(core.run())
+        before = suppressed.value
+        for pk, sk in keys()[:3]:  # a quorum of votes on b2: the round-2 QC
+            await core_channel.put(Vote.new_from_key(b2.digest(), 2, pk, sk))
+        for signers in list(combinations(keys(), 3))[:k]:  # and k round-2 TCs
+            timeouts = [Timeout.new_from_key(qc_for(b1), 2, pk, sk) for pk, sk in signers]
+            await core_channel.put(
+                TC(2, tuple((t.author, t.signature, t.high_qc.round) for t in timeouts))
+            )
+        for _ in range(200):
+            if suppressed.value - before >= k:
+                break
+            await asyncio.sleep(0.05)
+        await asyncio.sleep(0.2)
+        blocks = []
+        while not network_tx.empty():
+            out = decode_consensus_message(network_tx.get_nowait().data)
+            if isinstance(out, Block):
+                blocks.append(out)
+        assert [(b.round, b.qc.hash) for b in blocks] == [(3, b2.digest())]
+        assert suppressed.value - before == k
+        assert core.round == 3
+
+    run_async(body())
+
+
 def test_commit_on_two_chain(run_async, base_port):
     async def body():
         cmt = committee(base_port)

@@ -50,7 +50,7 @@ def test_unknown_authority_rejected(run_async):
         with pytest.raises(UnknownAuthorityError):
             await core._handle_others_payload(payload)
         await core.drain_verifications()
-        assert not core.queue
+        assert not core.payloads.queue
 
     run_async(body())
 
@@ -63,7 +63,7 @@ def test_oversized_payload_rejected(run_async):
         with pytest.raises(PayloadTooBigError):
             await core._handle_others_payload(payload)
         await core.drain_verifications()
-        assert not core.queue
+        assert not core.payloads.queue
 
     run_async(body())
 
@@ -79,7 +79,7 @@ def test_bad_signature_rejected(run_async):
         payload = Payload.new_from_key([b"\x01" + bytes(40)], author_pk, wrong_sk)
         await core._handle_others_payload(payload)
         await core.drain_verifications()
-        assert not core.queue
+        assert not core.payloads.queue
         assert await core.store.read(b"payload:" + payload.digest().data) is None
 
     run_async(body())
@@ -92,7 +92,7 @@ def test_valid_payload_accepted(run_async):
         payload = Payload.new_from_key([b"\x01" + bytes(40)], author_pk, author_sk)
         await core._handle_others_payload(payload)
         await core.drain_verifications()
-        assert payload.digest() in core.queue
+        assert payload.digest() in core.payloads.queue
         assert await core.store.read(b"payload:" + payload.digest().data) is not None
 
     run_async(body())
@@ -106,11 +106,11 @@ def test_queue_full_rejected(run_async):
         p2 = Payload.new_from_key([b"\x02" + bytes(40)], author_pk, author_sk)
         await core._handle_others_payload(p1)
         await core.drain_verifications()
-        assert len(core.queue) == 1
+        assert len(core.payloads) == 1
         # second one: stored (it IS valid) but the queue insert must raise
         await core._handle_others_payload(p2)
         await core.drain_verifications()
-        assert len(core.queue) == 1
+        assert len(core.payloads) == 1
 
     run_async(body())
 
