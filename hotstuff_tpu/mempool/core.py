@@ -53,6 +53,9 @@ _M_PAYLOADS_OTHER = metrics.counter("mempool.payloads_other")
 _M_PAYLOAD_BYTES = metrics.counter("mempool.payload_bytes")
 _M_REQUESTS_SERVED = metrics.counter("mempool.payload_requests_served")
 _M_GOSSIP_DROPPED = metrics.counter("mempool.gossip_dropped")
+# Copies of others' payloads already stored, or byte-identical to one in
+# acceptance: not verified, stored or charged again.
+_M_PAYLOADS_DUPLICATE = metrics.counter("mempool.payloads_duplicate")
 _M_SYNTHETIC_SKIPPED = metrics.counter("mempool.synthetic_skipped")
 # the same in batches: `_verify_sem` counts batches, whatever their size
 _M_SYNTHETIC_SKIPPED_BATCHES = metrics.counter("mempool.synthetic_skipped_batches")
@@ -171,6 +174,10 @@ class Core:
         # best-effort by contract; the payload synchronizer recovers any
         # payload consensus actually needs.
         self._accept_sem = asyncio.Semaphore(64)
+        # (digest, author signature) of each payload in acceptance: what
+        # decides its acceptance, so a copy with the same key gets the same
+        # answer. At most one key a slot of `_accept_sem`.
+        self._accepting: set[tuple[Digest, bytes]] = set()
         self._inflight: set[asyncio.Task] = set()
         self._gossip_dropped = 0  # payloads shed at full acceptance bound
         self._synthetic_skipped = 0  # workload sigs skipped at a full pipeline
@@ -323,6 +330,24 @@ class Core:
             payload.size() <= self.parameters.max_payload_size,
             PayloadTooBigError(payload.size(), self.parameters.max_payload_size),
         )
+        # A payload is accepted once. A copy whose digest is stored, or whose
+        # digest AND signature bytes are those of a copy in acceptance, would
+        # get the same answer: typically the reply to a PayloadRequest sent
+        # while the gossiped copy's check was still in flight (signatures are
+        # deterministic, so an honest author's copies are byte-identical). A
+        # copy with other signature bytes is checked on its own, so a forged
+        # copy never shadows the valid one. Checked before the bound, so
+        # `gossip_dropped` counts only copies that were needed.
+        digest = payload.digest()
+        key = (digest, payload.signature.data)
+        # The map before the store: a check that ends while the read waits
+        # has stored its payload before its key leaves the map.
+        if (
+            key in self._accepting
+            or await self.store.read(PAYLOAD_PREFIX + digest.data) is not None
+        ):
+            _M_PAYLOADS_DUPLICATE.inc()
+            return
         # Acceptance (verify the author's ONE signature, store, queue) is
         # cheap and consensus-critical: it rides its own wide bound
         # (_accept_sem), never the workload-saturated _verify_sem. Only
@@ -345,31 +370,38 @@ class Core:
                     self._gossip_dropped,
                 )
             return
+        self._accepting.add(key)
         await self._spawn_verification(
-            self._finish_others_payload, payload, sem=self._accept_sem
+            self._finish_others_payload, payload, key, sem=self._accept_sem
         )
 
-    async def _finish_others_payload(self, payload: Payload) -> None:
-        ok = await payload.verify_async(self.committee, self.verification_service)
-        if not ok:
-            raise InvalidPayloadSignatureError(payload.author.short())
-        _M_PAYLOADS_OTHER.inc()
-        _M_PAYLOAD_BYTES.inc(payload.size())
-        # Store + queue as soon as the REAL signature verifies: consensus
-        # blocks on payload availability, and the synthetic workload below is
-        # pure load whose result never gates acceptance (the reference
-        # verifies pre-generated triples, mempool/src/core.rs:211-224 — the
-        # outcome is measured, not consumed).
-        await self._store_payload(payload)
-        if tracing.enabled():
-            tracing.event(
-                "payload.stored", tracing.trace_id(0, payload.digest().data)
+    async def _finish_others_payload(self, payload: Payload, key) -> None:
+        try:
+            ok = await payload.verify_async(
+                self.committee, self.verification_service
             )
-        self._queue_insert(payload.digest())
-        # The synthetic OTHER batch rides the capped pipeline; at a full
-        # pipeline the measurement load is skipped so acceptance never
-        # queues behind it.
-        await self._submit_synthetic_batch("OTHER", len(payload.transactions))
+            if not ok:
+                raise InvalidPayloadSignatureError(payload.author.short())
+            _M_PAYLOADS_OTHER.inc()
+            _M_PAYLOAD_BYTES.inc(payload.size())
+            # Store + queue as soon as the REAL signature verifies: consensus
+            # blocks on payload availability, and the synthetic workload below
+            # is pure load whose result never gates acceptance (the reference
+            # verifies pre-generated triples, mempool/src/core.rs:211-224 —
+            # the outcome is measured, not consumed).
+            await self._store_payload(payload)
+            if tracing.enabled():
+                tracing.event(
+                    "payload.stored", tracing.trace_id(0, payload.digest().data)
+                )
+            self._queue_insert(payload.digest())
+            # The synthetic OTHER batch rides the capped pipeline; at a full
+            # pipeline the measurement load is skipped so acceptance never
+            # queues behind it.
+            await self._submit_synthetic_batch("OTHER", len(payload.transactions))
+        finally:
+            # stored by now, or rejected: a later copy is checked afresh
+            self._accepting.discard(key)
 
     def _queue_insert(self, digest: Digest) -> None:
         # A digest in a block that may still commit, or that committed, is

@@ -704,6 +704,7 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     # mempool/core.py
     ("mempool.payloads_own", "counter", None),
     ("mempool.payloads_other", "counter", None),
+    ("mempool.payloads_duplicate", "counter", None),
     ("mempool.payload_bytes", "counter", None),
     ("mempool.payload_requests_served", "counter", None),
     ("mempool.gossip_dropped", "counter", None),

@@ -23,6 +23,9 @@ _BASE = {
     "verifier.readback_ms", "sidecar.columnar_share", "sidecar.cache_hit_share",
     "node.pool_build_s", "node.batch_sigs", "node.request_sigs", "node.cpu_verified_share",
     "sidecar.sigs_per_request",
+    # appended later with no `workloads` list: due wherever
+    # `verified_tx_per_s` is, so in every cell
+    "mempool.duplicate_share",
 }
 _FLOOD = {"loadgen.failed_share", "flood.committed_p95_ms", "front.shed_share",
           "flood.verified_share"}

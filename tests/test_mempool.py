@@ -378,3 +378,218 @@ def test_oversized_payload_request_clamped(run_async, base_port):
         assert count == 2
 
     run_async(body())
+
+
+# -- a payload is accepted once ------------------------------------------------
+
+
+class _HeldService:
+    """The node's verification service with every payload check counted and
+    held until `gate` is set (so a first copy stays in acceptance while a
+    second arrives); `answers` are verdicts handed out instead of the real
+    ones, first calls first. Workload batches pass straight through."""
+
+    def __init__(self, answers=()):
+        from hotstuff_tpu.crypto.batch_service import BatchVerificationService
+
+        self.service = BatchVerificationService()
+        self.gate = asyncio.Event()
+        self.checked = []
+        self.answers = list(answers)
+
+    async def verify(self, msg, key, sig, **kw):
+        self.checked.append((msg, sig.data))
+        await self.gate.wait()
+        if self.answers:
+            return self.answers.pop(0)
+        return await self.service.verify(msg, key, sig, **kw)
+
+    async def verify_group(self, *args, **kw):
+        return await self.service.verify_group(*args, **kw)
+
+
+class _CountingStore(Store):
+    def __init__(self):
+        super().__init__()
+        self.writes = []
+
+    async def write(self, key, value):
+        self.writes.append(key)
+        await super().write(key, value)
+
+
+def _counts():
+    from hotstuff_tpu.utils import metrics
+
+    return (
+        metrics.counter("mempool.payloads_duplicate").value,
+        metrics.counter("mempool.payloads_other").value,
+        metrics.histogram("mempool.verify_batch_size", metrics.SIZE_BUCKETS).count,
+    )
+
+
+def _workload_core(store, service):
+    from hotstuff_tpu.mempool.core import Core
+
+    params = MempoolParameters(benchmark_mode=True, synthetic_pool_size=16)
+    return Core(
+        keys()[0][0], mempool_committee(0), params, store, None, None,
+        channel(), channel(), channel(), verification_service=service,
+    )
+
+
+def _copy(payload):
+    """The same bytes as another object: what a PayloadRequest's reply is."""
+    return decode_mempool_message(encode_mempool_message(payload))
+
+
+def _since(before):
+    return tuple(now - then for now, then in zip(_counts(), before))
+
+
+def test_a_copy_that_arrives_while_the_first_is_in_acceptance_is_not_checked_again(
+    run_async,
+):
+    """The gossiped copy's check is in flight when the reply to the
+    synchronizer's PayloadRequest brings the same bytes: one check, one store
+    write, one OTHER batch, and the reply counted as a duplicate."""
+
+    async def body():
+        author_pk, author_sk = keys()[1]
+        gossip = Payload.new_from_key([b"\x01" + bytes(40)], author_pk, author_sk)
+        store, service = _CountingStore(), _HeldService()
+        core = _workload_core(store, service)
+        before = _counts()
+        await core._handle_others_payload(gossip)
+        await core._handle_others_payload(_copy(gossip))
+        await asyncio.sleep(0.01)  # the first copy's check is waiting
+        assert len(service.checked) == 1
+        service.gate.set()
+        await core.drain_verifications()
+        key = b"payload:" + gossip.digest().data
+        assert store.writes.count(key) == 1
+        # duplicates +1, accepted +1, workload batches +1
+        assert _since(before) == (1, 1, 1)
+        assert list(core.payloads.queue) == [gossip.digest()]
+        assert not core._accepting
+
+    run_async(body())
+
+
+def test_a_copy_of_a_stored_payload_is_skipped(run_async):
+    async def body():
+        author_pk, author_sk = keys()[1]
+        payload = Payload.new_from_key([b"\x01" + bytes(40)], author_pk, author_sk)
+        store, service = _CountingStore(), _HeldService()
+        service.gate.set()
+        core = _workload_core(store, service)
+        before = _counts()
+        await core._handle_others_payload(payload)
+        await core.drain_verifications()
+        assert _since(before) == (0, 1, 1)
+        await core._handle_others_payload(_copy(payload))
+        await core.drain_verifications()
+        assert len(service.checked) == 1
+        assert store.writes.count(b"payload:" + payload.digest().data) == 1
+        assert _since(before) == (1, 1, 1)
+
+    run_async(body())
+
+
+@pytest.mark.parametrize("forged_first", [False, True], ids=["valid_first", "forged_first"])
+def test_a_copy_with_other_signature_bytes_is_checked_on_its_own(run_async, forged_first):
+    """The same digest signed by the wrong key, while the valid copy is in
+    acceptance (or ahead of it): each is verified, the forged one rejected,
+    the valid one accepted and charged once; neither shadows the other."""
+
+    async def body():
+        author_pk, author_sk = keys()[1]
+        _, wrong_sk = keys()[2]
+        txs = [b"\x01" + bytes(40)]
+        valid = Payload.new_from_key(txs, author_pk, author_sk)
+        forged = Payload.new_from_key(txs, author_pk, wrong_sk)
+        assert forged.digest() == valid.digest() and forged.signature != valid.signature
+        store, service = _CountingStore(), _HeldService()
+        core = _workload_core(store, service)
+        before = _counts()
+        for p in (forged, valid) if forged_first else (valid, forged):
+            await core._handle_others_payload(p)
+        await asyncio.sleep(0.01)
+        assert sorted(sig for _m, sig in service.checked) == sorted(
+            [valid.signature.data, forged.signature.data]
+        )
+        service.gate.set()
+        await core.drain_verifications()
+        assert _since(before) == (0, 1, 1)
+        raw = await store.read(b"payload:" + valid.digest().data)
+        w = Writer()
+        valid.encode(w)
+        assert raw == w.bytes()
+        assert list(core.payloads.queue) == [valid.digest()]
+        assert not core._accepting
+
+    run_async(body())
+
+
+def test_after_a_failed_acceptance_a_later_copy_is_checked_again(run_async):
+    async def body():
+        author_pk, author_sk = keys()[1]
+        payload = Payload.new_from_key([b"\x01" + bytes(40)], author_pk, author_sk)
+        store, service = _CountingStore(), _HeldService(answers=[False])
+        service.gate.set()
+        core = _workload_core(store, service)
+        before = _counts()
+        await core._handle_others_payload(payload)
+        await core.drain_verifications()
+        key = b"payload:" + payload.digest().data
+        assert await store.read(key) is None and not core._accepting
+        assert _since(before) == (0, 0, 0)
+        await core._handle_others_payload(_copy(payload))
+        await core.drain_verifications()
+        assert len(service.checked) == 2
+        assert store.writes.count(key) == 1
+        assert _since(before) == (0, 1, 1)
+
+    run_async(body())
+
+
+def test_a_waiting_block_wakes_once_on_the_first_copys_store(run_async, base_port):
+    """A block waits for a payload whose gossiped copy is in acceptance; the
+    reply to the synchronizer's request brings a second copy. The block goes
+    back to consensus once, when the first copy is stored."""
+
+    async def body():
+        n = 4
+        pk, sk = keys()[0]
+        store, consensus_channel, cm = _CountingStore(), channel(), channel()
+        core = Mempool.run(
+            pk, mempool_committee(base_port, n), MempoolParameters(), store,
+            SignatureService(sk), cm, consensus_channel,
+        )
+        service = _HeldService()
+        core.verification_service = service
+        await asyncio.sleep(0.05)
+        author_pk, author_sk = keys()[1]
+        payload = Payload.new_from_key([b"\x01" + bytes(40)], author_pk, author_sk)
+        block = chain(1, committee(base_port + 2 * n))[0]
+        object.__setattr__(block, "payload", (payload.digest(),))
+        fut = asyncio.get_running_loop().create_future()
+        await cm.put(MempoolVerify(block, fut))
+        assert await asyncio.wait_for(fut, 5) == PayloadStatus.WAIT
+
+        before = _counts()
+        await core._handle_others_payload(payload)
+        await core._handle_others_payload(_copy(payload))
+        await asyncio.sleep(0.05)
+        assert consensus_channel.empty()
+        service.gate.set()
+        lb = await asyncio.wait_for(consensus_channel.get(), 5)
+        assert lb.block == block
+        await core.drain_verifications()
+        await asyncio.sleep(0.1)
+        assert consensus_channel.empty()
+        assert len(service.checked) == 1
+        assert store.writes.count(b"payload:" + payload.digest().data) == 1
+        assert _since(before)[:2] == (1, 1)
+
+    run_async(body())

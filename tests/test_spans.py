@@ -577,6 +577,68 @@ def test_every_cell_reports_the_small_batch_metrics():
     assert all("workloads" not in m for m in bench["per_layer"][first:first + 4])
 
 
+# -- copies of a payload not checked again -------------------------------------
+
+# (node 0's duplicates and accepted at the window's first snapshot, the same at
+# its last; node 1's; the reading)
+DUPLICATE_CASES = {
+    # in the window 30 + 10 duplicates of 80 + 80 copies
+    "pooled": ((100, 500), (130, 550), (0, 20), (10, 90), 25.0),
+    "none_again": ((5, 10), (5, 60), (0, 0), (0, 40), 0.0),
+    "no_copy_in_the_window": ((5, 10), (5, 10), (0, 0), (0, 0), None),
+}
+
+
+def _duplicate_src(node0, node1):
+    src = _src()
+    for node, (at_first, at_last) in zip(src["nodes"], (node0, node1)):
+        (_t, first), (_t, last), (_t, after) = node["snapshots"]
+        for snap, (dup, other) in ((first, at_first), (last, at_last), (after, at_last)):
+            snap["counters"].update({"mempool.payloads_duplicate": dup,
+                                     "mempool.payloads_other": other})
+    return src
+
+
+@pytest.mark.parametrize("case", sorted(DUPLICATE_CASES))
+def test_duplicate_share_is_pooled_over_the_nodes(case):
+    from chipbench import run
+
+    read = run.load_reader("per_layer", "mempool.duplicate_share")
+    n0_first, n0_last, n1_first, n1_last, expected = DUPLICATE_CASES[case]
+    src = _duplicate_src((n0_first, n0_last), (n1_first, n1_last))
+    assert read(src) == (expected if expected is None else pytest.approx(expected))
+
+
+def test_duplicate_share_needs_the_counter_and_a_bracketing_snapshot():
+    from chipbench import run
+
+    read = run.load_reader("per_layer", "mempool.duplicate_share")
+    # a program that accepts every copy: the counter is on no snapshot
+    older = _duplicate_src(((100, 500), (130, 550)), ((0, 20), (10, 100)))
+    for _t, snap in older["nodes"][1]["snapshots"]:
+        snap["counters"].pop("mempool.payloads_duplicate")
+    assert read(older) is None
+    # one node with no snapshot at or before the window's opening
+    late = _duplicate_src(((100, 500), (130, 550)), ((0, 20), (10, 100)))
+    late["nodes"][0]["snapshots"] = late["nodes"][0]["snapshots"][1:]
+    assert read(late) is None
+
+
+def test_every_cell_reports_the_duplicate_share():
+    from chipbench import run
+
+    bench = run.load_benchmark()
+    (entry,) = (m for m in bench["per_layer"] if m["name"] == "mempool.duplicate_share")
+    assert entry == {
+        "name": "mempool.duplicate_share", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "mempool", "moves": "verified_tx_per_s",
+    }
+    assert bench["per_layer"][-1] == entry
+    for cell in bench["workloads"]:
+        names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
+        assert "mempool.duplicate_share" in names, cell["name"]
+
+
 def test_every_new_name_is_in_the_namespace():
     declared = {name for name, _kind, _b in metrics._DEFAULT_NAMESPACE}
     assert {
@@ -588,6 +650,7 @@ def test_every_new_name_is_in_the_namespace():
         "crypto.remote_cpu_sigs", "crypto.remote_cpu_batches",
         "mempool.synthetic_skipped_batches",
         "scheduler.critical_groups", "scheduler.critical_held",
+        "mempool.payloads_duplicate",
     } <= declared
     # a phase's histogram is its profiler name plus `_s`
     for name in timeline.PHASES.values():
