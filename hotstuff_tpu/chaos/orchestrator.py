@@ -1190,9 +1190,10 @@ class ChaosOrchestrator:
         # deterministically; a fresh ring isolates the run's dump.
         prev_clock = tracing.set_clock(loop.time)
         tracing.reset()
-        # The services' `collect` spans fill the timeline ring too: a ring
-        # that carried over would overflow (`timeline.dropped`, a counter
-        # the telemetry snapshots ship) at another point of a same-seed rerun.
+        # The services' `collect` spans fill the timeline ring too, and
+        # their edges reach its idle account, whose `timeline.*` counters
+        # the telemetry snapshots ship: start both afresh, so that a
+        # same-seed rerun sees the same state.
         timeline.reset()
         self.watchdog_dumps: list[dict] = []
 

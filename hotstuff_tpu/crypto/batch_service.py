@@ -356,6 +356,7 @@ class BatchVerificationService:
         self._ensure_task()
         group.t_submit = asyncio.get_running_loop().time()
         self.scheduler.submit(group)
+        timeline.ACCOUNT.submitted()
         return await group.future
 
     async def verify(
@@ -392,18 +393,25 @@ class BatchVerificationService:
     ) -> asyncio.Task:
         from ..utils.actors import spawn
 
+        # The scheduler closed this bucket: the device's idle account
+        # charges it until its first program, or its end (ops/timeline.py).
+        bucket = timeline.ACCOUNT.closed(len(groups))
         task = spawn(
-            self._dispatch(groups, total, urgent, backend_idx),
+            self._dispatch(groups, total, urgent, backend_idx, bucket),
             name="verify-dispatch",
         )
         self._dispatches.add(task)
         task.add_done_callback(self._dispatches.discard)
+        task.add_done_callback(bucket.end)
         return task
 
     async def _dispatch(
         self, groups: list[_Group], total: int, urgent: bool,
-        backend_idx: int = 0,
+        backend_idx: int = 0, bucket=None,
     ) -> None:
+        # the verifier's pipeline, under `to_thread`'s copy of this context,
+        # takes the bucket out of the idle account's closed state
+        timeline.BUCKET.set(bucket)
         if not urgent:
             await self._dispatch_sem.acquire()
         try:

@@ -44,7 +44,11 @@ The pipeline times the `stage` and `readback` phases of each task
 (`timeline.span`: the ring under the task's key, the task's histogram of
 that phase, the profiler annotation); the task's `submit` callable owns
 the `upload` and `dispatch` phases (the `submit` of the verifier's chunk
-loop, `Ed25519TpuVerifier._run`).
+loop, `Ed25519TpuVerifier._run`). The same two edges the backdated
+`readback` span has, a dispatch returned and a mask on the host, are the
+timeline's idle account's program edges (ops/timeline.py `IdleAccount`);
+the first program of a run charges the service's closed bucket the run
+serves (`timeline.BUCKET`, read on the caller's thread).
 `TIMELINE_STAGES` is the full vocabulary — the graftlint `pipeline`
 pass asserts
 it stays inside `timeline.PHASES` so trace_report.py's device rows keep
@@ -89,7 +93,6 @@ TIMELINE_STAGES: tuple[str, ...] = ("stage", "upload", "dispatch", "readback")
 
 _M_CHUNKS = metrics.counter("pipeline.chunks")
 _M_DEPTH = metrics.gauge("pipeline.depth")
-_M_INFLIGHT = metrics.gauge("pipeline.inflight")
 _M_STALLS = metrics.counter("pipeline.stalls")
 _M_STALL_S = metrics.histogram("pipeline.stall_s")
 _M_BUF_REUSE = metrics.counter("pipeline.buffer_reuse")
@@ -240,6 +243,7 @@ class DispatchPipeline:
         # its buffers until readback settles) plus the one being packed.
         self.pool = pool or StagingBufferPool(max_per_shape=self.depth + 1)
         self._tl = tl  # None -> the process-global timeline
+        self._account = (tl if tl is not None else timeline.TIMELINE).account
         self._execs: dict[str, ThreadPoolExecutor] = {}
         self._lock = threading.Lock()
         self._closed = False
@@ -301,8 +305,10 @@ class DispatchPipeline:
         with self._span("stage", task):
             return task.stage()
 
-    def _submitted(self, task: ChunkTask, payload):
-        return task.submit(payload), time.monotonic()
+    def _submitted(self, task: ChunkTask, payload, bucket):
+        handle = task.submit(payload)
+        self._account.dispatched(bucket)
+        return handle, time.monotonic()
 
     def _release_buffers(self, task: ChunkTask) -> None:
         """Hand the chunk's pooled staging buffers back — only once the
@@ -324,10 +330,18 @@ class DispatchPipeline:
             # (GIL/scheduler) is not device idle — without the backdate,
             # every worker handoff shows up as an idle gap that cancels
             # exactly the occupancy the overlap bought.
+            return self._readback(task, handle, dispatched_t)
+        finally:
+            self._release_buffers(task)
+
+    def _readback(self, task: ChunkTask, handle, dispatched_t: float) -> Any:
+        """The readback span, opened at `dispatched_t`; the mask on the host
+        ends the program's busy interval in the idle account."""
+        try:
             with self._span("readback", task, start=dispatched_t):
                 return task.readback(handle)
         finally:
-            self._release_buffers(task)
+            self._account.read()
 
     def run(self, tasks) -> list:
         """Run every task through the window; returns readbacks in task
@@ -340,26 +354,26 @@ class DispatchPipeline:
         # would otherwise report whichever was constructed last, active
         # or not).
         _M_DEPTH.set(self.depth)
+        bucket = timeline.BUCKET.get()
         if self.depth <= 1 or self._closed:
-            return [self._run_serial(t) for t in tasks]
-        return self._run_windowed(tasks)
+            return [self._run_serial(t, bucket) for t in tasks]
+        return self._run_windowed(tasks, bucket)
 
-    def _run_serial(self, task: ChunkTask) -> Any:
+    def _run_serial(self, task: ChunkTask, bucket) -> Any:
         """The inline/serial leg: caller-thread stage -> submit ->
         readback, nothing overlapped — deterministic under the chaos
         virtual-time loop."""
         try:
             payload = self._staged(task)
-            handle, dispatched_t = self._submitted(task, payload)
+            handle, dispatched_t = self._submitted(task, payload, bucket)
             # Same backdate rule as the windowed path (fair A/B): the span
             # opens at dispatch completion — on this thread that is only
             # microseconds ago, so serial semantics are unchanged.
-            with self._span("readback", task, start=dispatched_t):
-                return task.readback(handle)
+            return self._readback(task, handle, dispatched_t)
         finally:
             self._release_buffers(task)
 
-    def _run_windowed(self, tasks: list[ChunkTask]) -> list:
+    def _run_windowed(self, tasks: list[ChunkTask], bucket) -> list:
         up = self._executor("upload")
         rb = self._executor("readback")
         window = threading.Semaphore(self.depth)
@@ -368,7 +382,6 @@ class DispatchPipeline:
         def _release(_fut: Future) -> None:
             with self._lock:
                 self._inflight -= 1
-                _M_INFLIGHT.set(self._inflight)
             window.release()
 
         try:
@@ -384,7 +397,6 @@ class DispatchPipeline:
                     _M_STALL_S.record(time.monotonic() - t0)
                 with self._lock:
                     self._inflight += 1
-                    _M_INFLIGHT.set(self._inflight)
                 # The slot just taken has no future yet: until _release is
                 # attached, a failing stage must free it (and the staged
                 # buffers) itself.
@@ -392,7 +404,7 @@ class DispatchPipeline:
                 handle_fut = None
                 try:
                     payload = self._staged(task)
-                    handle_fut = up.submit(self._submitted, task, payload)
+                    handle_fut = up.submit(self._submitted, task, payload, bucket)
                     res_fut = rb.submit(self._read, task, handle_fut)
                     res_fut.add_done_callback(_release)
                     attached = True

@@ -53,9 +53,9 @@ Two properties of the third sink:
   * **Annotations are per thread and stack-like.** On an asyncio thread
     wrap only SYNCHRONOUS sections (no `await` inside), or coroutines
     interleave and nest falsely. A wait that crosses an `await` (the
-    scheduler's queue, the dispatch semaphore, the `to_thread` hop) is a
-    histogram only; in the trace it is the absence of any annotation,
-    which reads "the host was waiting for a request".
+    scheduler's queue, the dispatch semaphore, the `to_thread` hop) is no
+    annotation: in the trace it looks like "no request came". The idle
+    account below attributes those waits instead.
   * **A backdated span (`start=`) is backdated in the ring alone.** The
     pipeline opens `readback` at dispatch completion for the occupancy
     math; the histogram and the annotation run from the real enter, so on
@@ -70,6 +70,35 @@ request's `parse` and `reply` carry its `rid` as their `batch`, and
 `collect` names the rids it merged (`scatter`, the section after the
 backend call, shares its batch), so a request can be followed from parse
 to chunk to reply.
+
+**The idle account** (`IdleAccount`, one per `DeviceTimeline`; the
+process's is `ACCOUNT`) says why the device idled, over the whole run and
+not the ring's stretch. The device is BUSY while at least one program is
+between its dispatch returning and its mask reaching the host (the two
+edges of the backdated `readback` span, `ops/pipeline.py`), taken over
+every pipeline of the process: its lanes share one device. Every idle
+instant goes to exactly one cause, the first that holds:
+
+  * `host` — a bucket has closed (the scheduler handed it to the service,
+    `BatchVerificationService._spawn_dispatch`) and has neither dispatched
+    its first program nor ended: collect, the `to_thread` hop, stage and
+    upload. A bucket the cache answers whole ends without a program.
+  * `held` — groups are queued in a scheduler and no bucket is closed:
+    the flush policy holds them (deadline, grid, a bulk slot whose
+    dispatch's programs already ended).
+  * `no_request` — nothing queued, nothing closed: the nodes and the
+    offer set the pace.
+
+At each change of that state (a group submitted, a bucket closed, a
+bucket's first program or end, a program dispatched, a mask on the host)
+the seconds since the last change go to the state that held, on
+`time.monotonic()` (never the loop's clock, which chaos makes virtual).
+The account starts at the first program dispatch, so a process that never
+dispatches one (a node) charges nothing. An edge is one lock and one clock
+read, gated on `HOTSTUFF_METRICS` like every metric; the totals reach the
+counters `timeline.device_busy_s` and `timeline.idle_<cause>_s` at every
+metrics dump, which first charges the open interval, so a snapshot holds
+everything up to its own instant.
 
 Dependency-free by design: stdlib + utils.metrics/tracing only — no jax
 (the graftlint tool and the chaos/telemetry planes import this
@@ -93,6 +122,10 @@ __all__ = [
     "DEVICE_PHASES",
     "DeviceTimeline",
     "TIMELINE",
+    "IdleAccount",
+    "ACCOUNT",
+    "IDLE_CAUSES",
+    "BUCKET",
     "enabled",
     "enable",
     "span",
@@ -129,8 +162,10 @@ DEVICE_PHASES: frozenset[str] = frozenset({"upload", "dispatch", "readback"})
 # phases each, four a chunk); four times that keeps half a minute.
 RING_CAPACITY = 16384
 
-_M_INTERVALS = metrics.counter("timeline.intervals")
-_M_DROPPED = metrics.counter("timeline.dropped")
+# The idle account's causes, in the order it tests them after "busy"
+# (module docstring); each has the counter `timeline.idle_<cause>_s`.
+IDLE_CAUSES: tuple[str, ...] = ("host", "held", "no_request")
+_BUSY, _HOST, _HELD, _NO_REQUEST = range(4)
 
 _enabled = os.environ.get("HOTSTUFF_TIMELINE", "1") != "0"
 
@@ -144,15 +179,164 @@ def enable(on: bool = True) -> None:
     _enabled = on
 
 
+class _Bucket:
+    """A closed bucket's place in an `IdleAccount`: `open` while it counts
+    as closed (neither its first program dispatched nor its task ended).
+    `epoch` is the account's at the close (None: not counted), so that a
+    bucket closed before a `reset` never takes a later one's place."""
+
+    __slots__ = ("account", "epoch", "open")
+
+    def __init__(self, account: "IdleAccount", epoch: object | None) -> None:
+        self.account = account
+        self.epoch = epoch
+        self.open = epoch is not None
+
+    def end(self, _task=None) -> None:
+        """The bucket's dispatch ended, however (a done-callback)."""
+        self.account.ended(self)
+
+
+class IdleAccount:
+    """The device's busy seconds and its idle seconds by cause (module
+    docstring). Edges come from the event loop and the pipeline's workers
+    alike; `clock` is `time.monotonic` but in tests. `counters` are the
+    four metric counters (busy, then `IDLE_CAUSES`) that `flush` feeds;
+    None keeps the totals here alone."""
+
+    def __init__(self, clock=time.monotonic, counters: tuple | None = None) -> None:
+        self._clock = clock
+        self._counters = counters
+        # Re-entrant, as utils/metrics.py's locks are: a SIGTERM handler's
+        # last dump may land on the thread parked inside an edge.
+        self._lock = threading.RLock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self._epoch = object()  # the buckets of this reset
+            self._t: float | None = None  # the last change; None: not started
+            self._programs = 0  # dispatch returned, mask not yet on the host
+            self._closed = 0  # buckets closed, no program yet, not ended
+            self._queued = 0  # groups in a scheduler's lanes
+            self._totals = [0.0] * 4  # busy, then IDLE_CAUSES
+            self._flushed = [0.0] * 4  # what the counters have had
+
+    def _charge(self) -> None:
+        """Give the seconds since the last change to the state that held
+        through them; nothing before the first program. Under the lock."""
+        if self._t is None:
+            return
+        now = self._clock()
+        if self._programs:
+            i = _BUSY
+        elif self._closed:
+            i = _HOST
+        elif self._queued:
+            i = _HELD
+        else:
+            i = _NO_REQUEST
+        self._totals[i] += now - self._t
+        self._t = now
+
+    # -- the edges -----------------------------------------------------------
+
+    def submitted(self) -> None:
+        """A group joined a scheduler's lane."""
+        if not metrics.enabled():
+            return
+        with self._lock:
+            self._charge()
+            self._queued += 1
+
+    def closed(self, groups: int) -> _Bucket:
+        """A scheduler closed a bucket of `groups` queued groups and handed
+        it to its service; end the bucket returned when its dispatch ends."""
+        if not metrics.enabled():
+            return _Bucket(self, None)
+        with self._lock:
+            self._charge()
+            self._queued = max(0, self._queued - groups)
+            self._closed += 1
+            return _Bucket(self, self._epoch)
+
+    def ended(self, bucket: _Bucket) -> None:
+        """`bucket`'s dispatch ended; it leaves the closed state if its
+        first program has not already taken it out."""
+        if not bucket.open:  # it only ever closes: no lock to see that
+            return
+        with self._lock:
+            if not bucket.open:
+                return
+            bucket.open = False
+            if bucket.epoch is not self._epoch:
+                return
+            self._charge()
+            self._closed -= 1
+
+    def dispatched(self, bucket: _Bucket | None = None) -> None:
+        """A program's dispatch returned: the device has it. `bucket` is the
+        closed bucket the program serves (`BUCKET`), None outside one. The
+        process's first program starts the account."""
+        if not metrics.enabled():
+            return
+        with self._lock:
+            if self._t is None:
+                self._t = self._clock()
+            else:
+                self._charge()
+            self._programs += 1
+            if bucket is not None and bucket.open and bucket.epoch is self._epoch:
+                bucket.open = False
+                self._closed -= 1
+
+    def read(self) -> None:
+        """A program's mask reached the host."""
+        if not metrics.enabled():
+            return
+        with self._lock:
+            self._charge()
+            self._programs = max(0, self._programs - 1)
+
+    # -- the totals ----------------------------------------------------------
+
+    def totals(self) -> dict:
+        """{"busy_s", "idle_s": {cause: s}} up to now, the open interval
+        charged; all zero until the first program."""
+        with self._lock:
+            self._charge()
+            t = list(self._totals)
+        return {
+            "busy_s": round(t[_BUSY], 6),
+            "idle_s": {c: round(t[1 + i], 6) for i, c in enumerate(IDLE_CAUSES)},
+        }
+
+    def flush(self) -> None:
+        """Charge the open interval and add to each counter what it has not
+        had yet. Every metrics dump calls it first (`metrics.before_dump`)."""
+        with self._lock:
+            self._charge()
+            adds = [t - f for t, f in zip(self._totals, self._flushed)]
+            self._flushed = list(self._totals)
+        for counter, add in zip(self._counters or (), adds):
+            if add:
+                counter.inc(add)
+
+
 class DeviceTimeline:
     """Ring of (batch, chunk, phase, t0, t1, n) intervals.
 
     `batch` numbers one verify_batch_mask[_committee] call; `chunk` is the
     chunk's index within its batch (the uploader is a 1-worker FIFO, so
     chunk order IS dispatch order). Appends are deque-atomic under the
-    GIL — the staging thread and the uploader thread both record."""
+    GIL — the staging thread and the uploader thread both record.
+    `account` is the idle account the pipelines that record here feed (a
+    fresh one, counters off, unless given)."""
 
-    def __init__(self, capacity: int | None = None) -> None:
+    def __init__(
+        self, capacity: int | None = None, account: IdleAccount | None = None
+    ) -> None:
+        self.account = account if account is not None else IdleAccount()
         if capacity is None:
             try:
                 capacity = int(
@@ -178,9 +362,6 @@ class DeviceTimeline:
             return
         with self._lock:
             self._count += 1
-        _M_INTERVALS.inc()
-        if self._count > self.capacity:
-            _M_DROPPED.inc()
         self._ring.append((batch, chunk, phase, t0, t1, n))
 
     def __len__(self) -> int:
@@ -207,12 +388,16 @@ class DeviceTimeline:
 
     def summary(self) -> dict:
         """Occupancy / idle-gap / overlap-headroom over the chunk phases
-        of the whole ring (request phases are no part of a chunk).
+        of the whole ring (request phases are no part of a chunk), and
+        beside them the idle account's totals since it started (`account`:
+        the device's one idle, by cause; the ring's gaps are the same edges
+        over the ring's stretch alone, their cause unknown).
 
-        All fields derive from ONE ring snapshot. Empty ring -> zeros (the
-        shape is stable so BENCH json and dashboards never KeyError)."""
+        The ring's fields derive from ONE ring snapshot. Empty ring -> zeros
+        (the shape is stable so BENCH json and dashboards never KeyError)."""
         iv = [i for i in list(self._ring) if i[2] in CHUNK_PHASES]
         out = {
+            "account": self.account.totals(),
             "batches": 0,
             "chunks": 0,
             "span_s": 0.0,
@@ -314,9 +499,26 @@ class DeviceTimeline:
     def reset(self) -> None:
         self._ring.clear()
         self._count = 0
+        self.account.reset()
 
 
-TIMELINE = DeviceTimeline()
+ACCOUNT = IdleAccount(
+    counters=(
+        metrics.counter("timeline.device_busy_s"),
+        metrics.counter("timeline.idle_host_s"),
+        metrics.counter("timeline.idle_held_s"),
+        metrics.counter("timeline.idle_no_request_s"),
+    )
+)
+metrics.before_dump(ACCOUNT.flush)
+TIMELINE = DeviceTimeline(account=ACCOUNT)
+
+# The closed bucket (`IdleAccount.closed`) the service's dispatch runs
+# under: the verifier's pipeline, in a copy of that context (`to_thread`),
+# charges the bucket's first program to it.
+BUCKET: contextvars.ContextVar[_Bucket | None] = contextvars.ContextVar(
+    "timeline_bucket", default=None
+)
 
 
 # The third sink. None until code that imports jax installs

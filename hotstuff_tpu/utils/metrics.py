@@ -60,6 +60,7 @@ __all__ = [
     "gauge",
     "histogram",
     "span",
+    "before_dump",
     "timed",
     "enabled",
     "enable",
@@ -328,6 +329,7 @@ class Registry:
     def __init__(self) -> None:
         self._metrics: dict[str, Counter | Gauge | Histogram] = {}
         self._info: dict[str, object] = {}
+        self._before_dump: list[Callable[[], None]] = []
         self._lock = _new_lock()
 
     def _get_or_create(self, name: str, kind, factory):
@@ -353,9 +355,19 @@ class Registry:
     ) -> Histogram:
         return self._get_or_create(name, Histogram, lambda: Histogram(name, buckets))
 
+    def before_dump(self, fn: Callable[[], None]) -> None:
+        """Run `fn()` at the start of every dump: a flush of counters that
+        are charged lazily (ops/timeline.py's idle account)."""
+        with self._lock:
+            self._before_dump.append(fn)
+
     def dump(self, include_buckets: bool = True) -> dict:
         """Full structured artifact (the `--metrics-out` JSON)."""
         counters, gauges, hists = {}, {}, {}
+        with self._lock:
+            flushes = list(self._before_dump)
+        for fn in flushes:
+            fn()
         with self._lock:
             metrics = list(self._metrics.values())
         for m in metrics:
@@ -424,6 +436,10 @@ def gauge(name: str) -> Gauge:
 
 def histogram(name: str, buckets: Sequence[float] = TIME_BUCKETS_S) -> Histogram:
     return REGISTRY.histogram(name, buckets)
+
+
+def before_dump(fn: Callable[[], None]) -> None:
+    REGISTRY.before_dump(fn)
 
 
 def span(hist: Histogram | str) -> _Span:
@@ -626,7 +642,6 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     # work-stealing bulk dispatch; the rest by DispatchPipeline itself.
     ("pipeline.chunks", "counter", None),
     ("pipeline.depth", "gauge", None),
-    ("pipeline.inflight", "gauge", None),
     ("pipeline.stalls", "counter", None),
     ("pipeline.stall_s", "histogram", None),
     ("pipeline.buffer_reuse", "counter", None),
@@ -820,9 +835,12 @@ _DEFAULT_NAMESPACE: tuple[tuple[str, str, tuple[float, ...] | None], ...] = (
     ("incident.mttd_s", "histogram", None),
     ("incident.mttr_s", "histogram", None),
     ("incident.budget_burn_s", "histogram", None),
-    # ops/timeline.py — device-occupancy timeline
-    ("timeline.intervals", "counter", None),
-    ("timeline.dropped", "counter", None),
+    # ops/timeline.py — the idle account: the device's busy seconds and
+    # its idle seconds by cause (float counts, fed at every dump)
+    ("timeline.device_busy_s", "counter", None),
+    ("timeline.idle_host_s", "counter", None),
+    ("timeline.idle_held_s", "counter", None),
+    ("timeline.idle_no_request_s", "counter", None),
     # utils/metrics.py meter_loop_cpu — CPU seconds of the event loop's
     # thread (a float count), started by the sidecar's and the node's mains
     ("runtime.loop_cpu_s", "counter", None),

@@ -26,6 +26,7 @@ _BASE = {
     # appended later with no `workloads` list: due wherever
     # `verified_tx_per_s` is, so in every cell
     "mempool.duplicate_share",
+    "device.idle_no_request_share", "device.idle_held_share", "device.idle_host_share",
 }
 _FLOOD = {"loadgen.failed_share", "flood.committed_p95_ms", "front.shed_share",
           "flood.verified_share"}

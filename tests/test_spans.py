@@ -7,7 +7,9 @@ CPU only: counts, orders and names. No time here is a device's.
 
 import asyncio
 import glob
+import json
 import os
+import threading
 import time
 
 import pytest
@@ -322,6 +324,95 @@ def test_loopback_sidecar_counts_requests_and_slot_holds(run_async, base_port):
     run_async(body())
 
 
+class _PacedBackend:
+    """Stands in for the sidecar's TpuBackend: a call stages for `HOST_S`,
+    runs two programs through a real DispatchPipeline (depth 2) whose masks
+    take `DEVICE_S` each to come back, and spends `AFTER_S` on the host
+    after them. `after` is set once a call is past its programs."""
+
+    HOST_S, DEVICE_S, AFTER_S = 0.02, 0.01, 0.03
+    name = "paced"
+    bucket_alignment = 0
+
+    def __init__(self):
+        self.pipeline = DispatchPipeline(depth=2, name="paced")
+        self.first_dispatch = None
+        self.after = threading.Event()
+
+    def _submit(self, _payload):
+        if self.first_dispatch is None:
+            self.first_dispatch = time.monotonic()
+        return "handle"
+
+    def verify_batch_mask(self, msgs, _pks, _sigs):
+        time.sleep(self.HOST_S)
+        self.pipeline.run(
+            ChunkTask(stage=lambda: None, submit=self._submit,
+                      readback=lambda _h: time.sleep(self.DEVICE_S))
+            for _ in range(2)
+        )
+        self.after.set()
+        time.sleep(self.AFTER_S)
+        return [True] * len(msgs)
+
+
+def test_loopback_idle_account_adds_up_to_the_elapsed_time(run_async):
+    """The real scheduler, service and pipeline through a script in which
+    each cause holds for a while: the account's four counters add up to the
+    time since the first program, and none of them is 0."""
+    from hotstuff_tpu.crypto.batch_service import BatchVerificationService
+    from hotstuff_tpu.crypto.scheduler import SchedulerConfig
+
+    names = ("timeline.device_busy_s", "timeline.idle_host_s",
+             "timeline.idle_held_s", "timeline.idle_no_request_s")
+    backend = _PacedBackend()
+
+    def counters():
+        c = json.loads(metrics.snapshot_json())["counters"]
+        return [c[n] for n in names]
+
+    async def body():
+        service = BatchVerificationService(
+            backend, dedup_cache_size=0,
+            scheduler_config=SchedulerConfig(bulk_concurrency=1),
+        )
+
+        def group():
+            return service.verify_group([b"m"] * 4, [(b"k", b"s")] * 4)
+
+        timeline.ACCOUNT.reset()
+        c0 = counters()
+        await group()  # its first program starts the account
+        await asyncio.sleep(0.05)  # nothing sent: no_request
+        backend.after.clear()
+        # held for the mempool lane's deadline, then closed: host, busy
+        second = asyncio.ensure_future(group())
+        await asyncio.to_thread(backend.after.wait, 5)
+        # the one bulk slot is the second's, past its programs: held
+        await group()
+        await second
+        t_end = time.monotonic()
+        c1 = counters()
+        t_after = time.monotonic()
+        return [b - a for a, b in zip(c0, c1)], t_end, t_after
+
+    try:
+        deltas, t_end, t_after = run_async(body())
+    finally:
+        backend.pipeline.close()
+        timeline.ACCOUNT.reset()
+    busy, host, held, no_request = deltas
+    total = sum(deltas)
+    # the dump charges up to its own clock read, taken between the two
+    # stamps; the account's start follows the first submit by one edge
+    assert t_end - backend.first_dispatch - 2e-3 <= total
+    assert total <= t_after - backend.first_dispatch
+    assert busy >= 3 * 2 * _PacedBackend.DEVICE_S * 0.9
+    assert host >= 2 * _PacedBackend.HOST_S * 0.9
+    assert no_request >= 0.05 * 0.9
+    assert held > 0
+
+
 # -- (d) the benchmark's readers ----------------------------------------------
 
 NEW_METRICS = (
@@ -633,10 +724,68 @@ def test_every_cell_reports_the_duplicate_share():
         "name": "mempool.duplicate_share", "unit": "%", "better": "lower",
         "source": "program_counter", "layer": "mempool", "moves": "verified_tx_per_s",
     }
-    assert bench["per_layer"][-1] == entry
+    # what later PRs appended after it is theirs to pin
+    assert bench["per_layer"][-1 - len(IDLE_METRICS)] == entry
     for cell in bench["workloads"]:
         names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
         assert "mempool.duplicate_share" in names, cell["name"]
+
+
+# -- the device's idle by cause (the idle account) ------------------------------
+
+IDLE_METRICS = (
+    "device.idle_no_request_share", "device.idle_held_share", "device.idle_host_share",
+)
+# since boot at the window's first snapshot, at its last; 40 s apart
+IDLE_COUNTS = {
+    "device.idle_no_request_share": ("timeline.idle_no_request_s", 50.0, 62.0, 30.0),
+    "device.idle_held_share": ("timeline.idle_held_s", 1.0, 3.0, 5.0),
+    "device.idle_host_share": ("timeline.idle_host_s", 2.0, 2.8, 2.0),
+}
+
+
+def _idle_src(names=None):
+    src = _src()
+    (_t, first), (_t, last), (_t, after) = src["sidecar"]["snapshots"]
+    for name, at_first, at_last, _share in IDLE_COUNTS.values():
+        if names is None or name in names:
+            first["counters"][name] = at_first
+            last["counters"][name] = after["counters"][name] = at_last
+    return src
+
+
+@pytest.mark.parametrize("name", IDLE_METRICS)
+def test_idle_share_is_the_windows_advance_over_its_seconds(name):
+    from chipbench import run
+
+    read = run.load_reader("per_layer", name)
+    counter, _a, _b, share = IDLE_COUNTS[name]
+    assert read(_idle_src()) == pytest.approx(share)
+    # a program without the account (the parent): nothing to read, and not 0
+    assert read(_src()) is None
+    assert read(_idle_src(names={c for c, *_ in IDLE_COUNTS.values()} - {counter})) is None
+    # no snapshot at or before the window's opening: nothing to read
+    late = _idle_src()
+    late["sidecar"]["snapshots"] = late["sidecar"]["snapshots"][1:]
+    assert read(late) is None
+    late["sidecar"]["snapshots"] = []
+    assert read(late) is None
+
+
+def test_every_cell_reports_the_idle_shares():
+    from chipbench import run
+
+    bench = run.load_benchmark()
+    entries = [m for m in bench["per_layer"] if m["name"] in IDLE_METRICS]
+    assert entries == [
+        {"name": name, "unit": "%", "better": "lower", "source": "program_counter",
+         "layer": "device", "moves": "verified_tx_per_s"}
+        for name in IDLE_METRICS
+    ]
+    assert bench["per_layer"][-len(IDLE_METRICS):] == entries
+    for cell in bench["workloads"]:
+        names = [m["name"] for m in run.metrics_for(bench, cell["name"], "per_layer")]
+        assert set(IDLE_METRICS) <= set(names), cell["name"]
 
 
 def test_every_new_name_is_in_the_namespace():
@@ -651,6 +800,8 @@ def test_every_new_name_is_in_the_namespace():
         "mempool.synthetic_skipped_batches",
         "scheduler.critical_groups", "scheduler.critical_held",
         "mempool.payloads_duplicate",
+        "timeline.device_busy_s", "timeline.idle_host_s", "timeline.idle_held_s",
+        "timeline.idle_no_request_s",
     } <= declared
     # a phase's histogram is its profiler name plus `_s`
     for name in timeline.PHASES.values():

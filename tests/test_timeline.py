@@ -1,9 +1,11 @@
 """Device-occupancy timeline (ops/timeline.py): summary math, ring
-bounds, jax-free importability, and the wired verifier chunk loop.
+bounds, the idle account, jax-free importability, and the wired verifier
+chunk loop.
 
 The summary-math tests drive a private DeviceTimeline with hand-placed
 intervals so occupancy / idle gaps / overlap headroom are checked against
-numbers computed by hand, not against the implementation.
+numbers computed by hand, not against the implementation; the idle
+account's tests drive it through scripted edges on a scripted clock.
 """
 
 import json
@@ -13,7 +15,9 @@ import sys
 
 import pytest
 
-from hotstuff_tpu.ops.timeline import DeviceTimeline
+from hotstuff_tpu.ops import timeline
+from hotstuff_tpu.ops.timeline import DeviceTimeline, IdleAccount
+from hotstuff_tpu.utils import metrics
 
 
 def _fill(tl: DeviceTimeline, intervals):
@@ -21,8 +25,22 @@ def _fill(tl: DeviceTimeline, intervals):
         tl.note(batch, chunk, phase, t0, t1, n)
 
 
+class Clock:
+    """A scripted `time.monotonic`."""
+
+    def __init__(self, t: float = 100.0) -> None:
+        self.t = t
+
+    def __call__(self) -> float:
+        return self.t
+
+
+ZERO_ACCOUNT = {"busy_s": 0.0, "idle_s": {"host": 0.0, "held": 0.0, "no_request": 0.0}}
+
+
 def test_summary_empty_ring_is_stable_shape():
     s = DeviceTimeline(capacity=64).summary()
+    assert s["account"] == ZERO_ACCOUNT
     assert s["chunks"] == 0
     assert s["occupancy"] == 0.0
     assert s["overlap_headroom"] == 0.0
@@ -31,7 +49,8 @@ def test_summary_empty_ring_is_stable_shape():
 
 
 def test_summary_occupancy_and_idle_gaps_hand_computed():
-    tl = DeviceTimeline(capacity=64)
+    clock = Clock(0.0)
+    tl = DeviceTimeline(capacity=64, account=IdleAccount(clock=clock))
     # span [0, 10]; device busy [0,2] and [5,6] -> occupancy 0.3; one
     # idle gap of 3 between them ([6,10] is trailing span from the host
     # stage below, not an inter-busy gap).
@@ -52,6 +71,22 @@ def test_summary_occupancy_and_idle_gaps_hand_computed():
     assert s["idle"]["total_s"] == pytest.approx(3.0)
     assert s["idle"]["max_s"] == pytest.approx(3.0)
     assert s["phase_s"]["stage"] == pytest.approx(1.0)
+    # the account over the same edges: nothing until the dispatch returns
+    # at 2, busy to the mask at 6, no request to 9, then a closed bucket
+    # whose stage runs to 10. The ring's gap [2, 5] is the account's busy:
+    # the program ran while the readback worker had not yet taken it.
+    account = tl.account
+    clock.t = 2.0
+    account.dispatched()
+    clock.t = 6.0
+    account.read()
+    clock.t = 9.0
+    account.submitted()
+    account.closed(1)
+    clock.t = 10.0
+    assert tl.summary()["account"] == {
+        "busy_s": 4.0, "idle_s": {"host": 1.0, "held": 0.0, "no_request": 3.0},
+    }
 
 
 def test_summary_overlap_headroom_pairs_consecutive_chunks():
@@ -84,6 +119,145 @@ def test_ring_bound_evicts_oldest_and_counts_drops():
     assert len(tl) == 16
     assert tl.dropped == 4
     assert tl.intervals()[0]["chunk"] == 4  # oldest evicted
+
+
+# -- the idle account -----------------------------------------------------------
+
+# A script of edges and clock steps: "d" a program dispatched outside any
+# bucket, "D" one that serves the last bucket closed, "r" a mask on the
+# host, "s" a group submitted, ("c", k) a bucket of k groups closed, "e"
+# the last bucket's dispatch ended ("e0" the first's), "x" a reset, a number
+# the seconds that pass. Then (busy, host, held, no_request) seconds.
+ACCOUNT_SCRIPTS = {
+    "busy": (["d", 2, "r"], (2, 0, 0, 0)),
+    "no_request": (["d", "r", 3], (0, 0, 0, 3)),
+    "held": (["d", "r", "s", 5], (0, 0, 5, 0)),
+    "host": (["d", "r", "s", ("c", 1), 7], (0, 7, 0, 0)),
+    # a closed bucket wins over groups still queued beside it
+    "host_over_held": (["d", "r", "s", "s", ("c", 1), 4, "e", 1], (0, 4, 1, 0)),
+    # a program on the device wins over everything the host holds
+    "busy_over_host": (["d", "s", "s", ("c", 1), 6], (6, 0, 0, 0)),
+    # a bucket the cache answers whole leaves with its task, programless
+    "cache_hit_only": (["d", "r", "s", ("c", 1), 2, "e", 3], (0, 2, 0, 3)),
+    # busy until the last of two programs in flight is back
+    "two_programs": (["d", 1, "d", 2, "r", 3, "r", 4], (6, 0, 0, 4)),
+    # a bucket's first program takes it out; its end changes nothing then
+    "first_program": (["d", "r", "s", ("c", 1), 2, "D", 3, "r", 4, "e", 5],
+                      (3, 2, 0, 9)),
+    # a node: edges, never a program, so nothing is charged
+    "never_dispatches": (["s", 5, ("c", 1), 5, "e", 5], (0, 0, 0, 0)),
+    # a bucket closed before a reset ends after it: the new one stays closed
+    "reset": (["d", "r", "s", ("c", 1), "x", "d", "r", "s", ("c", 1), 1, "e0", 2],
+              (0, 3, 0, 0)),
+}
+
+
+def _play(account: IdleAccount, clock: Clock, script) -> None:
+    buckets = []
+    for step in script:
+        if isinstance(step, (int, float)):
+            clock.t += step
+        elif isinstance(step, tuple):
+            buckets.append(account.closed(step[1]))
+        elif step in ("e", "e0"):
+            buckets[-1 if step == "e" else 0].end()
+        elif step == "x":
+            account.reset()
+        else:
+            bucket = buckets[-1] if buckets else None
+            {"d": account.dispatched, "D": lambda: account.dispatched(bucket),
+             "r": account.read, "s": account.submitted}[step]()
+
+
+@pytest.mark.parametrize("case", sorted(ACCOUNT_SCRIPTS))
+def test_idle_account_charges_every_instant_once(case):
+    script, (busy, host, held, no_request) = ACCOUNT_SCRIPTS[case]
+    clock = Clock()
+    account = IdleAccount(clock=clock)
+    _play(account, clock, script)
+    got = account.totals()
+    assert got == {
+        "busy_s": busy, "idle_s": {"host": host, "held": held, "no_request": no_request},
+    }
+    # the four add up to the time since the first program
+    started = next((i for i, s in enumerate(script) if s in ("d", "D")), None)
+    elapsed = 0 if started is None else sum(
+        s for s in script[started:] if isinstance(s, (int, float)))
+    assert got["busy_s"] + sum(got["idle_s"].values()) == elapsed
+
+
+def test_idle_account_keeps_to_the_metrics_gate():
+    clock = Clock()
+    account = IdleAccount(clock=clock)
+    metrics.enable(False)
+    try:
+        _play(account, clock, ["d", 2, "r", "s", ("c", 1), 3])
+    finally:
+        metrics.enable(True)
+    assert account.totals() == ZERO_ACCOUNT
+
+
+def test_idle_account_loses_no_edge_across_threads():
+    """More threads than cores run whole bucket lives at once, with the
+    interpreter switching threads as often as it can: every count comes back
+    to 0 and the totals add up to the clock's whole advance. (A lost update
+    leaves a count off 0; a charge made twice or lost breaks the sum.)"""
+    import itertools
+    import sys
+    import threading
+
+    ticks = itertools.count()
+    account = IdleAccount(clock=lambda: float(next(ticks)))
+    account.dispatched()  # reads 0.0: the account starts there
+    account.read()
+
+    def life():
+        for _ in range(500):
+            account.submitted()
+            bucket = account.closed(1)
+            account.dispatched(bucket)
+            account.read()
+            bucket.end()
+
+    threads = [threading.Thread(target=life) for _ in range(2 * (os.cpu_count() or 4))]
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(prev)
+    assert not any(t.is_alive() for t in threads)
+    assert (account._programs, account._closed, account._queued) == (0, 0, 0)
+    got = account.totals()  # one more clock read: the charge up to it
+    assert got["busy_s"] + sum(got["idle_s"].values()) == account._t
+
+
+def test_a_metrics_dump_charges_the_open_interval(monkeypatch):
+    """The counters reach a snapshot with the interval still open charged
+    up to it: ten seconds with nothing sent land in the snapshot that
+    follows them, not in a later one."""
+    clock = Clock()
+    monkeypatch.setattr(timeline.ACCOUNT, "_clock", clock)
+    timeline.ACCOUNT.reset()
+    names = ("timeline.device_busy_s", "timeline.idle_host_s",
+             "timeline.idle_held_s", "timeline.idle_no_request_s")
+
+    def counters():
+        return json.loads(metrics.snapshot_json())["counters"]
+
+    try:
+        c0 = counters()
+        _play(timeline.ACCOUNT, clock, ["d", 2, "r", 3, "s", ("c", 1), 4, "e"])
+        c1 = counters()
+        clock.t += 10.0
+        c2 = counters()
+    finally:
+        timeline.ACCOUNT.reset()
+    assert [c1[n] - c0[n] for n in names] == pytest.approx([2.0, 4.0, 0.0, 3.0])
+    assert [c2[n] - c1[n] for n in names] == pytest.approx([0.0, 0.0, 0.0, 10.0])
 
 
 def test_span_context_manager_records_monotonic_interval():
